@@ -439,6 +439,12 @@ def main(argv: "list[str] | None" = None) -> int:
     )
     args = parser.parse_args(argv)
 
+    if args.command in ("run", "sweep", "serve"):
+        # the commands that compile: JAX's persistent compilation cache
+        # gets its directory here, before the first compile
+        from shadow_tpu.runtime.compile_cache import place_persistent_cache
+
+        place_persistent_cache()
     if args.command == "run":
         from shadow_tpu.runtime.cli_run import CliUserError, run_from_config
 

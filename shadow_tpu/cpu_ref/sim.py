@@ -45,6 +45,7 @@ class CpuRefPhold:
         self.send = [0] * self.h
         self.packets_sent = [0] * self.h
         self.packets_dropped = [0] * self.h
+        self.events_handled = [0] * self.h  # as the engine counts: past ingress
         self.trace = []  # (time, tie, kind, data, host) in processing order
 
         def _bw(v, i):
@@ -155,6 +156,7 @@ class CpuRefPhold:
         self.trace.append((t, tie, kind, data, host))
         if not self._ingress(host, t, tie, kind, data, aux):
             return
+        self.events_handled[host] += 1
         base = self.ctr[host]
         if kind == KIND_PACKET:
             self.recv[host] += 1

@@ -113,12 +113,12 @@ def ensemble_engine_cfg(cfg: EngineConfig) -> EngineConfig:
     """The engine config an ensemble actually traces: cfg.ensemble arms
     the per-replica done-mask in run_round (semantics-neutral; unbatched
     runs skip its cost — engine/state.py), and the megakernel's
-    pallas_call is not exercised under vmap here, so a megakernel engine —
-    explicit, or "auto" resolving to it on a real backend
-    (effective_engine) — falls back to the XLA pump microscan: the SAME
-    pump microsteps, bit-identical results (tests/test_megakernel.py),
-    one vmappable program."""
-    if effective_engine(dataclasses.replace(cfg, ensemble=False)) == "megakernel":
+    pallas_call is not exercised under vmap here, so an explicit
+    megakernel engine ("auto" never resolves to it — effective_engine)
+    falls back to the XLA pump microscan: the SAME pump microsteps,
+    bit-identical results (tests/test_megakernel.py), one vmappable
+    program."""
+    if cfg.engine == "megakernel":
         return dataclasses.replace(
             cfg, ensemble=True, engine="pump",
             pump_k=cfg.pump_k if cfg.pump_k > 0 else 8,
@@ -320,7 +320,7 @@ def _drive_ensemble(
     launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
     tracker=None, on_state=None, on_rows=None,
     watchdog_s: float = 0.0, engine: str = "pump",
-    capacity_error=None,
+    capacity_error=None, compile_chunk=None,
 ):
     """The ensemble twin of engine/round.py `_drive`: same depth-2
     pipeline and donation discipline, same two-phase checkpoint commit,
@@ -332,7 +332,8 @@ def _drive_ensemble(
     sweep scheduler's per-job progress stream (one row per job, zero
     extra device syncs; runtime/sweep.py). `watchdog_s`/`engine` and
     the chaos capacity/stall/compile hooks mirror engine/round.py
-    `_drive` — the degradation ladder covers both drivers.
+    `_drive` — the degradation ladder covers both drivers — as does
+    `compile_chunk` (_launch_chunk0).
     `capacity_error(rows, live_state)` overrides how an overflow
     becomes an exception (the 2-D mesh driver names (replica, shard)
     coordinates from the live state — engine/mesh.py); the default
@@ -378,7 +379,9 @@ def _drive_ensemble(
         for r in range(R)
         if int(entry_rows[r, PROBE_NEXT_TIME]) >= end_time
     }
-    pend_st, pend_probe = _launch_chunk0(launch, st, tracker, engine)
+    pend_st, pend_probe = _launch_chunk0(
+        launch, st, tracker, engine, compile_chunk
+    )
     launched = 1
     fetched = 0
     pending_snap = None
@@ -532,13 +535,16 @@ def run_ensemble_until(
         # same-shape worlds that differ only in seed
         jit_cfg = trace_static_cfg(cfg)
 
+        chunk_args = (end, rounds_per_chunk, model, tables, jit_cfg)
+
         def launch(s):
-            return _run_ensemble_chunk_jit(
-                s, end, rounds_per_chunk, model, tables, jit_cfg
-            )
+            return _run_ensemble_chunk_jit(s, *chunk_args)
+
+        def compile_chunk(s):
+            _run_ensemble_chunk_jit.lower(s, *chunk_args).compile()
 
     else:
-        exe = launch
+        exe, compile_chunk = launch, None  # compiled in the cache's seam
 
         def launch(s):
             return exe(s, end, tables)
@@ -548,4 +554,5 @@ def run_ensemble_until(
         desc=f"{max_chunks}x{rounds_per_chunk} rounds",
         tracker=tracker, on_state=on_state, on_rows=on_rows,
         watchdog_s=watchdog_s, engine=effective_engine(ensemble_engine_cfg(cfg)),
+        compile_chunk=compile_chunk,
     )

@@ -21,10 +21,15 @@ byte-for-byte identical program, just scheduled differently.
 
 Execution tiers:
 
-  * CPU (and any box without a real TPU backend): `interpret=True` — the
-    kernel is discharged to ordinary XLA ops, jittable, bit-identical;
-    this is the always-on conformance path (tests/test_megakernel.py).
-  * TPU: compiled via Mosaic over host tiles. Tiling is row-local by
+  * CPU: `interpret=True` — the kernel is discharged to ordinary XLA
+    ops, jittable, bit-identical; this is the always-on conformance path
+    (tests/test_megakernel.py) and the only tier that has ever run.
+  * TPU: compiled via Mosaic over host tiles — today REFUSED by the
+    chip's compiler before the body is looked at (int64 carry leaves;
+    docs/megakernel.md "Engine selection", tests/test_chip_compile.py),
+    so `engine: megakernel` on a TPU fails with the compiler's error and
+    `auto` never selects it. Any other backend name is an error: a
+    Pallas kernel is never interpreted on an accelerator. Tiling is row-local by
     construction (every microstep op is elementwise over [H]/[H,S]/[H,K]
     rows or a per-row reduction), so any tile split of the host axis is
     bit-identical; cross-tile scalars (min_used, the rejected flag) are
@@ -238,7 +243,14 @@ def megakernel_stage(
     XLA — they run once per launch, not per microstep."""
     if cfg.pump_k <= 0:
         raise ValueError("megakernel_stage requires pump_k > 0")
-    interpret = jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        # never interpreted on an accelerator, whatever it calls itself
+        raise ValueError(
+            f"the megakernel runs interpreted on 'cpu' and compiled on "
+            f"'tpu'; backend {backend!r} is neither"
+        )
+    interpret = backend == "cpu"
     c = pump_carry_init(st, model, tables, cfg)
     c = _launch(c, window_end, model, tables, cfg, interpret)
     return pump_carry_finish(st, c, model, cfg)

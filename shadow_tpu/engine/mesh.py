@@ -57,6 +57,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from shadow_tpu.engine.ensemble import (
@@ -78,7 +79,6 @@ from shadow_tpu.engine.round import (
     state_probe,
     validate_runahead,
 )
-from shadow_tpu.engine.sharded import _SHARD_MAP_CHECK_KW, shard_map
 from shadow_tpu.engine.state import EngineConfig, SimState, trace_static_cfg
 
 # one definition of the "RxS" grid spec, shared with config validation
@@ -324,7 +324,7 @@ def _mesh_chunk_fn(st: SimState, plan: MeshPlan, mesh: Mesh,
         mesh=mesh,
         in_specs=(specs, tspecs, P()),
         out_specs=(specs, P(REPLICA_AXIS, None)),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )
     fn = jax.jit(f, donate_argnums=(0,))
     _CHUNK_FNS[key] = fn
@@ -504,8 +504,11 @@ def run_mesh_until(
         def launch(s):
             return compiled(s, tables, end)
 
+        def compile_chunk(s):
+            compiled.lower(s, tables, end).compile()
+
     else:
-        exe = launch
+        exe, compile_chunk = launch, None  # compiled in the cache's seam
 
         def launch(s):
             return exe(s, tables, end)
@@ -518,7 +521,7 @@ def run_mesh_until(
         desc=f"{max_chunks}x{rounds_per_chunk} rounds ({plan.describe()})",
         tracker=tracker, on_state=on_state, on_rows=on_rows,
         watchdog_s=watchdog_s, engine=effective_engine(cfg),
-        capacity_error=capacity_error,
+        capacity_error=capacity_error, compile_chunk=compile_chunk,
     )
 
 

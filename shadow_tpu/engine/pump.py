@@ -512,7 +512,7 @@ def pump_microstep(
     acked = jnp.where(p3, abs_ack - v_snd_una, 0)
     cwnd1 = jnp.where(ss, v_cwnd + jnp.minimum(acked, mss), v_cwnd)
     cwnd1 = jnp.where(
-        ca, cwnd1 + jnp.maximum((mss * mss) // jnp.maximum(cwnd1, 1), 1), cwnd1
+        ca, cwnd1 + jnp.maximum(T.ca_increment(p.mss, cwnd1), 1), cwnd1
     )
     una1 = jnp.where(p3, abs_ack, v_snd_una)
     nxt1 = jnp.where(p3, jnp.maximum(v_snd_nxt, abs_ack), v_snd_nxt)
@@ -524,9 +524,9 @@ def pump_microstep(
     rtt = now - v_rtt_ts
     first = v_srtt < 0
     rttvar1 = jnp.where(
-        first, rtt // 2, (3 * v_rttvar + jnp.abs(v_srtt - rtt)) // 4
+        first, rtt >> 1, (3 * v_rttvar + jnp.abs(v_srtt - rtt)) >> 2
     )
-    srtt1 = jnp.where(first, rtt, (7 * v_srtt + rtt) // 8)
+    srtt1 = jnp.where(first, rtt, (7 * v_srtt + rtt) >> 3)
     rto1 = jnp.clip(
         srtt1 + jnp.maximum(p.granularity_ns, 4 * rttvar1),
         p.rto_min_ns,

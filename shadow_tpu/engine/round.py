@@ -63,13 +63,19 @@ class Draw:
         return rng.exponential_ns(self.key, self.counter + jnp.uint32(i), mean_ns)
 
 
-def _axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis. jax >= 0.5 exposes
-    jax.lax.axis_size; on older versions psum of a Python int
-    constant-folds to the same static value inside shard_map."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def _pmin(x: jax.Array, axis_name) -> jax.Array:
+    """lax.pmin for the engine's int64 times. The chip's compiler lowers
+    only SUM all-reduces of 64-bit integers ("UNIMPLEMENTED: Supported
+    lowering only of Sum all reduce", asked for a described v5e 2x2 —
+    tests/test_chip_compile.py), so the values are gathered and reduced
+    locally: same result on every shard, and batchable under the mesh
+    plane's vmap like the collective it replaces."""
+    return jnp.min(jax.lax.all_gather(x, axis_name), axis=0)
+
+
+def _pmax(x: jax.Array, axis_name) -> jax.Array:
+    """lax.pmax for int64 (see _pmin)."""
+    return jnp.max(jax.lax.all_gather(x, axis_name), axis=0)
 
 
 def _lane_seqs(valid: jax.Array, base: jax.Array):
@@ -539,7 +545,7 @@ def _flush_outbox_traffic(
         mode = getattr(cfg, "exchange", "all_to_all") if cfg is not None else "all_gather"
         base = jax.lax.axis_index(axis_name) * h_local
         if mode in ("all_to_all", "dense"):
-            d = _axis_size(axis_name)
+            d = jax.lax.axis_size(axis_name)
             cap = getattr(cfg, "a2a_capacity", 0) or 0
             if cap <= 0:
                 # safe default: each peer bucket can hold the whole local
@@ -717,7 +723,7 @@ def _flush_segment(
 
     base = 0
     if axis_name is not None:
-        d = _axis_size(axis_name)
+        d = jax.lax.axis_size(axis_name)
         base = jax.lax.axis_index(axis_name) * h_local
         cap = getattr(cfg, "a2a_capacity", 0)
         cap = e_max if cap <= 0 else min(cap, e_max)
@@ -804,8 +810,8 @@ def run_round(
     if compact:
         max_iters *= -(-h_local // lanes)
 
-    # Engine selection ("auto" resolved by effective_engine: megakernel on
-    # real backends, pump/plain on CPU and under vmap). Models without a
+    # Engine selection ("auto" resolved by effective_engine: pump when
+    # pump_k > 0, else plain, on every backend). Models without a
     # pump_spec (or with hooks the fast paths can't honor) always take the
     # plain handler, so every engine value is bit-identical on every model.
     # With compaction, the WHOLE iteration body — pump/megakernel stage
@@ -928,7 +934,7 @@ def _next_window_end(
     if start is None:
         start = jnp.min(equeue.next_time(st.queue))
         if axis_name is not None:
-            start = jax.lax.pmin(start, axis_name)
+            start = _pmin(start, axis_name)
     start = jnp.minimum(start, end_time)
     runahead = jnp.asarray(cfg.runahead_ns, jnp.int64)
     if cfg.use_dynamic_runahead:
@@ -936,7 +942,7 @@ def _next_window_end(
         # packet has flown, stay at the conservative graph minimum
         used = st.min_used_lat
         if axis_name is not None:
-            used = jax.lax.pmin(used, axis_name)
+            used = _pmin(used, axis_name)
         runahead = jnp.maximum(
             runahead, jnp.where(used == TIME_MAX, runahead, used)
         )
@@ -973,7 +979,7 @@ def _next_window_end(
     bound = nt + jnp.minimum(la, TIME_MAX - nt)  # saturating add
     w = jnp.min(bound)
     if axis_name is not None:
-        w = jax.lax.pmin(w, axis_name)
+        w = _pmin(w, axis_name)
     return jnp.maximum(floor, jnp.minimum(w, end_time))
 
 
@@ -1004,7 +1010,7 @@ def run_rounds_scan(
     def one(s, _):
         start = jnp.min(equeue.next_time(s.queue))
         if axis_name is not None:
-            start = jax.lax.pmin(start, axis_name)
+            start = _pmin(start, axis_name)
         has_traffic = _has_traffic(s, axis_name)
         window_end = _next_window_end(
             s, end_time, cfg, axis_name, start=start, tables=tables
@@ -1163,10 +1169,10 @@ def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
     # replicated scalars (win_ns_sum is mesh-uniform: pmin'd window math)
     rounds = [tr.rounds_live, tr.rounds_idle, st.win_ns_sum]
     if axis_name is not None:
-        nt = jax.lax.pmin(nt, axis_name)
+        nt = _pmin(nt, axis_name)
         sums = [jax.lax.psum(x, axis_name) for x in sums]
-        maxes = [jax.lax.pmax(x, axis_name) for x in maxes]
-        rounds = [jax.lax.pmax(x, axis_name) for x in rounds]
+        maxes = [_pmax(x, axis_name) for x in maxes]
+        rounds = [_pmax(x, axis_name) for x in rounds]
     now, qh, oh, xh = maxes
     (ov, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx, it, ll) = sums
     rl, ri, wn = rounds
@@ -1319,26 +1325,12 @@ class DeviceLossError(RuntimeError):
         self.injected = cause is None
 
 
-# XLA runtime failures the drivers translate into DeviceLossError: the
-# jaxlib XlaRuntimeError (surfacing device resets, DMA failures, dead
-# PJRT clients) and its public jax.errors alias. Deliberately NOT a
+# XLA runtime failures the drivers translate into DeviceLossError:
+# jax.errors.JaxRuntimeError (surfacing device resets, DMA failures, dead
+# PJRT clients). Deliberately NOT a
 # plain-RuntimeError catch — jax's "Array has been deleted" donation
 # error and engine bugs must keep propagating as what they are.
-def _device_error_types() -> tuple:
-    types = []
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-
-        types.append(XlaRuntimeError)
-    except Exception:  # pragma: no cover — jaxlib layout changed
-        pass
-    err = getattr(getattr(jax, "errors", None), "JaxRuntimeError", None)
-    if err is not None:
-        types.append(err)
-    return tuple(types)
-
-
-_DEVICE_ERROR_TYPES = _device_error_types()
+_DEVICE_ERROR_TYPES = (jax.errors.JaxRuntimeError,)
 
 # XLA status prefixes that plausibly mean a device/runtime died — the
 # ALLOWLIST the translation below keys on. Anything else (OOM,
@@ -1365,7 +1357,7 @@ def device_loss_from(err: BaseException, chunk: int) -> "DeviceLossError | None"
     drivers share (engine/ensemble.py _drive_ensemble probe fetch)."""
     if isinstance(err, DeviceLossError):
         return err
-    if _DEVICE_ERROR_TYPES and isinstance(err, _DEVICE_ERROR_TYPES):
+    if isinstance(err, _DEVICE_ERROR_TYPES):
         msg = str(err).lstrip()
         if any(msg.startswith(p) for p in _DEVICE_LOSS_STATUSES):
             return DeviceLossError(chunk, cause=err)
@@ -1391,22 +1383,33 @@ def effective_engine(cfg) -> str:
     """The engine an "auto" config actually runs — the single resolution
     seam run_round's engine selection, the chaos `compile` fault targets,
     and the fallback-ladder records all share (runtime/chaos.py,
-    runtime/scheduler.py). Resolution order (docs/megakernel.md):
+    runtime/scheduler.py). One rule on every backend (docs/megakernel.md
+    "Engine selection"):
 
       1. an explicit engine name always wins;
-      2. "auto" on a real (non-CPU) backend resolves to the megakernel —
-         safe as a default since the PR-8 fallback ladder degrades a
-         failed megakernel compile to pump/plain with bit-identical
-         results — except under the ensemble plane (cfg.ensemble), where
-         pallas_call is not exercised under vmap and "auto" resolves to
-         the pump;
-      3. "auto" on CPU (and under vmap) keeps the prior behavior: pump
-         when pump_k > 0, else plain.
+      2. "auto" is the pump when pump_k > 0, else the plain handler.
+
+    "auto" never means the megakernel: it is an interpret-mode-verified
+    design that the chip's compiler refuses (`ZeroDivisionError: integer
+    modulo by zero` from Mosaic's block-mapping check — the carry is
+    int64 and a 64-bit block has tiling 128 * (32 // 64) = 0), reachable
+    only by `engine: megakernel`, which on a TPU fails with that error.
+
+    What the rule rests on — compile walls of the whole chunk program
+    asked of the chip's compiler for a described v5e at the tgen-10k
+    world's 10,240 hosts (tools/compile_for_chip.py, PR 22, 8 cores
+    shared by 2-4 compiles): plain 152-182 s, pump k=2 241 s, k=4 257 s,
+    k=8 284 s; at 256 hosts plain 57 s, pump k=8 90 s. On the chip's own
+    host (chip_smoke.py, PR 22, TPU v5 lite): compile+launch plain 185 s,
+    pump k=8 279 s; after it, 500 ms of the world took plain 64.8 s and
+    the pump 67.4 s — no win for the pump, so pump_k stays an opt-in.
+    Before PR 22 re-spelled the int64 divides
+    (intmath.py) and the delivery-grid sorts (equeue.push_many_sorted),
+    plain took 735 s at 1,024 hosts and neither engine finished in
+    1,500 s at 10,240.
     """
     if cfg.engine != "auto":
         return cfg.engine
-    if not cfg.ensemble and jax.default_backend() != "cpu":
-        return "megakernel"
     return "pump" if cfg.pump_k > 0 else "plain"
 
 
@@ -1630,23 +1633,32 @@ def _try_get(arr):
         return False, e
 
 
-def _launch_chunk0(launch, st, tracker, engine: str):
-    """Chunk 0's launch is where the engine's chunk program traces and
-    compiles: wrap it in the shared compile seam (runtime/chaos.py
-    compile_seam) so a compile/trace failure (or an injected `compile`
-    chaos fault) surfaces as a typed EngineCompileError the fallback
-    ladder can act on. Driver-level exceptions pass through untouched —
-    only the first launch is compile territory."""
+def _launch_chunk0(launch, st, tracker, engine: str, compile_chunk=None):
+    """Chunk 0 is where the engine's chunk program traces and compiles.
+    `compile_chunk(st)` — the driver's jitted chunk lowered and compiled
+    ahead of time for this state — runs inside the shared compile seam
+    (runtime/chaos.py compile_seam), so a trace/compile failure (or an
+    injected `compile` chaos fault) surfaces as a typed
+    EngineCompileError the fallback ladder can act on. The launch itself
+    runs OUTSIDE the seam and finds the executable in JAX's in-memory
+    cache: what the device raises when the program runs (out of memory,
+    a lost device) stays what it is. A driver handed an executable that
+    was compiled elsewhere (the sweep cache's entry, compiled inside its
+    own seam) passes no compile_chunk; the seam is still entered so an
+    injected fault fires at the same place either way."""
     from shadow_tpu.runtime import chaos
 
-    with chaos.compile_seam(engine):
-        with _tspan(tracker, "compile+launch", chunk=0):
-            return launch(st)
+    with _tspan(tracker, "compile+launch", chunk=0):
+        with chaos.compile_seam(engine):
+            if compile_chunk is not None:
+                compile_chunk(st)
+        return launch(st)
 
 
 def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
            tracker=None, on_state=None, capacity_detail=None,
-           watchdog_s: float = 0.0, engine: str = "plain"):
+           watchdog_s: float = 0.0, engine: str = "plain",
+           compile_chunk=None):
     """The shared chunk-dispatch loop behind run_until and
     ShardedRunner.run_until.
 
@@ -1688,8 +1700,9 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
     that exceeds the deadline raises WatchdogExpired (the in-flight
     chunk is abandoned; runtime/recovery.py re-dispatches from the
     retained clean snapshot). `engine` labels the engine whose chunk
-    program chunk 0 compiles — a compile/trace failure there raises a
-    typed EngineCompileError for the fallback ladder. Both, plus the
+    program `compile_chunk` compiles before chunk 0 launches — a
+    compile/trace failure there raises a typed EngineCompileError for
+    the fallback ladder (_launch_chunk0). Both, plus the
     chaos plane's capacity/stall injections, are consulted through
     runtime/chaos.py hooks that cost one global read when no fault plan
     is installed.
@@ -1699,7 +1712,9 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
     # every _drive entry (first attempt, fallback rung, recovery replay)
     # restarts the cumulative probe lanes: new delta segment
     flightrec.begin_segment()
-    pend_st, pend_probe = _launch_chunk0(launch, st, tracker, engine)
+    pend_st, pend_probe = _launch_chunk0(
+        launch, st, tracker, engine, compile_chunk
+    )
     launched = 1
     fetched = 0  # index of the chunk whose probe is fetched next
     pending_snap = None  # (chunk_idx, host_state) awaiting its own probe
@@ -1868,8 +1883,10 @@ def run_until(
     # worlds that differ only in seed share one compiled executable
     jit_cfg = trace_static_cfg(cfg)
 
+    chunk_args = (end, rounds_per_chunk, model, tables, jit_cfg)
+
     def launch(s):
-        return _run_chunk_jit(s, end, rounds_per_chunk, model, tables, jit_cfg)
+        return _run_chunk_jit(s, *chunk_args)
 
     return _drive(
         launch, st, end_time, max_chunks, on_chunk, pipeline,
@@ -1877,6 +1894,7 @@ def run_until(
         tracker=tracker, on_state=on_state,
         capacity_detail=capacity_topk,
         watchdog_s=watchdog_s, engine=effective_engine(cfg),
+        compile_chunk=lambda s: _run_chunk_jit.lower(s, *chunk_args).compile(),
     )
 
 

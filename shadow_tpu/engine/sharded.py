@@ -8,7 +8,8 @@ on the host axis, each device drains its hosts' events independently within
 the conservative window (no collectives in the inner loop), and the only
 cross-device traffic per round is
 
-  * one pmin over ICI to agree on the next window, and
+  * one min over ICI to agree on the next window (an all_gather reduced
+    locally — the chip lowers no 64-bit all-reduce but Sum, round._pmin), and
   * one destination-bucketed all_to_all of the per-host packet outboxes
     (the exchange step — the analogue of the locked cross-host queue
     push, worker.rs:619-629; cfg.exchange selects all_to_all/all_gather).
@@ -22,23 +23,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # stable alias in newer jax
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-import inspect as _inspect
-
-# newer jax renamed the replication-check kwarg check_rep -> check_vma;
-# pass whichever this version accepts (the check stays off either way:
-# the chunk's probe output is made replicated by explicit collectives)
-_SHARD_MAP_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else "check_rep"
-)
 
 from shadow_tpu.engine.round import (
     _drive,
@@ -176,7 +162,9 @@ class ShardedRunner:
             mesh=self.mesh,
             in_specs=(specs, tspecs, P()),
             out_specs=(specs, P()),
-            **{_SHARD_MAP_CHECK_KW: False},
+            # the replication check stays off: the chunk's probe output
+            # is made replicated by explicit collectives
+            check_vma=False,
         )
         # the sharded state is donated chunk-to-chunk, same as the
         # single-device driver (run_until feeds only its private copy)
@@ -267,4 +255,7 @@ class ShardedRunner:
             tracker=tracker, on_state=on_state,
             capacity_detail=self._capacity_detail,
             watchdog_s=watchdog_s, engine=effective_engine(self.cfg),
+            compile_chunk=lambda s: self._compiled.lower(
+                s, self.tables, end
+            ).compile(),
         )

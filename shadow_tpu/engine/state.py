@@ -67,8 +67,9 @@ class EngineConfig:
     # contract (delivery slot order is key-driven; engine/round.py
     # flush_outbox):
     #   dense  — route packets into a dest-major [H, deliver_lanes] grid
-    #            via three multi-operand sorts (equeue.push_many_sorted)
-    #            and merge it with fused per-lane selects. "all_to_all"
+    #            (one index sort by destination, the payload following
+    #            as packed rows — equeue.push_many_sorted) and merge it
+    #            with fused per-lane selects. "all_to_all"
     #            (default) buckets outbox entries by destination shard
     #            and exchanges only each peer's bucket over ICI;
     #            "all_gather" replicates every shard's whole outbox
@@ -110,11 +111,16 @@ class EngineConfig:
     # into outbox overflow (check_capacity names this knob).
     pool_capacity: int = 0
     # Round-boundary delivery grid width: the exchange routes packets into
-    # a dest-major [H, deliver_lanes] grid via three multi-operand sorts
-    # (equeue.push_many_sorted) and merges it densely — zero scatters.
-    # XLA TPU scatter serializes per index (~125 ms/round at bench scale,
-    # the dominant engine cost, tools/profile_flush.py) while full-payload
-    # sorts of the same entries are ~4 ms (tools/profile_prims.py).
+    # a dest-major [H, deliver_lanes] grid (equeue.push_many_sorted: one
+    # (destination, position) sort, then one row gather and one row
+    # scatter of the packed payload) and merges it densely. An earlier
+    # spelling carried the whole payload through the sorts because XLA
+    # TPU scatter serializes per index (round-3 profile: ~125 ms/round
+    # for five per-column scatters vs ~4 ms for full-payload sorts); the
+    # chip's compiler takes ~14 s per operand word of such a sort, which
+    # no front-door run could afford (PR 22). tools/profile_landing.py
+    # times both spellings and the new one's pieces on the chip; the
+    # numbers are in CHANGES.md (PR 22) and ROADMAP speed item 2.
     # Bounds deliveries per host per ROUND; beyond it overflows loudly
     # via check_capacity. 0 (default) = queue_capacity: exact — a
     # delivery wave the queue could hold can never be grid-bounded.
@@ -137,9 +143,10 @@ class EngineConfig:
     # to the unpumped engine (tests/test_pump.py).
     pump_k: int = 0
     # Engine selection for the round drain loop:
-    #   "auto"       — current behavior: the pump microscan when pump_k > 0
-    #                  and the model is pump-capable, else the plain
-    #                  one-event-per-host handler loop.
+    #   "auto"       — on every backend: the pump microscan when
+    #                  pump_k > 0 and the model is pump-capable, else the
+    #                  plain one-event-per-host handler loop; never the
+    #                  megakernel (engine/round.py effective_engine).
     #   "plain"      — always the full handler, even with pump_k set.
     #   "pump"       — the XLA pump microscan (requires pump_k > 0).
     #   "megakernel" — the fused Pallas round megakernel
@@ -148,7 +155,9 @@ class EngineConfig:
     #                  one kernel launch per iteration (pump_k defaults to
     #                  8 when unset). Falls back to the plain handler for
     #                  models without a pump_spec. Bit-identical results
-    #                  across all four values (tests/test_megakernel.py).
+    #                  across all four values (tests/test_megakernel.py)
+    #                  — interpreted on the CPU; the chip's compiler
+    #                  refuses the kernel today (docs/megakernel.md).
     engine: str = "auto"
     # Megakernel host-tile rows per Pallas program (the VMEM working-set
     # knob; see docs/megakernel.md for the byte budget). 0 = auto: the

@@ -269,8 +269,8 @@ def push_many(
 
     This is the round-boundary exchange step (the analogue of
     Worker::push_packet_to_host, reference src/main/core/worker.rs:619-629,
-    minus the mutex). Delegates to the all-sort implementation with a
-    full-capacity delivery grid (exact, never grid-bounded)."""
+    minus the mutex). Delegates to the delivery-grid implementation with
+    a full-capacity grid (exact, never grid-bounded)."""
     return push_many_sorted(
         q, dst, valid, time, tie, kind, data, aux,
         deliver_lanes=q.capacity,
@@ -288,51 +288,33 @@ def push_many_sorted(
     aux: "jax.Array | None" = None,  # [M] i32
     deliver_lanes: int = 48,
 ) -> EventQueue:
-    """push_many built entirely on multi-operand sorts — zero scatters,
-    zero large gathers.
+    """push_many through a dest-major delivery grid [H, D] (D =
+    deliver_lanes), merged into the queue rows by the dense
+    push_self_lanes pattern:
 
-    XLA TPU scatter/gather serialize per index (~40-130 ns each; the five
-    scatters of the plain push_many cost ~125 ms per round at bench
-    scale), while a full-payload lax.sort of the same entries is ~4 ms
-    (tools/profile_prims.py). So the exchange becomes:
+      S   one stable sort of (destination, position) — two words per
+          entry, invalids last; per-destination ranks fall out of a dense
+          segment cummax over the sorted keys;
+      G   the payload follows as packed 32-bit rows: one row gather into
+          sorted order, one row scatter to grid slot dst * D + rank.
+          Entries that do not fit (rank >= D) and invalid ones get an
+          out-of-bounds slot and are dropped by the scatter; slots are
+          unique among fitting entries (ranks within a destination are
+          distinct), so no entry can land on another host's row or on an
+          occupied slot, overflow or not.
 
-      S1  stable sort of everything by destination (invalids last) —
-          per-destination ranks fall out of a dense segment cummax;
-      S2  stable sort by final grid slot: real entry i -> dst*D + rank
-          (D = deliver_lanes), invalid entries -> the ascending
-          enumeration of unfilled grid slots (computed densely; aligned
-          to the invalid positions by one dynamic_slice) — the first H*D
-          sorted entries ARE the dest-major delivery grid [H, D];
-      S3  a light (key, slot) sort that enumerates the unfilled slots.
+    Per-host deliveries beyond D or queue capacity are counted loudly in
+    overflow. Slot order within a destination equals arrival order of
+    the stable sort; pop order is key-driven anyway.
 
-    The grid merges into the queue rows with the push_self_lanes dense
-    one-hot pattern (per-host append, fused selects). Per-host deliveries
-    beyond D or queue capacity are counted loudly in overflow. Slot
-    order within a destination equals arrival order of the stable sort —
-    the same order plain push_many produced; pop order is key-driven
-    anyway.
-
-    Overflow safety: when a destination receives more than D entries the
-    filler enumeration can run short (fewer invalid entries than unfilled
-    grid slots), which would shift later fitting entries onto earlier grid
-    positions. Two defenses (round-4 advisor, high):
-
-      * the S2 key switches, via lax.cond on the exact shortfall
-        predicate, to a repair assignment that hands every grid slot to
-        exactly one entry (fitting entries to their target slots via a
-        permutation sort of the slots by source position; non-fitting
-        entries claim the unfilled slots). The repair needs two m-wide
-        gathers, paid ONLY on the (always loud, check_capacity-fatal)
-        overflow path — the common path is the plain filler arithmetic;
-      * belt-and-braces, the destination id rides through S2 (it IS the
-        S1 key, one extra sort operand) and the grid rejects any entry
-        whose carried destination differs from the row it landed on.
-
-    Net: a delivery is either on its correct host with its exact payload
-    or counted in overflow; hosts within their lane budget receive
-    everything even while another destination overflows. Within-row lane
-    shifts are harmless (pop order is key-driven, lane position carries
-    no meaning).
+    Why the payload does not ride the sort: XLA:TPU's sort costs the
+    chip's compiler ~14 s per 32-bit operand word once the array no
+    longer sorts in one tile (> 16k entries) — the former spelling
+    carried all 16 payload words through two sorts of max(M, H*D)
+    entries plus two index sorts to enumerate the unfilled slots, ~10
+    minutes of compile for this function alone at any real world size
+    (tools/compile_for_chip.py, CHANGES.md PR 22). Grid contents at valid
+    slots, and so every queue leaf, are identical to that spelling.
     """
     if aux is None:
         aux = jnp.zeros_like(kind)
@@ -343,142 +325,46 @@ def push_many_sorted(
     # capacity — at traffic scale for small-M callers like hybrid uploads)
     d = min(deliver_lanes, m)
     grid = h * d
-    big = jnp.int32(1 << 30)
 
-    # pad so every grid slot can receive a filler entry (empty payload)
-    mp = max(m, grid)
-    if mp > m:
-        pad = mp - m
-
-        def padded(x, fill):
-            cst = jnp.full((pad,) + x.shape[1:], fill, x.dtype)
-            return jnp.concatenate([x, cst])
-
-        dst = padded(dst, 0)
-        valid = padded(valid, False)
-        time = padded(time, TIME_MAX)
-        tie = padded(tie, _I64_MAX)
-        kind = padded(kind, KIND_INVALID)
-        data = padded(data, 0)
-        aux = padded(aux, 0)
-
-    # S1: group by destination (stable; invalids/pad sort last)
+    # S: group by destination (stable; invalids sort last)
     key1 = jnp.where(valid, dst, h).astype(jnp.int32)
-    key1_s, time_s, tie_s, kind_s, aux_s, valid_s, *data_cols = jax.lax.sort(
-        (key1, time, tie, kind, aux, valid)
-        + tuple(data[:, i] for i in range(data.shape[1])),
-        num_keys=1,
-        is_stable=True,
-    )
-    pos = jnp.arange(mp, dtype=jnp.int32)
+    pos = jnp.arange(m, dtype=jnp.int32)
+    key1_s, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
     seg_start = jnp.concatenate(
         [jnp.ones((1,), bool), key1_s[1:] != key1_s[:-1]]
     )
     rank = pos - jax.lax.cummax(jnp.where(seg_start, pos, -1))
-    real = valid_s
-    n_valid = jnp.sum(real.astype(jnp.int32))
+    fits = (key1_s < h) & (rank < d)
+    slot = jnp.where(fits, key1_s * d + rank, grid)  # OOB -> dropped
 
-    # per-destination delivery counts (for the unfilled-slot enumeration);
-    # one searchsorted over [0..H] gives every segment boundary (stop of
-    # host h == start of host h+1)
-    hosts = jnp.arange(h + 1, dtype=jnp.int32)
-    bounds = jnp.searchsorted(key1_s, hosts, side="left", method="sort")
-    cnt = jnp.minimum((bounds[1:] - bounds[:-1]).astype(jnp.int32), d)  # [H]
+    # G: packed rows [time(2) | tie(2) | kind | aux | used | data...]
+    def words(x):  # i64 [M] -> i32 [M, 2], bit-exact
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
 
-    # S3: ascending enumeration of unfilled grid slots
-    lane_r = jnp.arange(d, dtype=jnp.int32)[None, :]
-    unfilled = (lane_r >= cnt[:, None]).reshape(grid)
-    filler_key = jnp.where(
-        unfilled, jnp.cumsum(unfilled.astype(jnp.int32)) - 1, big
+    rows = jnp.concatenate(
+        [words(time), words(tie), kind[:, None], aux[:, None],
+         jnp.ones((m, 1), jnp.int32), data],
+        axis=1,
     )
-    _, fill_pos = jax.lax.sort(
-        (filler_key, jnp.arange(grid, dtype=jnp.int32)), num_keys=1,
-        is_stable=True,
-    )
-    # positions past the unfilled count hold FILLED slots (their filler_key
-    # was the sentinel); a leftover invalid entry picking one up would
-    # collide with the real entry targeting that slot and shift the whole
-    # grid — replace them with unique beyond-grid keys
-    n_unfilled = jnp.sum(unfilled.astype(jnp.int32))
-    gpos = jnp.arange(grid, dtype=jnp.int32)
-    fill_pos = jnp.where(gpos < n_unfilled, fill_pos, big + gpos)
-    # align filler slots with the invalid positions (which are contiguous
-    # from n_valid): key2[p] for an invalid at position p must read
-    # fill_pos[p - n_valid] — one dynamic_slice, no gather
-    fill_pad = jnp.concatenate(
-        [jnp.zeros((mp,), jnp.int32), fill_pos,
-         big + jnp.arange(mp, dtype=jnp.int32)]
-    )
-    fill_for_pos = jax.lax.dynamic_slice(fill_pad, (mp - n_valid,), (mp,))
-
-    fits = real & (rank < d)
-    target = key1_s * d + rank
-
-    def _key2_common(_):
-        # fillers exactly cover the unfilled slots (no overflow anywhere)
-        return jnp.where(
-            fits, target, jnp.where(real, big + pos, fill_for_pos)
-        ).astype(jnp.int32)
-
-    def _key2_repair(_):
-        # Exact slot assignment via a slot-permutation: sort grid slots by
-        # the S1 position they want to read (filled slot (dst, lane) wants
-        # position bounds[dst] + lane; unfilled slots sort after, in slot
-        # order), then entry with fitting-rank j takes pi[j] and the k-th
-        # non-fitting entry claims pi[n_fit + k] — every slot claimed
-        # exactly once, so no entry can shift rows even under overflow.
-        src_pos = jnp.where(
-            lane_r < cnt[:, None], bounds[:-1][:, None] + lane_r, 0
-        ).reshape(grid)
-        src_key = jnp.where(
-            unfilled, big + jnp.arange(grid, dtype=jnp.int32), src_pos
-        )
-        _, pi = jax.lax.sort(
-            (src_key, jnp.arange(grid, dtype=jnp.int32)), num_keys=1,
-            is_stable=True,
-        )
-        pi_pad = jnp.concatenate([pi, big + jnp.arange(mp, dtype=jnp.int32)])
-        fits_i = fits.astype(jnp.int32)
-        rank_fit = jnp.cumsum(fits_i) - fits_i
-        n_fit = jnp.sum(fits_i)
-        rank_nonfit = pos - rank_fit
-        idx = jnp.where(fits, rank_fit, n_fit + rank_nonfit)
-        return pi_pad[jnp.minimum(idx, grid + mp - 1)]
-
-    # fillers run short iff total overflow exceeds the padding slack —
-    # only then pay the repair gathers (the run is already doomed loudly)
-    shortfall = (grid - jnp.sum(cnt)) - (mp - n_valid)
-    key2 = jax.lax.cond(shortfall > 0, _key2_repair, _key2_common, None)
-
-    # S2: place into grid order; the first H*D entries are the grid.
-    # key1_s (== dst for valid entries) rides along so landing rows can be
-    # validated below — see the overflow-safety note in the docstring.
-    _, time_g, tie_g, kind_g, aux_g, used_g, dst_g, *data_g = jax.lax.sort(
-        (key2, time_s, tie_s, kind_s, aux_s, fits, key1_s)
-        + tuple(data_cols),
-        num_keys=1,
-        is_stable=True,
+    g = (
+        jnp.zeros((grid, rows.shape[1]), jnp.int32)
+        .at[slot]
+        .set(rows[order], mode="drop")
+        .reshape(h, d, rows.shape[1])
     )
 
-    def to_grid(x):
-        return x[:grid].reshape(h, d)
+    def long(x):  # i32 [H, D, 2] -> i64 [H, D]
+        return jax.lax.bitcast_convert_type(x, jnp.int64)
 
-    g_valid = to_grid(used_g) & (
-        to_grid(dst_g) == jnp.arange(h, dtype=jnp.int32)[:, None]
-    )
-    g_time = to_grid(time_g)
-    g_tie = to_grid(tie_g)
-    g_kind = to_grid(kind_g)
-    g_aux = to_grid(aux_g)
-    g_data = jnp.stack([to_grid(c) for c in data_g], axis=-1)
-
+    g_valid = g[:, :, 6] != 0
+    n_valid = jnp.sum(valid.astype(jnp.int32))
     overflow_extra = (n_valid - jnp.sum(g_valid.astype(jnp.int32))).astype(
         jnp.int32
     )
 
     q2 = push_self_lanes(
-        q, valid=g_valid, time=g_time, tie=g_tie, kind=g_kind,
-        data=g_data, aux=g_aux,
+        q, valid=g_valid, time=long(g[:, :, 0:2]), tie=long(g[:, :, 2:4]),
+        kind=g[:, :, 4], data=g[:, :, 7:], aux=g[:, :, 5],
     )
     # per-destination overflow beyond deliver_lanes is counted globally
     # (loud via check_capacity), not per host
@@ -499,9 +385,9 @@ def push_many_segment(
     destination sort + ragged segment offsets + an M-sized free-slot
     scatter, instead of push_many_sorted's [H, D] delivery grid.
 
-    Where the dense path enumerates a full dest-major grid (three sorts
-    over max(M, H*D) entries and a D-deep select chain per queue array),
-    this lands the M in-flight entries directly:
+    Where the dense path builds a full dest-major [H, D] grid and merges
+    it with a D-deep select chain per queue array, this lands the M
+    in-flight entries directly:
 
       S1  stable sort of everything by destination (invalids last) —
           per-destination ranks from a dense segment cummax, and the

@@ -19,7 +19,9 @@ host axis; no extra events are ever created for the relay itself.
 
 Determinism: all bucket math is int64; CoDel's `interval / sqrt(count)`
 uses a precomputed int64 table so CPU-reference and TPU timelines agree
-bit-for-bit.
+bit-for-bit. The buckets' division by the per-host refill goes through
+intmath.divmod_nonneg (exact, and cheap for the chip's compiler where an
+int64 `//` by a variable is not); divisions by a constant stay `//`.
 """
 
 from __future__ import annotations
@@ -29,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from shadow_tpu.intmath import divmod_nonneg
 from shadow_tpu.simtime import NS_PER_MS
 
 # Reference constants: refill every 1 ms (relay/mod.rs:286), CoDel TARGET
@@ -171,7 +174,7 @@ def tb_depart(tokens, last, refill, now, size, charge):
 
     # wait k more intervals until the deficit is covered (k = 0 if none)
     deficit = jnp.maximum(size - cur, 0)
-    k = (deficit + safe_refill - 1) // safe_refill
+    k, _ = divmod_nonneg(deficit + safe_refill - 1, safe_refill)
     depart = jnp.where(deficit > 0, cur_last + k * REFILL_INTERVAL_NS, now)
     tokens_out = cur + k * safe_refill - size
     last_out = jnp.where(deficit > 0, cur_last + k * REFILL_INTERVAL_NS, cur_last)
@@ -206,7 +209,7 @@ def tb_depart_lanes(tokens, last, refill, now, sizes, charge):
 
     pref = jnp.cumsum(jnp.where(limited, sizes, 0), axis=1)
     deficit = jnp.maximum(pref - cur[:, None], 0)
-    k = (deficit + (safe_refill - 1)[:, None]) // safe_refill[:, None]
+    k, _ = divmod_nonneg(deficit + (safe_refill - 1)[:, None], safe_refill[:, None])
     # "departs at now" follows the SEQUENTIAL deficit — tokens left over
     # from an earlier lane's interval refill can cover a later lane
     # immediately (tb_depart returns `now` whenever the running balance

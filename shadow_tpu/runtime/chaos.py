@@ -346,12 +346,16 @@ def injected_device_loss(at, spec: "FaultSpec | None" = None):
 @contextlib.contextmanager
 def compile_seam(engine: str):
     """The one compile-failure seam behind every engine-compile site —
-    _drive's chunk-0 launch (engine/round.py _launch_chunk0) and the
-    EnsembleRunner's AOT cache fill (runtime/ensemble.py _launch_for):
-    fires an injected `compile` fault targeting `engine`, passes
-    driver-level control exceptions through untouched, and wraps
-    anything else in a typed EngineCompileError the fallback ladder can
-    act on. Shared so the two seams can never drift."""
+    the ahead-of-time compile before _drive's chunk-0 launch
+    (engine/round.py _launch_chunk0) and the runners' AOT cache fill
+    (runtime/ensemble.py / runtime/mesh.py _launch_for): fires an
+    injected `compile` fault targeting `engine`, passes driver-level
+    control exceptions through untouched, and wraps anything else in a
+    typed EngineCompileError the fallback ladder can act on. Only
+    tracing, lowering and compiling happen inside it — never the
+    launch — so an error the device raises when the program runs (out of
+    memory, a JaxRuntimeError of a lost device) is never relabelled a
+    compile failure."""
     from shadow_tpu.engine.round import (
         CapacityError,
         DeviceLossError,
@@ -416,13 +420,15 @@ def next_engine_cfg(cfg):
     return None
 
 
-def run_with_engine_ladder(cfg, attempt, on_fallback=None):
+def run_with_engine_ladder(cfg, attempt, on_fallback=None, fail_fast=False):
     """Run `attempt(cfg)`, downgrading the engine one rung per
     EngineCompileError until plain fails too (then the original error
     propagates — a structured, named failure). Returns
     (attempt result, fallback records). Each record lands in
     sim-stats.json's `degraded` section and bench's salvage line, so a
-    degraded run is visibly degraded, never silently slower."""
+    degraded run is visibly degraded, never silently slower. With
+    `fail_fast` (--no-recover: the runners pass `recovery is None`) the
+    first EngineCompileError propagates and no rung is walked."""
     from shadow_tpu.engine.round import EngineCompileError
 
     fallbacks: "list[dict]" = []
@@ -430,7 +436,7 @@ def run_with_engine_ladder(cfg, attempt, on_fallback=None):
         try:
             return attempt(cfg), fallbacks
         except EngineCompileError as err:
-            nxt = next_engine_cfg(cfg)
+            nxt = None if fail_fast else next_engine_cfg(cfg)
             if nxt is None:
                 raise
             rec = {

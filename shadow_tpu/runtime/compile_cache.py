@@ -31,6 +31,11 @@ jax version and backend platform, so a restarted daemon pays zero XLA
 recompiles for worlds it has already compiled — and a corrupt,
 truncated, or version-mismatched entry degrades to a recompile with a
 warning, never a crash (the `cache-corrupt` chaos fault pins this).
+
+Below both sits JAX's own persistent compilation cache, which serves
+every jit of the process (`shadow-tpu run` has no executable tier of its
+own). `place_persistent_cache` is the one function that decides its
+directory; cli.main calls it before the first compile.
 """
 
 from __future__ import annotations
@@ -47,7 +52,29 @@ from shadow_tpu.utils.shadow_log import slog
 
 # bumped when the on-disk entry layout changes; a mismatch is a skip
 # (recompile), never an error
-CACHE_FORMAT = 1
+CACHE_FORMAT = 2
+
+
+def place_persistent_cache() -> "str | None":
+    """Decide where JAX's persistent compilation cache lives — the only
+    place in the program that does. Where JAX_COMPILATION_CACHE_DIR is
+    set, JAX reads it itself and nothing is set in code (returns None);
+    where it is not, the cache goes to `<checkout>/.jax_cache`
+    (git-ignored): a fixed path, never a temp name, pid or time, because
+    the path is part of what makes the next process find the entries.
+    Every compile is kept, however short, so a second run of the same
+    command adds no entry (chip_smoke.py checks exactly that). Whether the
+    cache is used at all stays JAX's own switch
+    (jax_enable_compilation_cache; the tests turn it off)."""
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    checkout = os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    )
+    path = os.path.join(checkout, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def state_signature(st) -> tuple:
@@ -103,11 +130,19 @@ class CompileCache:
         exe = self._load_persisted(fk)
         if exe is not None:
             # a disk hit is a hit — the whole point is zero recompiles
-            # across daemon restarts
+            # across daemon restarts. It stays on probation until its
+            # first call returns: an entry that loads but cannot run
+            # degrades to a recompile like every other bad entry.
             self.hits += 1
+            exe = _OnProbation(self, fk, exe, build)
             self._entries[fk] = exe
             flightrec.record_event("compile_cache", hit=True, tier="disk")
             return exe
+        return self._compile(fk, build)
+
+    def _compile(self, fk, build):
+        from shadow_tpu.runtime import flightrec, memtrack
+
         t0 = time.perf_counter()
         exe = build()
         wall = time.perf_counter() - t0
@@ -119,8 +154,6 @@ class CompileCache:
         # reports it, the executable's peak HBM (runtime/memtrack.py) —
         # is a first-class event in the metrics stream
         ev = {"hit": False, "wall_s": round(wall, 4)}
-        from shadow_tpu.runtime import memtrack
-
         mem = memtrack.compiled_memory(exe)
         if mem and mem.get("peak_bytes"):
             self.compile_peaks.append(int(mem["peak_bytes"]))
@@ -134,6 +167,9 @@ class CompileCache:
         return None
 
     def _persist(self, fk, exe) -> None:
+        pass
+
+    def _loaded_cannot_run(self, fk, err) -> None:
         pass
 
     @property
@@ -155,6 +191,32 @@ class CompileCache:
         if self.compile_peaks:
             out["peak_hbm_bytes"] = max(self.compile_peaks)
             out["compile_peaks"] = self.compile_peaks
+        return out
+
+
+class _OnProbation:
+    """A disk-loaded executable until its first call has returned. If
+    that call raises, the entry is evicted with the usual warning, the
+    program is compiled afresh (a counted miss) and the call is made
+    again on the new executable; after one good call the wrapper steps
+    aside. A failure of the retry propagates as what it is."""
+
+    def __init__(self, cache: CompileCache, fk, exe, build):
+        self._cache, self._fk, self._exe, self._build = cache, fk, exe, build
+        self._proven = False
+
+    def __call__(self, *args):
+        if self._proven:
+            return self._exe(*args)
+        try:
+            out = self._exe(*args)
+        except Exception as e:  # noqa: BLE001 — any failure = recompile
+            self._cache._loaded_cannot_run(self._fk, e)
+            self._exe = self._cache._compile(self._fk, self._build)
+            out = self._exe(*args)
+        self._proven = True
+        self._build = None  # the closure holds the caller's whole state
+        self._cache._entries[self._fk] = self._exe
         return out
 
 
@@ -239,9 +301,15 @@ class PersistentCompileCache(CompileCache):
                  "sha-256 integrity check; recompiling")
             return None
         try:
+            # the executable runs on the devices it was compiled for:
+            # left to its default, deserialize_and_load spreads a
+            # one-device program over every visible device, and the first
+            # call then fails on the shard count
+            by_id = {d.id: d for d in jax.devices()}
+            devices = [by_id[i] for i in header["devices"]]
             serialized, in_tree, out_tree = pickle.loads(payload)
             exe = serialize_executable.deserialize_and_load(
-                serialized, in_tree, out_tree
+                serialized, in_tree, out_tree, execution_devices=devices
             )
         except Exception as e:  # noqa: BLE001 — any load failure = recompile
             self.disk_skips += 1
@@ -253,6 +321,17 @@ class PersistentCompileCache(CompileCache):
             return None
         self.disk_hits += 1
         return exe
+
+    def _loaded_cannot_run(self, fk, err) -> None:
+        path = self._entry_path(fk)
+        self.disk_hits -= 1
+        self.hits -= 1
+        self.disk_skips += 1
+        self._evict(path)
+        slog("warning", 0, "cache",
+             f"persistent compile-cache entry {path} loaded but failed "
+             f"its first call ({type(err).__name__}: {str(err)[:120]}); "
+             "recompiling")
 
     def _persist(self, fk, exe) -> None:
         from jax.experimental import serialize_executable
@@ -269,6 +348,7 @@ class PersistentCompileCache(CompileCache):
             return
         try:
             payload = pickle.dumps(serialize_executable.serialize(exe))
+            devices = [d.id for d in exe.runtime_executable().local_devices()]
         except Exception as e:  # noqa: BLE001 — persistence is best-effort
             self.disk_skips += 1
             slog("warning", 0, "cache",
@@ -281,6 +361,7 @@ class PersistentCompileCache(CompileCache):
             "runtime": self.runtime_version,
             "sha256": hashlib.sha256(payload).hexdigest(),
             "bytes": len(payload),
+            "devices": devices,
         }
         tmp = f"{path}.tmp.{os.getpid()}"
         try:

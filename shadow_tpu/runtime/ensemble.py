@@ -153,8 +153,10 @@ class EnsembleRunner:
         SLOWEST replica quiesces; finished replicas idle as identity
         no-ops). Mirrors TpuScheduler.run — including the engine
         fallback ladder (already at pump under vmap, so the only rung
-        left is pump → plain; bit-identical either way) — with the
-        regrow step vmapped over the replica axis."""
+        left is pump → plain; bit-identical either way), walked only
+        when a `recovery` policy is given: `recovery=None` is fail-fast,
+        the first EngineCompileError propagates — with the regrow step
+        vmapped over the replica axis."""
         from shadow_tpu.runtime.chaos import run_with_engine_ladder
         from shadow_tpu.runtime.recovery import (
             RecoveryPolicy,
@@ -185,6 +187,7 @@ class EnsembleRunner:
             (final, report), _ = run_with_engine_ladder(
                 self.cfg, attempt,
                 on_fallback=self.engine_fallbacks.append,
+                fail_fast=recovery is None,
             )
         except Exception as err:
             # keep the partial degradation record on failure: recoveries

@@ -32,6 +32,11 @@ from shadow_tpu.simtime import NS_PER_SEC, fmt_time_ns
 from shadow_tpu.utils.shadow_log import slog
 
 
+# per-host counters both the device state and the scalar oracle keep;
+# published as sim-stats.json's `per_host` block
+PER_HOST_COUNTERS = ("events_handled", "packets_sent", "packets_dropped")
+
+
 @dataclasses.dataclass
 class HostInstance:
     """One expanded simulated host (reference: HostInfo, sim_config.rs:96)."""
@@ -738,7 +743,9 @@ class Manager:
         if isinstance(sched, CpuRefScheduler):
             results = SimResults(
                 hosts=self.hosts,
-                events_handled=len(final.trace),
+                # as the engine counts them: arrivals the ingress relay
+                # deferred or dropped are in the oracle's trace, not here
+                events_handled=sum(final.events_handled),
                 packets_sent=sum(final.packets_sent),
                 packets_dropped=sum(final.packets_dropped),
                 packets_unroutable=0,
@@ -757,6 +764,13 @@ class Manager:
                 sim_seconds=end / NS_PER_SEC,
                 scheduler=sched.name,
             )
+        if replicas <= 1:
+            # the per-host counters the device state and the oracle both
+            # keep: what a parity check (chip_smoke.py) compares leaf for leaf
+            results.extra_stats["per_host"] = {
+                k: np.asarray(getattr(final, k)).tolist()
+                for k in PER_HOST_COUNTERS
+            }
         report = getattr(sched, "recovery_report", [])
         if report:
             # rollback-and-regrow happened: surface it in sim-stats.json
@@ -773,6 +787,24 @@ class Manager:
             results.extra_stats["degraded"] = {
                 "engine_fallbacks": list(fallbacks),
                 "watchdog_redispatches": watchdogs,
+            }
+        if not isinstance(sched, CpuRefScheduler):
+            # where the run really ran, and on which engine (the last
+            # fallback's when the ladder acted): a CPU run is visibly a
+            # CPU run, and chip_smoke.py reads the device from here
+            # because its own process never touches JAX
+            import jax
+
+            dev = jax.devices()[0]
+            results.extra_stats["device"] = {
+                "platform": dev.platform,
+                "kind": dev.device_kind,
+                "count": len(jax.devices()),
+                "ids": sorted(d.id for d in final.seq.devices()),
+                "engine": (
+                    fallbacks[-1]["to"] if fallbacks
+                    else getattr(sched, "engine", None)
+                ),
             }
         if autotune_plan is not None:
             # what the autotuner decided and on what evidence — an

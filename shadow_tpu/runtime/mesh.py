@@ -255,8 +255,10 @@ class MeshRunner:
             recovery=None):
         """Run the whole mesh batch to end_time_ns (the driver stops
         when the slowest replica quiesces). Mirrors EnsembleRunner.run —
-        engine fallback ladder, recovery loop with the whole-batch
-        regrow — with the chunk dispatch on the 2-D mesh."""
+        engine fallback ladder (walked only with a `recovery` policy;
+        `recovery=None` fails fast on the first EngineCompileError),
+        recovery loop with the whole-batch regrow — with the chunk
+        dispatch on the 2-D mesh."""
         from shadow_tpu.runtime.chaos import run_with_engine_ladder
         from shadow_tpu.runtime.recovery import (
             RecoveryPolicy,
@@ -291,6 +293,7 @@ class MeshRunner:
             (final, report), _ = run_with_engine_ladder(
                 self.cfg, attempt,
                 on_fallback=self.engine_fallbacks.append,
+                fail_fast=recovery is None,
             )
         except Exception as err:
             self.recovery_report = list(getattr(err, "recoveries", []))

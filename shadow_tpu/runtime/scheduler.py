@@ -50,13 +50,14 @@ class TpuScheduler:
         while n > 1 and cfg.num_hosts % n != 0:
             n -= 1
         self.num_devices = n
-        # the engine run_round actually executes on THIS backend for THIS
-        # model ("auto" resolves megakernel-first on real accelerators —
-        # engine/round.py effective_engine, docs/megakernel.md "Engine
-        # selection"), mirroring run_round's own substitutions so the
-        # start log never advertises a faster engine than runs: models
-        # the fast paths can't honor take the plain handler, and sharded
-        # runs keep the XLA pump (pallas_call under shard_map untested)
+        # the engine run_round actually executes for THIS model ("auto"
+        # is pump when pump_k > 0, else plain — engine/round.py
+        # effective_engine, docs/megakernel.md "Engine selection"),
+        # mirroring run_round's own substitutions so the start log never
+        # advertises a faster engine than runs: models the fast paths
+        # can't honor take the plain handler, and sharded runs of an
+        # explicit megakernel keep the XLA pump (pallas_call under
+        # shard_map untested)
         self.engine = effective_engine(cfg)
         if not model_pump_capable(model):
             self.engine = "plain"
@@ -130,12 +131,16 @@ class TpuScheduler:
         """Run to end_time_ns. `start_state` (a restored checkpoint)
         replaces the bootstrapped t=0 state; `checkpoints` /`guard` tap
         chunk-boundary states (runtime/checkpoint.py); `recovery` (a
-        RecoveryPolicy, None = fail-fast) turns CapacityError into
-        rollback-and-regrow. A compile/trace failure of the selected
-        engine walks the fallback ladder (megakernel → pump → plain,
-        bit-identical results) instead of failing the run; the fallback
-        records of the last run are left on self.engine_fallbacks and
-        the recovery report on self.recovery_report."""
+        RecoveryPolicy) turns CapacityError into rollback-and-regrow, and
+        with one a compile/trace failure of the selected engine walks the
+        fallback ladder (megakernel → pump → plain, bit-identical
+        results) instead of failing the run. `recovery=None` — what
+        --no-recover and every programmatic caller that passes no policy
+        get — is fail-fast for both: the first
+        CapacityError or EngineCompileError propagates and no rung is
+        walked. The fallback records of the last run are left on
+        self.engine_fallbacks and the recovery report on
+        self.recovery_report."""
         from shadow_tpu.runtime.chaos import run_with_engine_ladder
         from shadow_tpu.runtime.recovery import (
             RecoveryPolicy,
@@ -166,6 +171,7 @@ class TpuScheduler:
             (final, report), _ = run_with_engine_ladder(
                 self.cfg, attempt,
                 on_fallback=self.engine_fallbacks.append,
+                fail_fast=recovery is None,
             )
         except Exception as err:
             # keep the partial degradation record on failure (mirrors

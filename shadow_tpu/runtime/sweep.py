@@ -56,7 +56,7 @@ from shadow_tpu.engine.round import (
     host_stats,
 )
 from shadow_tpu.runtime.compile_cache import CompileCache
-from shadow_tpu.runtime.manager import Manager, SimResults
+from shadow_tpu.runtime.manager import PER_HOST_COUNTERS, Manager, SimResults
 from shadow_tpu.simtime import NS_PER_SEC, fmt_time_ns
 from shadow_tpu.utils.shadow_log import slog
 
@@ -1005,6 +1005,9 @@ class SweepService:
             sim_seconds=end / NS_PER_SEC,
             scheduler="tpu",
         )
+        results.extra_stats["per_host"] = {
+            k: sl_hs[k].tolist() for k in PER_HOST_COUNTERS
+        }
         if recovery_report:
             results.extra_stats["recovery"] = {
                 "count": len(recovery_report),

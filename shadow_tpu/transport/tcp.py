@@ -361,10 +361,22 @@ def app_close(ts: TcpState, mask, slot) -> TcpState:
 # --- RTT / RTO (RFC 6298, tcp.c:1135-1170) -------------------------------
 
 
+def ca_increment(mss: int, cwnd: jax.Array) -> jax.Array:
+    """mss * mss // max(cwnd, 1), the congestion-avoidance step, computed
+    in int32: mss squared fits, and a divisor clamped to mss * mss + 1
+    gives the same quotient (0) as any larger window. Exact, and spares
+    the chip's compiler an int64 divide (intmath.py)."""
+    sq = mss * mss
+    div = jnp.clip(cwnd, 1, sq + 1).astype(jnp.int32)
+    return (jnp.int32(sq) // div).astype(jnp.int64)
+
+
 def _rtt_update(v: TcpState, m, rtt, p: TcpParams) -> TcpState:
     first = v.srtt < 0
-    rttvar1 = jnp.where(first, rtt // 2, (3 * v.rttvar + jnp.abs(v.srtt - rtt)) // 4)
-    srtt1 = jnp.where(first, rtt, (7 * v.srtt + rtt) // 8)
+    # `>> k` IS floor division by 2**k on signed ints; an int64 `//`
+    # costs the chip's compiler tens of seconds each (intmath.py)
+    rttvar1 = jnp.where(first, rtt >> 1, (3 * v.rttvar + jnp.abs(v.srtt - rtt)) >> 2)
+    srtt1 = jnp.where(first, rtt, (7 * v.srtt + rtt) >> 3)
     rto1 = jnp.clip(
         srtt1 + jnp.maximum(p.granularity_ns, 4 * rttvar1), p.rto_min_ns, p.rto_max_ns
     )
@@ -704,7 +716,7 @@ def tcp_handle(
     ss = valid_ack & ~v.in_rec & (v.cwnd < v.ssthresh)
     ca = valid_ack & ~v.in_rec & ~ss
     cwnd1 = jnp.where(ss, v.cwnd + jnp.minimum(acked, mss), v.cwnd)
-    cwnd1 = jnp.where(ca, cwnd1 + jnp.maximum((mss * mss) // jnp.maximum(cwnd1, 1), 1), cwnd1)
+    cwnd1 = jnp.where(ca, cwnd1 + jnp.maximum(ca_increment(p.mss, cwnd1), 1), cwnd1)
     cwnd1 = jnp.where(full_ack, v.ssthresh, cwnd1)
     # partial ack: deflate by amount acked, inflate by one MSS, stay in rec
     cwnd1 = jnp.where(part_ack, jnp.maximum(cwnd1 - acked + mss, mss), cwnd1)
@@ -752,10 +764,10 @@ def tcp_handle(
     flight = v.snd_max - v.snd_una
     v = v.replace(
         dupacks=jnp.where(dup, v.dupacks + 1, v.dupacks),
-        ssthresh=jnp.where(dup3, jnp.maximum(flight // 2, 2 * mss), v.ssthresh),
+        ssthresh=jnp.where(dup3, jnp.maximum(flight >> 1, 2 * mss), v.ssthresh),
         cwnd=jnp.where(
             dup3,
-            jnp.maximum(flight // 2, 2 * mss) + 3 * mss,
+            jnp.maximum(flight >> 1, 2 * mss) + 3 * mss,
             jnp.where(dup & v.in_rec, v.cwnd + mss, v.cwnd),
         ),
         recover=jnp.where(dup3, v.snd_max, v.recover),
@@ -881,7 +893,7 @@ def tcp_handle(
     rto_fire = fired & ~tw_done & (v.snd_una < v.snd_max)
     flight_w = v.snd_max - v.snd_una
     v = v.replace(
-        ssthresh=jnp.where(rto_fire, jnp.maximum(flight_w // 2, 2 * mss), v.ssthresh),
+        ssthresh=jnp.where(rto_fire, jnp.maximum(flight_w >> 1, 2 * mss), v.ssthresh),
         cwnd=jnp.where(rto_fire, mss, v.cwnd),
         snd_nxt=jnp.where(rto_fire, v.snd_una, v.snd_nxt),
         in_rec=jnp.where(rto_fire, False, v.in_rec),

@@ -109,7 +109,7 @@ def test_mesh_degradation_honors_survivors_and_divisibility():
 
 
 def test_device_loss_from_translates_xla_runtime_errors():
-    from jaxlib.xla_extension import XlaRuntimeError
+    from jax.errors import JaxRuntimeError as XlaRuntimeError
 
     err = XlaRuntimeError("INTERNAL: device failed")
     loss = device_loss_from(err, 5)

@@ -57,7 +57,7 @@ def _stats(cfg_path: pathlib.Path) -> dict:
         (cfg_path.parent / "data" / "sim-stats.json").read_text()
     )
     for k in ("wall_seconds", "scheduler", "mesh", "recovery", "degraded",
-              "chaos", "metrics", "autotune", "memory"):
+              "chaos", "metrics", "autotune", "memory", "device"):
         stats.pop(k, None)
     ens = stats.get("ensemble")
     if ens:

@@ -352,6 +352,7 @@ sweep:
         # memory prices the run's own plane (batch row vs standalone
         # shard): execution shape, not trajectory
         s.pop("memory", None)
+        s.pop("device", None)  # likewise: where it ran, not what it computed
         if "tracker" in s:
             s["tracker"].pop("phases", None)
             for k in ("iters", "lanes_live", "occupancy"):

@@ -64,6 +64,7 @@ def _stats(path) -> dict:
     # the memory section prices the run's OWN device footprint (sharded
     # single state vs ensemble batch row): execution shape, not trajectory
     s.pop("memory", None)
+    s.pop("device", None)  # likewise: where it ran, not what it computed
     if "tracker" in s:
         s["tracker"].pop("phases", None)
         for k in ("iters", "lanes_live", "occupancy"):
