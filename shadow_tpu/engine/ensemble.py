@@ -85,6 +85,7 @@ from shadow_tpu.engine.round import (
     state_probe,
     validate_runahead,
 )
+from shadow_tpu import scopes
 from shadow_tpu.engine.state import (
     EngineConfig,
     SimState,
@@ -192,10 +193,12 @@ def grow_ensemble_state(
     )(st)
 
 
+@scopes.keyed
 def _run_ensemble_chunk(st, end, num_rounds, model, tables, cfg):
     def one(s):
         s = run_rounds_scan(s, end, num_rounds, model, tables, cfg)
-        return s, state_probe(s)
+        with jax.named_scope(scopes.PROBE):
+            return s, state_probe(s)
 
     return jax.vmap(one)(st)
 
@@ -373,7 +376,8 @@ def _drive_ensemble(
     # probe, which would re-accumulate idle rounds — carries the exact
     # leaves _finish must restore.
     flightrec.begin_segment()  # mirrors engine/round.py _drive
-    entry_rows = np.asarray(jax.device_get(_peek_probe_ensemble(st)))
+    with _tspan(tracker, "entry_probe"):
+        entry_rows = np.asarray(jax.device_get(_peek_probe_ensemble(st)))
     final_rows: "dict[int, np.ndarray]" = {
         r: entry_rows[r]
         for r in range(R)
@@ -410,59 +414,61 @@ def _drive_ensemble(
                     raise
                 raise loss from err
         fetched += 1
-        # the flight-recorder seam mirrors engine/round.py `_drive`:
-        # aggregate and record BEFORE the capacity checks so a
-        # post-mortem's last sample is the failing chunk's probe
-        probe = _aggregate_probe(rows)
-        flightrec.observe_probe(probe, chunk=fetched - 1)
-        injected = chaos.fire("capacity", at=fetched - 1)
-        if injected is not None:
-            raise chaos.injected_capacity_error(fetched - 1, injected)
-        if int(rows[:, PROBE_OVERFLOW].sum()):
-            from shadow_tpu.engine.round import attach_capacity_bytes
+        with _tspan(tracker, "probe_decide", chunk=fetched - 1):
+            # the flight-recorder seam mirrors engine/round.py `_drive`:
+            # aggregate and record BEFORE the capacity checks so a
+            # post-mortem's last sample is the failing chunk's probe
+            probe = _aggregate_probe(rows)
+            flightrec.observe_probe(probe, chunk=fetched - 1)
+            injected = chaos.fire("capacity", at=fetched - 1)
+            if injected is not None:
+                raise chaos.injected_capacity_error(fetched - 1, injected)
+            if int(rows[:, PROBE_OVERFLOW].sum()):
+                from shadow_tpu.engine.round import attach_capacity_bytes
 
-            live = nxt[0] if nxt is not None else pend_st
-            if capacity_error is not None:
-                err = capacity_error(rows, live)
-            else:
-                err = _replica_capacity_error(rows)
-            attach_capacity_bytes(err, live)
-            raise err
-        if on_rows is not None:
-            on_rows(rows)
-        if on_chunk is not None:
-            on_chunk(probe)
-        for r in range(R):
-            if r not in final_rows and int(rows[r, PROBE_NEXT_TIME]) >= end_time:
-                final_rows[r] = rows[r]
-        if on_state is not None:
-            if pending_snap is not None and pending_snap[0] <= fetched - 1:
-                on_state.commit(pending_snap[1])
-                pending_snap = None
-            interrupted = on_state.interrupted()
-            if (
-                pending_snap is None and on_state.due(probe, fetched - 1)
-            ) or interrupted:
-                src = nxt[0] if nxt is not None else pend_st
-                with _tspan(tracker, "state_snapshot", chunk=launched - 1):
-                    host = _patch_snapshot(state_to_host(src), final_rows)
-                if nxt is None:
-                    on_state.commit(host)
-                elif interrupted:
-                    if (
-                        int(host.queue.overflow.sum()) == 0
-                        and int(host.outbox.overflow.sum()) == 0
-                    ):
-                        on_state.commit(host)
+                live = nxt[0] if nxt is not None else pend_st
+                if capacity_error is not None:
+                    err = capacity_error(rows, live)
                 else:
-                    pending_snap = (launched - 1, host)
-            if interrupted:
-                raise RunInterrupted(
-                    f"run interrupted at sim time {probe.now} ns"
-                )
+                    err = _replica_capacity_error(rows)
+                attach_capacity_bytes(err, live)
+                raise err
+            if on_rows is not None:
+                on_rows(rows)
+            if on_chunk is not None:
+                on_chunk(probe)
+            for r in range(R):
+                if r not in final_rows and int(rows[r, PROBE_NEXT_TIME]) >= end_time:
+                    final_rows[r] = rows[r]
+            if on_state is not None:
+                if pending_snap is not None and pending_snap[0] <= fetched - 1:
+                    on_state.commit(pending_snap[1])
+                    pending_snap = None
+                interrupted = on_state.interrupted()
+                if (
+                    pending_snap is None and on_state.due(probe, fetched - 1)
+                ) or interrupted:
+                    src = nxt[0] if nxt is not None else pend_st
+                    with _tspan(tracker, "state_snapshot", chunk=launched - 1):
+                        host = _patch_snapshot(state_to_host(src), final_rows)
+                    if nxt is None:
+                        on_state.commit(host)
+                    elif interrupted:
+                        if (
+                            int(host.queue.overflow.sum()) == 0
+                            and int(host.outbox.overflow.sum()) == 0
+                        ):
+                            on_state.commit(host)
+                    else:
+                        pending_snap = (launched - 1, host)
+                if interrupted:
+                    raise RunInterrupted(
+                        f"run interrupted at sim time {probe.now} ns"
+                    )
         if len(final_rows) == R:
             out = nxt[0] if nxt is not None else pend_st
-            return _finish(out, final_rows)
+            with _tspan(tracker, "quiescent_restore"):
+                return _finish(out, final_rows)
         if nxt is None:
             if launched < max_chunks:
                 with _tspan(tracker, "chunk_launch", chunk=launched):
@@ -519,40 +525,45 @@ def run_ensemble_until(
     scheduler's compile cache) — it must have been lowered for exactly
     this state shape and a trace_static_cfg-canonicalized version of
     this cfg."""
-    cfg = ensemble_engine_cfg(cfg)
-    validate_runahead(cfg, tables)
-    num_replicas(st)  # loud on a non-ensemble state
-    if int(_peek_next_time_ensemble(st)) >= end_time:
-        check_capacity(st)
-        return st
-    end = jnp.asarray(end_time, jnp.int64)
-    with _tspan(tracker, "donate_copy"):
-        st = st.donatable()
+    with _tspan(tracker, "run"):
+        cfg = ensemble_engine_cfg(cfg)
+        with _tspan(tracker, "validate_runahead"):
+            validate_runahead(cfg, tables)
+        num_replicas(st)  # loud on a non-ensemble state
+        with _tspan(tracker, "peek_next_time"):
+            quiescent = int(_peek_next_time_ensemble(st)) >= end_time
+        if quiescent:
+            check_capacity(st)
+            return st
+        with _tspan(tracker, "put_end_time"):
+            end = jnp.asarray(end_time, jnp.int64)
+        with _tspan(tracker, "donate_copy"):
+            st = st.donatable()
 
-    if launch is None:
-        # seed is canonicalized out of the static cfg so the process-wide
-        # jit cache, like the AOT path, reuses one executable across
-        # same-shape worlds that differ only in seed
-        jit_cfg = trace_static_cfg(cfg)
+        if launch is None:
+            # seed is canonicalized out of the static cfg so the process-wide
+            # jit cache, like the AOT path, reuses one executable across
+            # same-shape worlds that differ only in seed
+            jit_cfg = trace_static_cfg(cfg)
 
-        chunk_args = (end, rounds_per_chunk, model, tables, jit_cfg)
+            chunk_args = (end, rounds_per_chunk, model, tables, jit_cfg)
 
-        def launch(s):
-            return _run_ensemble_chunk_jit(s, *chunk_args)
+            def launch(s):
+                return _run_ensemble_chunk_jit(s, *chunk_args)
 
-        def compile_chunk(s):
-            _run_ensemble_chunk_jit.lower(s, *chunk_args).compile()
+            def compile_chunk(s):
+                return _run_ensemble_chunk_jit.lower(s, *chunk_args).compile()
 
-    else:
-        exe, compile_chunk = launch, None  # compiled in the cache's seam
+        else:
+            exe, compile_chunk = launch, None  # compiled in the cache's seam
 
-        def launch(s):
-            return exe(s, end, tables)
+            def launch(s):
+                return exe(s, end, tables)
 
-    return _drive_ensemble(
-        launch, st, end_time, max_chunks, on_chunk, pipeline,
-        desc=f"{max_chunks}x{rounds_per_chunk} rounds",
-        tracker=tracker, on_state=on_state, on_rows=on_rows,
-        watchdog_s=watchdog_s, engine=effective_engine(ensemble_engine_cfg(cfg)),
-        compile_chunk=compile_chunk,
-    )
+        return _drive_ensemble(
+            launch, st, end_time, max_chunks, on_chunk, pipeline,
+            desc=f"{max_chunks}x{rounds_per_chunk} rounds",
+            tracker=tracker, on_state=on_state, on_rows=on_rows,
+            watchdog_s=watchdog_s, engine=effective_engine(ensemble_engine_cfg(cfg)),
+            compile_chunk=compile_chunk,
+        )

@@ -60,6 +60,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from shadow_tpu import scopes
 from shadow_tpu.engine.ensemble import (
     _drive_ensemble,
     _peek_next_time_ensemble,
@@ -306,6 +307,7 @@ def _mesh_chunk_fn(st: SimState, plan: MeshPlan, mesh: Mesh,
     specs = mesh_state_specs(st, plan)
     tspecs = jax.tree.map(lambda _: P(), tables)
 
+    @scopes.keyed
     def chunk(st_local, tables_r, end):
         def one(s):
             s = run_rounds_scan(
@@ -315,7 +317,8 @@ def _mesh_chunk_fn(st: SimState, plan: MeshPlan, mesh: Mesh,
             # per-replica probe row, reduced along `hosts` ONLY: within
             # a replica row the collectives make it replicated; across
             # rows it stays that row's own values
-            return s, state_probe(s, axis_name=HOST_AXIS)
+            with jax.named_scope(scopes.PROBE):
+                return s, state_probe(s, axis_name=HOST_AXIS)
 
         return jax.vmap(one)(st_local)
 
@@ -473,56 +476,62 @@ def run_mesh_until(
     overrides the dispatch with a pre-compiled executable
     (lower_mesh_chunk + .compile(), via the compile cache) called as
     `exe(st, tables, end)`."""
-    cfg = mesh_engine_cfg(cfg)
-    validate_runahead(cfg, tables)
-    r = num_replicas(st)  # loud on a non-batched state
-    if r != plan.replicas:
-        raise ValueError(
-            f"state carries {r} replica(s), plan expects {plan.replicas}"
+    with _tspan(tracker, "run"):
+        cfg = mesh_engine_cfg(cfg)
+        with _tspan(tracker, "validate_runahead"):
+            validate_runahead(cfg, tables)
+        r = num_replicas(st)  # loud on a non-batched state
+        if r != plan.replicas:
+            raise ValueError(
+                f"state carries {r} replica(s), plan expects {plan.replicas}"
+            )
+        if cfg.num_hosts % plan.shards:
+            raise ValueError(
+                f"num_hosts={cfg.num_hosts} must divide evenly over "
+                f"{plan.shards} host-shard(s)"
+            )
+        if mesh is None:
+            mesh = plan.build_mesh()
+        with _tspan(tracker, "shard_state"):
+            st = shard_mesh_state(st, mesh, plan)
+        with _tspan(tracker, "peek_next_time"):
+            quiescent = int(_peek_next_time_ensemble(st)) >= end_time
+        if quiescent:
+            check_capacity(st)
+            return st
+        with _tspan(tracker, "put_end_time"):
+            end = jnp.asarray(end_time, jnp.int64)
+        with _tspan(tracker, "donate_copy"):
+            st = st.donatable()
+
+        if launch is None:
+            jit_cfg = trace_static_cfg(cfg)
+            compiled = _mesh_chunk_fn(
+                st, plan, mesh, rounds_per_chunk, model, tables, jit_cfg
+            )
+
+            def launch(s):
+                return compiled(s, tables, end)
+
+            def compile_chunk(s):
+                return compiled.lower(s, tables, end).compile()
+
+        else:
+            exe, compile_chunk = launch, None  # compiled in the cache's seam
+
+            def launch(s):
+                return exe(s, tables, end)
+
+        def capacity_error(rows, live_st):
+            return mesh_capacity_error(rows, live_st, plan)
+
+        return _drive_ensemble(
+            launch, st, end_time, max_chunks, on_chunk, pipeline,
+            desc=f"{max_chunks}x{rounds_per_chunk} rounds ({plan.describe()})",
+            tracker=tracker, on_state=on_state, on_rows=on_rows,
+            watchdog_s=watchdog_s, engine=effective_engine(cfg),
+            capacity_error=capacity_error, compile_chunk=compile_chunk,
         )
-    if cfg.num_hosts % plan.shards:
-        raise ValueError(
-            f"num_hosts={cfg.num_hosts} must divide evenly over "
-            f"{plan.shards} host-shard(s)"
-        )
-    if mesh is None:
-        mesh = plan.build_mesh()
-    st = shard_mesh_state(st, mesh, plan)
-    if int(_peek_next_time_ensemble(st)) >= end_time:
-        check_capacity(st)
-        return st
-    end = jnp.asarray(end_time, jnp.int64)
-    with _tspan(tracker, "donate_copy"):
-        st = st.donatable()
-
-    if launch is None:
-        jit_cfg = trace_static_cfg(cfg)
-        compiled = _mesh_chunk_fn(
-            st, plan, mesh, rounds_per_chunk, model, tables, jit_cfg
-        )
-
-        def launch(s):
-            return compiled(s, tables, end)
-
-        def compile_chunk(s):
-            compiled.lower(s, tables, end).compile()
-
-    else:
-        exe, compile_chunk = launch, None  # compiled in the cache's seam
-
-        def launch(s):
-            return exe(s, tables, end)
-
-    def capacity_error(rows, live_st):
-        return mesh_capacity_error(rows, live_st, plan)
-
-    return _drive_ensemble(
-        launch, st, end_time, max_chunks, on_chunk, pipeline,
-        desc=f"{max_chunks}x{rounds_per_chunk} rounds ({plan.describe()})",
-        tracker=tracker, on_state=on_state, on_rows=on_rows,
-        watchdog_s=watchdog_s, engine=effective_engine(cfg),
-        capacity_error=capacity_error, compile_chunk=compile_chunk,
-    )
 
 
 __all__ = [

@@ -77,7 +77,7 @@ import jax.numpy as jnp
 
 import flax.struct
 
-from shadow_tpu import equeue, netstack, rng
+from shadow_tpu import equeue, netstack, rng, scopes
 from shadow_tpu.engine.state import EngineConfig, SimState
 from shadow_tpu.events import KIND_PACKET, pack_tie, tie_src_host
 from shadow_tpu.graph.routing import RoutingTables
@@ -891,15 +891,16 @@ def pump_carry_finish(
         lanes_live = (jnp.arange(k)[None, :] >= c.f_head[:, None]) & (
             jnp.arange(k)[None, :] < c.f_cnt[:, None]
         )
-        q = equeue.push_self_lanes(
-            q,
-            valid=lanes_live,
-            time=c.f_time,
-            tie=c.f_tie,
-            kind=c.f_kind,
-            data=c.f_data,
-            aux=c.f_aux,
-        )
+        with jax.named_scope(scopes.PUSH_SELF):
+            q = equeue.push_self_lanes(
+                q,
+                valid=lanes_live,
+                time=c.f_time,
+                tie=c.f_tie,
+                kind=c.f_kind,
+                data=c.f_data,
+                aux=c.f_aux,
+            )
 
     ob = st.outbox.replace(
         valid=c.obv, dst=c.obd, time=c.obt, tie=c.obtie, data=c.obdata,

@@ -32,7 +32,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from shadow_tpu import equeue, netstack, rng
+from shadow_tpu import equeue, netstack, rng, scopes
 from shadow_tpu.engine.state import EngineConfig, SimState, trace_static_cfg
 from shadow_tpu.events import KIND_PACKET, pack_tie
 from shadow_tpu.graph.routing import RoutingTables
@@ -271,10 +271,11 @@ def handle_one_iteration(
         p_valid, p_time, p_tie = lvalid, lemits.time, lane_tie
         p_kind, p_data = lemits.kind, lemits.data
         p_aux = jnp.zeros((host_ids.shape[0], el), jnp.int32)
-    queue = equeue.push_self_lanes(
-        st.queue, valid=p_valid, time=p_time, tie=p_tie, kind=p_kind,
-        data=p_data, aux=p_aux,
-    )
+    with jax.named_scope(scopes.PUSH_SELF):
+        queue = equeue.push_self_lanes(
+            st.queue, valid=p_valid, time=p_time, tie=p_tie, kind=p_kind,
+            data=p_data, aux=p_aux,
+        )
 
     # --- stage surviving packets into own outbox rows ---
     ob = st.outbox
@@ -509,19 +510,20 @@ def flush_outbox(
     # any-reduce). Sharded: the predicate is made mesh-uniform with a
     # psum, because the all_to_all/all_gather inside must be entered by
     # every shard or none.
-    has_traffic = _has_traffic(st, axis_name)
+    with jax.named_scope(scopes.EXCHANGE):
+        has_traffic = _has_traffic(st, axis_name)
 
-    def _skip(st):
-        return st
+        def _skip(st):
+            return st
 
-    def _do_flush(st):
-        return _flush_outbox_traffic(st, axis_name, cfg)
+        def _do_flush(st):
+            return _flush_outbox_traffic(st, axis_name, cfg)
 
-    if not isinstance(has_traffic, jax.core.Tracer):
-        # eager path (round_body_debug/tests): concrete predicate — an
-        # eager lax.cond over this state is pathological for the tracer
-        return _do_flush(st) if bool(has_traffic) else st
-    return jax.lax.cond(has_traffic, _do_flush, _skip, st)
+        if not isinstance(has_traffic, jax.core.Tracer):
+            # eager path (round_body_debug/tests): concrete predicate — an
+            # eager lax.cond over this state is pathological for the tracer
+            return _do_flush(st) if bool(has_traffic) else st
+        return jax.lax.cond(has_traffic, _do_flush, _skip, st)
 
 
 def _flush_outbox_traffic(
@@ -572,50 +574,43 @@ def _flush_outbox_traffic(
                 a2a_over if overflow_extra is None else overflow_extra + a2a_over
             )
 
-            def bucketize(x, fill):
+            def to_peers(x, fill):
                 buf = jnp.full((d, cap) + x.shape[1:], fill, x.dtype)
-                return buf.at[sdst, sslot].set(x[order], mode="drop")
+                buf = buf.at[sdst, sslot].set(x[order], mode="drop")
+                with jax.named_scope(scopes.COLLECTIVE):
+                    got = jax.lax.all_to_all(buf, axis_name, 0, 0, tiled=False)
+                return got.reshape((d * cap,) + x.shape[1:])
 
-            valid = jax.lax.all_to_all(
-                bucketize(valid, False), axis_name, 0, 0, tiled=False
-            ).reshape((d * cap,))
-            dst = jax.lax.all_to_all(
-                bucketize(dst, 0), axis_name, 0, 0, tiled=False
-            ).reshape((d * cap,))
-            time = jax.lax.all_to_all(
-                bucketize(time, TIME_MAX), axis_name, 0, 0, tiled=False
-            ).reshape((d * cap,))
-            tie = jax.lax.all_to_all(
-                bucketize(tie, 0), axis_name, 0, 0, tiled=False
-            ).reshape((d * cap,))
-            data = jax.lax.all_to_all(
-                bucketize(data, 0), axis_name, 0, 0, tiled=False
-            ).reshape((d * cap, data.shape[1]))
-            aux = jax.lax.all_to_all(
-                bucketize(aux, 0), axis_name, 0, 0, tiled=False
-            ).reshape((d * cap,))
+            valid = to_peers(valid, False)
+            dst = to_peers(dst, 0)
+            time = to_peers(time, TIME_MAX)
+            tie = to_peers(tie, 0)
+            data = to_peers(data, 0)
+            aux = to_peers(aux, 0)
         else:
-            valid = jax.lax.all_gather(valid, axis_name, tiled=True)
-            dst = jax.lax.all_gather(dst, axis_name, tiled=True)
-            time = jax.lax.all_gather(time, axis_name, tiled=True)
-            tie = jax.lax.all_gather(tie, axis_name, tiled=True)
-            data = jax.lax.all_gather(data, axis_name, tiled=True)
-            aux = jax.lax.all_gather(aux, axis_name, tiled=True)
+            with jax.named_scope(scopes.COLLECTIVE):
+                valid = jax.lax.all_gather(valid, axis_name, tiled=True)
+                dst = jax.lax.all_gather(dst, axis_name, tiled=True)
+                time = jax.lax.all_gather(time, axis_name, tiled=True)
+                tie = jax.lax.all_gather(tie, axis_name, tiled=True)
+                data = jax.lax.all_gather(data, axis_name, tiled=True)
+                aux = jax.lax.all_gather(aux, axis_name, tiled=True)
 
     local_dst = dst - base
     mine = valid & (local_dst >= 0) & (local_dst < h_local)
     lanes = getattr(cfg, "deliver_lanes", 0) if cfg is not None else 0
-    queue = equeue.push_many_sorted(
-        deliver_lanes=lanes if lanes > 0 else st.queue.capacity,
-        q=st.queue,
-        dst=local_dst,
-        valid=mine,
-        time=time,
-        tie=tie,
-        kind=jnp.full(valid.shape, KIND_PACKET, jnp.int32),
-        data=data,
-        aux=aux,
-    )
+    with jax.named_scope(scopes.LAND):
+        queue = equeue.push_many_sorted(
+            deliver_lanes=lanes if lanes > 0 else st.queue.capacity,
+            q=st.queue,
+            dst=local_dst,
+            valid=mine,
+            time=time,
+            tie=tie,
+            kind=jnp.full(valid.shape, KIND_PACKET, jnp.int32),
+            data=data,
+            aux=aux,
+        )
 
     fresh = ob.replace(
         valid=jnp.zeros_like(ob.valid),
@@ -641,25 +636,28 @@ def _ring_exchange(arrs: tuple, axis_name: str, d: int) -> tuple:
     Bytes over ICI: (d-1) x cap per array vs all_gather's (d-1) x m —
     the lane-factor saving when cap (the measured per-round traffic)
     is below the dense outbox width m."""
-    idx = jax.lax.axis_index(axis_name)
-    received = [
-        tuple(
-            jax.lax.dynamic_index_in_dim(a, idx, 0, keepdims=False)
-            for a in arrs
+    with jax.named_scope(scopes.COLLECTIVE):
+        idx = jax.lax.axis_index(axis_name)
+        received = [
+            tuple(
+                jax.lax.dynamic_index_in_dim(a, idx, 0, keepdims=False)
+                for a in arrs
+            )
+        ]
+        for k in range(1, d):
+            perm = [(i, (i + k) % d) for i in range(d)]
+            send = tuple(
+                jax.lax.dynamic_index_in_dim(
+                    a, (idx + k) % d, 0, keepdims=False
+                )
+                for a in arrs
+            )
+            received.append(
+                tuple(jax.lax.ppermute(s, axis_name, perm) for s in send)
+            )
+        return tuple(
+            jnp.stack([r[j] for r in received]) for j in range(len(arrs))
         )
-    ]
-    for k in range(1, d):
-        perm = [(i, (i + k) % d) for i in range(d)]
-        send = tuple(
-            jax.lax.dynamic_index_in_dim(a, (idx + k) % d, 0, keepdims=False)
-            for a in arrs
-        )
-        received.append(
-            tuple(jax.lax.ppermute(s, axis_name, perm) for s in send)
-        )
-    return tuple(
-        jnp.stack([r[j] for r in received]) for j in range(len(arrs))
-    )
 
 
 def _flush_segment(
@@ -768,16 +766,17 @@ def _flush_segment(
 
     local_dst = dst_p - base
     mine = valid_p & (local_dst >= 0) & (local_dst < h_local)
-    queue = equeue.push_many_segment(
-        q=st.queue,
-        dst=local_dst,
-        valid=mine,
-        time=time_p,
-        tie=tie_p,
-        kind=jnp.full(valid_p.shape, KIND_PACKET, jnp.int32),
-        data=data_p,
-        aux=aux_p,
-    )
+    with jax.named_scope(scopes.LAND):
+        queue = equeue.push_many_segment(
+            q=st.queue,
+            dst=local_dst,
+            valid=mine,
+            time=time_p,
+            tie=tie_p,
+            kind=jnp.full(valid_p.shape, KIND_PACKET, jnp.int32),
+            data=data_p,
+            aux=aux_p,
+        )
 
     fresh = ob.replace(
         valid=jnp.zeros_like(ob.valid),
@@ -848,23 +847,21 @@ def run_round(
             iters < max_iters
         )
 
+    def _handle(s):
+        with jax.named_scope(scopes.HANDLE):
+            return handle_one_iteration(s, window_end, model, tables, cfg)
+
     def _body(s):
         """One iteration over whatever rows `s` holds (full or compacted)."""
         if use_pump:
-            s, rej = stage(s, window_end, model, tables, stage_cfg)
+            with jax.named_scope(scopes.PUMP):
+                s, rej = stage(s, window_end, model, tables, stage_cfg)
             # the full handler only runs when some host's head event
             # failed pump classification — pump-only iterations cover the
             # steady packet streams (chains longer than pump_k keep
             # pumping next iteration without a handler pass)
-            return jax.lax.cond(
-                rej,
-                lambda x: handle_one_iteration(
-                    x, window_end, model, tables, cfg
-                ),
-                lambda x: x,
-                s,
-            )
-        return handle_one_iteration(s, window_end, model, tables, cfg)
+            return jax.lax.cond(rej, _handle, lambda x: x, s)
+        return _handle(s)
 
     def _step(carry):
         s, iters = carry
@@ -894,37 +891,49 @@ def run_round(
     else:
         body = _step
 
-    st, iters = jax.lax.while_loop(cond, body, (st, jnp.asarray(0, jnp.int32)))
+    with jax.named_scope(scopes.DRAIN):
+        st, iters = jax.lax.while_loop(
+            cond, body, (st, jnp.asarray(0, jnp.int32))
+        )
     if cfg.tracker:
         # Sample occupancy high-water marks at the two per-round peaks:
         # the outbox right before the flush empties it, and the queue
         # right after the flush delivers the exchanged packets. Sampled
         # per round (not per iteration), identically in every engine.
-        st = st.replace(
-            tracker=st.tracker.replace(
-                outbox_hwm=jnp.maximum(st.tracker.outbox_hwm, st.outbox.fill),
-                queue_hwm=jnp.maximum(st.tracker.queue_hwm, st.queue.count),
-                # per-round exchange traffic high-water (row 0, like
-                # iters_done): sum of staged events right before the
-                # flush — the measured figure that sizes a2a/segment
-                # ring buckets (sharded.auto_a2a_capacity) and the pool
-                # occupancy CapacityError reports
-                exch_hwm=st.tracker.exch_hwm.at[0].max(
-                    jnp.sum(st.outbox.fill).astype(jnp.int32)
-                ),
+        with jax.named_scope(scopes.PROBE):
+            st = st.replace(
+                tracker=st.tracker.replace(
+                    outbox_hwm=jnp.maximum(
+                        st.tracker.outbox_hwm, st.outbox.fill
+                    ),
+                    queue_hwm=jnp.maximum(
+                        st.tracker.queue_hwm, st.queue.count
+                    ),
+                    # per-round exchange traffic high-water (row 0, like
+                    # iters_done): sum of staged events right before the
+                    # flush — the measured figure that sizes a2a/segment
+                    # ring buckets (sharded.auto_a2a_capacity) and the
+                    # pool occupancy CapacityError reports
+                    exch_hwm=st.tracker.exch_hwm.at[0].max(
+                        jnp.sum(st.outbox.fill).astype(jnp.int32)
+                    ),
+                )
             )
-        )
     st = flush_outbox(st, axis_name, cfg)
     if cfg.tracker:
-        st = st.replace(
-            tracker=st.tracker.replace(
-                queue_hwm=jnp.maximum(st.tracker.queue_hwm, st.queue.count)
+        with jax.named_scope(scopes.PROBE):
+            st = st.replace(
+                tracker=st.tracker.replace(
+                    queue_hwm=jnp.maximum(
+                        st.tracker.queue_hwm, st.queue.count
+                    )
+                )
             )
+    with jax.named_scope(scopes.WINDOW):
+        return st.replace(
+            now=jnp.maximum(st.now, window_end),
+            iters_done=st.iters_done.at[0].add(iters),
         )
-    return st.replace(
-        now=jnp.maximum(st.now, window_end),
-        iters_done=st.iters_done.at[0].add(iters),
-    )
 
 
 def _next_window_end(
@@ -1008,41 +1017,47 @@ def run_rounds_scan(
     live branch contains the exchange collectives."""
 
     def one(s, _):
-        start = jnp.min(equeue.next_time(s.queue))
-        if axis_name is not None:
-            start = _pmin(start, axis_name)
-        has_traffic = _has_traffic(s, axis_name)
-        window_end = _next_window_end(
-            s, end_time, cfg, axis_name, start=start, tables=tables
-        )
+        with jax.named_scope(scopes.WINDOW):
+            start = jnp.min(equeue.next_time(s.queue))
+            if axis_name is not None:
+                start = _pmin(start, axis_name)
+            has_traffic = _has_traffic(s, axis_name)
+            window_end = _next_window_end(
+                s, end_time, cfg, axis_name, start=start, tables=tables
+            )
+            is_live = (start < end_time) | has_traffic
 
         def live(s):
-            width = window_end - jnp.minimum(start, window_end)
-            s = s.replace(win_ns_sum=s.win_ns_sum + width)
+            with jax.named_scope(scopes.WINDOW):
+                width = window_end - jnp.minimum(start, window_end)
+                s = s.replace(win_ns_sum=s.win_ns_sum + width)
             s = run_round(s, window_end, model, tables, cfg, axis_name)
             if cfg.tracker:
                 # replicated scalars: every shard runs the same round
                 # sequence, so no mesh reduction is needed (and the
                 # pipelined driver restores both from the probe on the
                 # quiescent-extra-chunk path, like `now`)
-                s = s.replace(
-                    tracker=s.tracker.replace(
-                        rounds_live=s.tracker.rounds_live + 1
+                with jax.named_scope(scopes.PROBE):
+                    s = s.replace(
+                        tracker=s.tracker.replace(
+                            rounds_live=s.tracker.rounds_live + 1
+                        )
                     )
-                )
             return s
 
         def idle(s):
-            s = s.replace(now=jnp.maximum(s.now, window_end))
+            with jax.named_scope(scopes.WINDOW):
+                s = s.replace(now=jnp.maximum(s.now, window_end))
             if cfg.tracker:
-                s = s.replace(
-                    tracker=s.tracker.replace(
-                        rounds_idle=s.tracker.rounds_idle + 1
+                with jax.named_scope(scopes.PROBE):
+                    s = s.replace(
+                        tracker=s.tracker.replace(
+                            rounds_idle=s.tracker.rounds_idle + 1
+                        )
                     )
-                )
             return s
 
-        return jax.lax.cond((start < end_time) | has_traffic, live, idle, s), None
+        return jax.lax.cond(is_live, live, idle, s), None
 
     st, _ = jax.lax.scan(one, st, None, length=num_rounds)
     return st
@@ -1461,9 +1476,11 @@ def host_stats(st: SimState) -> dict:
     )
 
 
+@scopes.keyed
 def _run_chunk(st, end, num_rounds, model, tables, cfg):
     st = run_rounds_scan(st, end, num_rounds, model, tables, cfg)
-    return st, state_probe(st)
+    with jax.named_scope(scopes.PROBE):
+        return st, state_probe(st)
 
 
 # model/cfg are hashable frozen dataclasses -> proper jit cache keys, so
@@ -1645,13 +1662,19 @@ def _launch_chunk0(launch, st, tracker, engine: str, compile_chunk=None):
     a lost device) stays what it is. A driver handed an executable that
     was compiled elsewhere (the sweep cache's entry, compiled inside its
     own seam) passes no compile_chunk; the seam is still entered so an
-    injected fault fires at the same place either way."""
+    injected fault fires at the same place either way (and nothing is
+    kept). The executable compiled here is kept as `scopes.last_chunk`,
+    where `scopes.chunk_table()` finds the text that maps a device
+    trace's operations back to the engine's layers; nobody reads it in a
+    run that does not ask."""
     from shadow_tpu.runtime import chaos
 
     with _tspan(tracker, "compile+launch", chunk=0):
         with chaos.compile_seam(engine):
+            scopes.last_chunk = None
             if compile_chunk is not None:
-                compile_chunk(st)
+                with _tspan(tracker, "chunk_compile"):
+                    scopes.last_chunk = compile_chunk(st)
         return launch(st)
 
 
@@ -1676,13 +1699,20 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
     entirely on a quiescent state — every round took run_rounds_scan's
     idle branch — so its output is leaf-identical and is returned as-is.
 
-    With a `tracker` attached (utils/tracker.py), every launch call and
-    probe fetch is recorded as a trace span (the first launch includes
-    jit compilation, labelled "compile+launch"), and whenever the tracker
-    says a per-host heartbeat is due — decided from the already-fetched
-    probe, never an extra sync — the full per-host counter tensors are
-    pulled in ONE bulk device_get from the live (never-donated) pending
-    state and rendered as reference-style tracker lines.
+    With a `tracker` attached (utils/tracker.py), the loop is recorded
+    as spans, children of the caller's `run` span: `compile+launch` for
+    chunk 0 (with the per-entry compile as its child `chunk_compile`),
+    `chunk_launch` for every later dispatch, `probe_fetch` while the
+    host is blocked on a probe, `probe_decide` from the fetched probe to
+    the next launch (`host_stats_fetch` and `state_snapshot` inside it,
+    at their cadences) and `quiescent_restore` for the last chunk's
+    put-back. Whenever the tracker says a per-host heartbeat is due —
+    decided from the already-fetched probe, never an extra sync — the
+    full per-host counter tensors are pulled in ONE bulk device_get from
+    the live (never-donated) pending state and rendered as
+    reference-style tracker lines. The driver calls `span` and
+    `host_heartbeat_due` on a tracker and, only where the latter says
+    yes, `emit_host_heartbeat`: the seam is duck-typed.
 
     `on_state` (runtime/checkpoint.py StateTap) taps chunk-boundary
     states for checkpoints / recovery snapshots / interrupt handling:
@@ -1729,78 +1759,79 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
                 _fetch_probe(pend_probe, watchdog_s, fetched)
             )
         fetched += 1
-        # flight recorder (runtime/flightrec.py): fold this probe into
-        # the installed recorder's ring BEFORE the capacity checks, so a
-        # post-mortem's last sample is the chunk that failed — reading
-        # the already-fetched probe costs zero extra device syncs
-        flightrec.observe_probe(probe, chunk=fetched - 1)
-        injected = chaos.fire("capacity", at=fetched - 1)
-        if injected is not None:
-            raise chaos.injected_capacity_error(fetched - 1, injected)
-        if probe.overflow:
-            err = _capacity_error(
-                probe.overflow,
-                queue_ov=probe.queue_overflow,
-                outbox_ov=probe.outbox_overflow,
-                queue_hwm=probe.queue_hwm,
-                outbox_hwm=probe.outbox_hwm,
-                exch_hwm=probe.exch_hwm,
-            )
-            # price the saturated buffers from the live state (the
-            # pipelined in-flight chunk's output when pend_st was
-            # donated into it) — shape metadata only, no device sync
-            attach_capacity_bytes(
-                err, nxt[0] if nxt is not None else pend_st
-            )
-            if capacity_detail is not None:
-                try:
-                    src = nxt[0] if nxt is not None else pend_st
-                    err.shard_detail = capacity_detail(src)
-                    if err.shard_detail:
-                        err.args = (f"{err.args[0]}\n{err.shard_detail}",)
-                except Exception:  # diagnostics must not mask the error
-                    pass
-            raise err
-        if on_chunk is not None:
-            on_chunk(probe)
-        if tracker is not None and tracker.host_heartbeat_due(probe.now):
-            # pend_st was donated into `nxt` under pipelining; the bulk
-            # fetch must read a live state, so use the in-flight chunk's
-            # output (one window later — immaterial at heartbeat cadence)
-            src = nxt[0] if nxt is not None else pend_st
-            with _tspan(tracker, "host_stats_fetch"):
-                tracker.emit_host_heartbeat(probe, host_stats(src))
-        if on_state is not None:
-            # chunk `fetched-1`'s probe just passed the capacity check:
-            # any snapshot waiting on it is now verified clean
-            if pending_snap is not None and pending_snap[0] <= fetched - 1:
-                on_state.commit(pending_snap[1])
-                pending_snap = None
-            interrupted = on_state.interrupted()
-            if (
-                pending_snap is None and on_state.due(probe, fetched - 1)
-            ) or interrupted:
-                from shadow_tpu.engine.state import state_to_host
-
-                src = nxt[0] if nxt is not None else pend_st
-                with _tspan(tracker, "state_snapshot", chunk=launched - 1):
-                    host = state_to_host(src)
-                if nxt is None:
-                    on_state.commit(host)  # src IS the verified chunk
-                elif interrupted:
-                    # cannot wait a chunk for verification: check the
-                    # overflow counters on the host copy directly
-                    if (
-                        int(host.queue.overflow.sum()) == 0
-                        and int(host.outbox.overflow.sum()) == 0
-                    ):
-                        on_state.commit(host)
-                else:
-                    pending_snap = (launched - 1, host)
-            if interrupted:
-                raise RunInterrupted(
-                    f"run interrupted at sim time {probe.now} ns"
+        with _tspan(tracker, "probe_decide", chunk=fetched - 1):
+            # flight recorder (runtime/flightrec.py): fold this probe into
+            # the installed recorder's ring BEFORE the capacity checks, so a
+            # post-mortem's last sample is the chunk that failed — reading
+            # the already-fetched probe costs zero extra device syncs
+            flightrec.observe_probe(probe, chunk=fetched - 1)
+            injected = chaos.fire("capacity", at=fetched - 1)
+            if injected is not None:
+                raise chaos.injected_capacity_error(fetched - 1, injected)
+            if probe.overflow:
+                err = _capacity_error(
+                    probe.overflow,
+                    queue_ov=probe.queue_overflow,
+                    outbox_ov=probe.outbox_overflow,
+                    queue_hwm=probe.queue_hwm,
+                    outbox_hwm=probe.outbox_hwm,
+                    exch_hwm=probe.exch_hwm,
                 )
+                # price the saturated buffers from the live state (the
+                # pipelined in-flight chunk's output when pend_st was
+                # donated into it) — shape metadata only, no device sync
+                attach_capacity_bytes(
+                    err, nxt[0] if nxt is not None else pend_st
+                )
+                if capacity_detail is not None:
+                    try:
+                        src = nxt[0] if nxt is not None else pend_st
+                        err.shard_detail = capacity_detail(src)
+                        if err.shard_detail:
+                            err.args = (f"{err.args[0]}\n{err.shard_detail}",)
+                    except Exception:  # diagnostics must not mask the error
+                        pass
+                raise err
+            if on_chunk is not None:
+                on_chunk(probe)
+            if tracker is not None and tracker.host_heartbeat_due(probe.now):
+                # pend_st was donated into `nxt` under pipelining; the bulk
+                # fetch must read a live state, so use the in-flight chunk's
+                # output (one window later — immaterial at heartbeat cadence)
+                src = nxt[0] if nxt is not None else pend_st
+                with _tspan(tracker, "host_stats_fetch"):
+                    tracker.emit_host_heartbeat(probe, host_stats(src))
+            if on_state is not None:
+                # chunk `fetched-1`'s probe just passed the capacity check:
+                # any snapshot waiting on it is now verified clean
+                if pending_snap is not None and pending_snap[0] <= fetched - 1:
+                    on_state.commit(pending_snap[1])
+                    pending_snap = None
+                interrupted = on_state.interrupted()
+                if (
+                    pending_snap is None and on_state.due(probe, fetched - 1)
+                ) or interrupted:
+                    from shadow_tpu.engine.state import state_to_host
+
+                    src = nxt[0] if nxt is not None else pend_st
+                    with _tspan(tracker, "state_snapshot", chunk=launched - 1):
+                        host = state_to_host(src)
+                    if nxt is None:
+                        on_state.commit(host)  # src IS the verified chunk
+                    elif interrupted:
+                        # cannot wait a chunk for verification: check the
+                        # overflow counters on the host copy directly
+                        if (
+                            int(host.queue.overflow.sum()) == 0
+                            and int(host.outbox.overflow.sum()) == 0
+                        ):
+                            on_state.commit(host)
+                    else:
+                        pending_snap = (launched - 1, host)
+                if interrupted:
+                    raise RunInterrupted(
+                        f"run interrupted at sim time {probe.now} ns"
+                    )
         if probe.next_time >= end_time:
             if nxt is None:
                 return pend_st
@@ -1814,17 +1845,18 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
             # probe) so pipelined and synchronous results are leaf-exact
             # in every case.
             out = nxt[0]
-            return out.replace(
-                now=jnp.asarray(probe.now, out.now.dtype),
-                tracker=out.tracker.replace(
-                    rounds_live=jnp.asarray(
-                        probe.rounds_live, out.tracker.rounds_live.dtype
+            with _tspan(tracker, "quiescent_restore"):
+                return out.replace(
+                    now=jnp.asarray(probe.now, out.now.dtype),
+                    tracker=out.tracker.replace(
+                        rounds_live=jnp.asarray(
+                            probe.rounds_live, out.tracker.rounds_live.dtype
+                        ),
+                        rounds_idle=jnp.asarray(
+                            probe.rounds_idle, out.tracker.rounds_idle.dtype
+                        ),
                     ),
-                    rounds_idle=jnp.asarray(
-                        probe.rounds_idle, out.tracker.rounds_idle.dtype
-                    ),
-                ),
-            )
+                )
         if nxt is None:
             if launched < max_chunks:  # synchronous mode: launch after probe
                 with _tspan(tracker, "chunk_launch", chunk=launched):
@@ -1865,37 +1897,47 @@ def run_until(
 
     `on_chunk(probe: ChunkProbe)` is invoked once per completed chunk
     (heartbeats/progress); it receives the fetched probe, not the state.
-    `tracker` (utils/tracker.py) records dispatch-pipeline spans and
-    per-host heartbeats (see _drive).
+    `tracker` (utils/tracker.py) records the entry as one `run` span
+    with the entry's own steps (`validate_runahead`, `peek_next_time`,
+    `put_end_time`, `donate_copy`) and the dispatch loop's spans (see
+    _drive) below it, and per-host heartbeats.
     """
-    validate_runahead(cfg, tables)
-    if int(_peek_next_time(st)) >= end_time:
-        # already quiescent: the zero-work fast path of the old driver —
-        # no copy, no chunk dispatch, caller's state returned untouched
-        check_capacity(st)
-        return st
-    end = jnp.asarray(end_time, jnp.int64)
-    with _tspan(tracker, "donate_copy"):
-        st = st.donatable()  # the caller's buffers are never donated
+    with _tspan(tracker, "run"):
+        with _tspan(tracker, "validate_runahead"):
+            validate_runahead(cfg, tables)
+        with _tspan(tracker, "peek_next_time"):
+            quiescent = int(_peek_next_time(st)) >= end_time
+        if quiescent:
+            # already quiescent: the zero-work fast path of the old driver
+            # — no copy, no chunk dispatch, caller's state returned untouched
+            check_capacity(st)
+            return st
+        with _tspan(tracker, "put_end_time"):
+            end = jnp.asarray(end_time, jnp.int64)
+        with _tspan(tracker, "donate_copy"):
+            st = st.donatable()  # the caller's buffers are never donated
 
-    # the seed never enters the traced chunk (it lives in the state's key
-    # grid), so canonicalizing it out of the static cfg lets same-shape
-    # worlds that differ only in seed share one compiled executable
-    jit_cfg = trace_static_cfg(cfg)
+        # the seed never enters the traced chunk (it lives in the state's
+        # key grid), so canonicalizing it out of the static cfg lets
+        # same-shape worlds that differ only in seed share one compiled
+        # executable
+        jit_cfg = trace_static_cfg(cfg)
 
-    chunk_args = (end, rounds_per_chunk, model, tables, jit_cfg)
+        chunk_args = (end, rounds_per_chunk, model, tables, jit_cfg)
 
-    def launch(s):
-        return _run_chunk_jit(s, *chunk_args)
+        def launch(s):
+            return _run_chunk_jit(s, *chunk_args)
 
-    return _drive(
-        launch, st, end_time, max_chunks, on_chunk, pipeline,
-        desc=f"{max_chunks}x{rounds_per_chunk} rounds",
-        tracker=tracker, on_state=on_state,
-        capacity_detail=capacity_topk,
-        watchdog_s=watchdog_s, engine=effective_engine(cfg),
-        compile_chunk=lambda s: _run_chunk_jit.lower(s, *chunk_args).compile(),
-    )
+        return _drive(
+            launch, st, end_time, max_chunks, on_chunk, pipeline,
+            desc=f"{max_chunks}x{rounds_per_chunk} rounds",
+            tracker=tracker, on_state=on_state,
+            capacity_detail=capacity_topk,
+            watchdog_s=watchdog_s, engine=effective_engine(cfg),
+            compile_chunk=lambda s: _run_chunk_jit.lower(
+                s, *chunk_args
+            ).compile(),
+        )
 
 
 def round_body_debug(

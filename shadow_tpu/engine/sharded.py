@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from shadow_tpu import scopes
 from shadow_tpu.engine.round import (
     _drive,
     _peek_next_time,
@@ -142,6 +143,7 @@ class ShardedRunner:
         specs = state_specs(st)
         tspecs = jax.tree.map(lambda _: P(), self.tables)
 
+        @scopes.keyed
         def chunk(st_local, tables_r, end):
             out = run_rounds_scan(
                 st_local,
@@ -155,7 +157,8 @@ class ShardedRunner:
             # probe lanes are reduced over the mesh axis inside the chunk,
             # so the replicated [PROBE_LANES] output is the only thing the
             # driver ever blocks on
-            return out, state_probe(out, axis_name=AXIS)
+            with jax.named_scope(scopes.PROBE):
+                return out, state_probe(out, axis_name=AXIS)
 
         f = shard_map(
             chunk,
@@ -233,29 +236,35 @@ class ShardedRunner:
         `tracker` records the same dispatch spans / per-host heartbeats
         as the single-device driver (the probe lanes arrive psum/pmax
         reduced over the mesh, so heartbeats stay sync-free sharded)."""
-        st = shard_state(st, self.mesh)
-        if int(_peek_next_time(st)) >= end_time:
-            # already quiescent: zero-work fast path, state untouched
-            check_capacity(st)
-            return st
-        # shard_state is a no-op alias when the input is already laid out;
-        # donatable() guarantees the caller's buffers are never donated
-        with _tspan(tracker, "donate_copy"):
-            st = st.donatable()
-        if self._compiled is None:
-            self._compiled = self._chunk_fn(st)
-        end = jnp.asarray(end_time, jnp.int64)
+        with _tspan(tracker, "run"):
+            with _tspan(tracker, "shard_state"):
+                st = shard_state(st, self.mesh)
+            with _tspan(tracker, "peek_next_time"):
+                quiescent = int(_peek_next_time(st)) >= end_time
+            if quiescent:
+                # already quiescent: zero-work fast path, state untouched
+                check_capacity(st)
+                return st
+            # shard_state is a no-op alias when the input is already laid
+            # out; donatable() guarantees the caller's buffers are never
+            # donated
+            with _tspan(tracker, "donate_copy"):
+                st = st.donatable()
+            if self._compiled is None:
+                self._compiled = self._chunk_fn(st)
+            with _tspan(tracker, "put_end_time"):
+                end = jnp.asarray(end_time, jnp.int64)
 
-        def launch(s):
-            return self._compiled(s, self.tables, end)
+            def launch(s):
+                return self._compiled(s, self.tables, end)
 
-        return _drive(
-            launch, st, end_time, max_chunks, on_chunk, pipeline,
-            desc=f"{max_chunks}x{self.rounds_per_chunk} rounds (sharded)",
-            tracker=tracker, on_state=on_state,
-            capacity_detail=self._capacity_detail,
-            watchdog_s=watchdog_s, engine=effective_engine(self.cfg),
-            compile_chunk=lambda s: self._compiled.lower(
-                s, self.tables, end
-            ).compile(),
-        )
+            return _drive(
+                launch, st, end_time, max_chunks, on_chunk, pipeline,
+                desc=f"{max_chunks}x{self.rounds_per_chunk} rounds (sharded)",
+                tracker=tracker, on_state=on_state,
+                capacity_detail=self._capacity_detail,
+                watchdog_s=watchdog_s, engine=effective_engine(self.cfg),
+                compile_chunk=lambda s: self._compiled.lower(
+                    s, self.tables, end
+                ).compile(),
+            )

@@ -22,6 +22,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
+from shadow_tpu import scopes
 from shadow_tpu.events import KIND_INVALID, pack_tie, tie_src_host
 from shadow_tpu.simtime import TIME_MAX
 
@@ -112,8 +113,8 @@ def peek_min(q: EventQueue, want: jax.Array) -> tuple[Popped, jax.Array]:
     # gathers cost well under a millisecond on TPU, while the previous
     # one-hot masked reductions re-read every [H, Q(, 8)] payload array in
     # full — the single biggest per-iteration traffic term at bench scale
-    # (tools/profile_prims.py: per-index cost is what matters, and it only
-    # bites at exchange scale, not at H).
+    # (per-index cost is what matters, and it only bites at exchange
+    # scale, not at H).
     sl1 = slot[:, None]
 
     def pick(arr):
@@ -362,10 +363,11 @@ def push_many_sorted(
         jnp.int32
     )
 
-    q2 = push_self_lanes(
-        q, valid=g_valid, time=long(g[:, :, 0:2]), tie=long(g[:, :, 2:4]),
-        kind=g[:, :, 4], data=g[:, :, 7:], aux=g[:, :, 5],
-    )
+    with jax.named_scope(scopes.PUSH_SELF):
+        q2 = push_self_lanes(
+            q, valid=g_valid, time=long(g[:, :, 0:2]), tie=long(g[:, :, 2:4]),
+            kind=g[:, :, 4], data=g[:, :, 7:], aux=g[:, :, 5],
+        )
     # per-destination overflow beyond deliver_lanes is counted globally
     # (loud via check_capacity), not per host
     return q2.replace(overflow=q2.overflow.at[0].add(overflow_extra))

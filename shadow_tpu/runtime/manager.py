@@ -978,11 +978,14 @@ class Manager:
 
     def _build_tracker(self, progress=None):
         """The host-side tracker registry (utils/tracker.py), or None
-        when neither general.tracker nor general.trace_file asks for it.
-        trace_file alone records dispatch spans; per-host heartbeats and
-        the sim-stats fold need the device counters (general.tracker)."""
+        when none of general.tracker, general.trace_file and
+        experimental.xprof_dir asks for it. trace_file alone records
+        dispatch spans, and an xprof capture wants them too: each span
+        writes itself into the profiler's trace (`shadow:<name>`), on the
+        clock of the device's operations. Per-host heartbeats and the
+        sim-stats fold need the device counters (general.tracker)."""
         g = self.config.general
-        if not (g.tracker or g.trace_file):
+        if not (g.tracker or g.trace_file or self.config.experimental.xprof_dir):
             return None
         from shadow_tpu.utils.tracker import Tracker
 

@@ -17,11 +17,36 @@ tracker.c:407-430, and the worker-local SimStats fold, sim_stats.rs):
     per-kind/per-class counters after them.
 
   * dispatch-pipeline spans — `span(name, **args)` context managers
-    recording wall-time intervals (compile+launch, chunk_launch,
-    probe_fetch, donate_copy, the hybrid pass/upload/drain phases,
-    worker round-trips). Spans nest by construction (a stack of context
-    managers per thread), which is what makes the emitted Chrome trace
-    well-formed.
+    recording wall-time intervals. Spans nest by construction (a stack
+    of context managers per thread), which is what makes the emitted
+    Chrome trace well-formed, and every span records that nesting in
+    its `args`: an `id`, its `parent` (the enclosing span of the thread,
+    None at the top) and the ordinal of the `run` it belongs to (a run
+    is one driver entry: one `TpuScheduler.run` without recovery
+    replays). The drivers' spans, each a child of the one above it:
+
+        run                    engine/round.py run_until and its sharded,
+                               ensemble and mesh twins, entry to return
+          validate_runahead
+          shard_state          sharded / mesh: device_put onto the mesh
+          peek_next_time       the blocking "anything to do?" round trip
+          put_end_time         the end time made a device scalar
+          donate_copy          the caller's state copied, leaf by leaf
+          entry_probe          ensemble / mesh: replicas done at entry
+          compile+launch       chunk 0
+            chunk_compile      its lower().compile() (cache hit or not)
+          chunk_launch         every later chunk
+          probe_fetch          the host blocked on a chunk's probe
+          probe_decide         from a fetched probe to the next launch:
+                               flight recorder, capacity, on_chunk
+            host_stats_fetch   heartbeat cadence only
+            state_snapshot     checkpoint cadence only
+          quiescent_restore    the last chunk's `now` / round counters
+
+    plus the hybrid pass/upload/drain phases and worker round-trips.
+    Each span also enters `jax.profiler.TraceAnnotation("shadow:<name>")`,
+    so a `--xprof-dir` capture shows the driver's spans on the host's
+    line, on the clock of the device's operations.
 
   * a Chrome-trace JSON (`write_trace`) loadable in chrome://tracing or
     Perfetto: one "X" (complete) event per span with microsecond
@@ -41,8 +66,10 @@ import json
 import threading
 import time
 
+import jax
 import numpy as np
 
+RUN_SPAN = "run"  # the root span of one driver entry
 
 # Span-list bound: beyond this many recorded events new spans fold into
 # the running per-phase totals only (the Chrome trace and percentiles
@@ -87,6 +114,9 @@ class Tracker:
         self.counters = counters
         self._t0 = time.perf_counter()
         self._lock = threading.Lock()
+        self._ids = 0  # spans opened so far; a span's id is its ordinal
+        self._runs = 0  # `run` spans opened so far
+        self._open = threading.local()  # .stack: [(id, run)] of this thread
         self.events: "list[dict]" = []  # chrome-trace events, append-only
         # running per-phase wall totals (seconds), updated on every span
         # append — phase_totals() is O(phases), never O(spans), so it is
@@ -117,48 +147,63 @@ class Tracker:
     def _now_us(self) -> float:
         return (time.perf_counter() - self._t0) * 1e6
 
+    def _enter(self, name: str) -> dict:
+        """A new span's place in the tree: its id, the span of this
+        thread that encloses it, and the run that one belongs to (a
+        `run` span starts the next)."""
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        parent, run = stack[-1] if stack else (None, None)
+        with self._lock:
+            self._ids += 1
+            sid = self._ids
+            if name == RUN_SPAN:
+                self._runs += 1
+                run = self._runs
+        stack.append((sid, run))
+        return {"id": sid, "parent": parent, "run": run}
+
+    def _record(self, name: str, ts: float, dur: float, args: dict) -> None:
+        ev = {
+            "name": name,
+            "cat": "dispatch",
+            "ph": "X",
+            "ts": ts,
+            "dur": dur,
+            "pid": 0,
+            "tid": threading.get_ident() % (1 << 31),
+            "args": args,
+        }
+        with self._lock:
+            if len(self.events) < _MAX_EVENTS:
+                self.events.append(ev)
+            self._totals[name] = self._totals.get(name, 0.0) + dur / 1e6
+
     @contextlib.contextmanager
     def span(self, name: str, **args):
+        place = self._enter(name)
         ts = self._now_us()
         try:
-            yield
+            with jax.profiler.TraceAnnotation("shadow:" + name):
+                yield
         finally:
             dur = self._now_us() - ts
-            ev = {
-                "name": name,
-                "cat": "dispatch",
-                "ph": "X",
-                "ts": ts,
-                "dur": dur,
-                "pid": 0,
-                "tid": threading.get_ident() % (1 << 31),
-            }
-            if args:
-                ev["args"] = args
-            with self._lock:
-                if len(self.events) < _MAX_EVENTS:
-                    self.events.append(ev)
-                self._totals[name] = self._totals.get(name, 0.0) + dur / 1e6
+            self._open.stack.pop()
+            self._record(name, ts, dur, {**place, **args})
 
     def add_span(self, name: str, t_start: float, t_end: float, **args) -> None:
         """Record an already-measured interval (time.perf_counter
         timestamps) — for callers that keep their own phase clocks, like
         the parallel hybrid scheduler's phase_wall accounting."""
-        ev = {
-            "name": name,
-            "cat": "dispatch",
-            "ph": "X",
-            "ts": (t_start - self._t0) * 1e6,
-            "dur": max(0.0, (t_end - t_start) * 1e6),
-            "pid": 0,
-            "tid": threading.get_ident() % (1 << 31),
-        }
-        if args:
-            ev["args"] = args
-        with self._lock:
-            if len(self.events) < _MAX_EVENTS:
-                self.events.append(ev)
-            self._totals[name] = self._totals.get(name, 0.0) + ev["dur"] / 1e6
+        place = self._enter(name)
+        self._open.stack.pop()
+        self._record(
+            name,
+            (t_start - self._t0) * 1e6,
+            max(0.0, (t_end - t_start) * 1e6),
+            {**place, **args},
+        )
 
     def instant(self, name: str, **args) -> None:
         ev = {

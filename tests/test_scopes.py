@@ -1,0 +1,180 @@
+"""The layer scopes inside the chunk program (shadow_tpu/scopes.py).
+
+Contracts pinned here, on the CPU at 8 hosts:
+
+  * every scope of the one table appears in the `op_name` metadata of the
+    compiled chunk, for tgen and phold, plain and pump, and — on 4 virtual
+    devices — the sharded chunk has its exchange collectives under
+    `exchange/collective` and the window's all_gather under `window`;
+  * `jax.named_scope` writes metadata and nothing else: the lowered chunk
+    is the same text with the scopes patched out, so the program, its
+    trajectories and its cost are unchanged;
+  * the instruction -> scope table (`scopes.parse_hlo_text`) names a scope
+    for at least nine in ten of the chunk's operations that carry an
+    `op_name`, and a table without a single scope is refused loudly.
+
+Each chunk is lowered twice and compiled once per module (the fixture).
+"""
+
+import contextlib
+import dataclasses
+import re
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from test_pipeline import _phold_world
+from test_pump import _world as _tgen_world
+
+from shadow_tpu import scopes
+from shadow_tpu.engine import round as rnd
+from shadow_tpu.engine.sharded import AXIS, ShardedRunner, shard_state
+from shadow_tpu.engine.state import trace_static_cfg
+
+END = jnp.asarray(30_000_000, jnp.int64)
+ROUNDS = 2
+CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
+EVERYWHERE = {
+    "window", "drain", "drain/handle", "drain/handle/push_self", "exchange",
+    "exchange/land", "exchange/land/push_self", "probe",
+}
+EXPECTED = {
+    "tgen-plain": EVERYWHERE,
+    "tgen-pump": EVERYWHERE | {"drain/pump", "drain/pump/push_self"},
+    # phold publishes no pump_spec: every engine value takes the handler
+    "phold-plain": EVERYWHERE,
+    "phold-pump": EVERYWHERE,
+    "tgen-sharded": EVERYWHERE | {"exchange/collective"},
+}
+
+
+def _lower(case):
+    """The case's chunk, traced anew (a function object of its own, so no
+    trace cache answers) and lowered."""
+    kind, engine = case.split("-")
+    if kind == "tgen":
+        cfg, model, tables, st = _tgen_world(8, 0.02, 20_000_000, seed=3)
+    else:
+        cfg, model, tables, st = _phold_world(8)
+    cfg = dataclasses.replace(cfg, tracker=True)
+    if engine == "pump":
+        cfg = dataclasses.replace(cfg, engine="pump", pump_k=3)
+    if engine == "sharded":
+        mesh = Mesh(np.array(jax.devices()[:4]), (AXIS,))
+        runner = ShardedRunner(mesh, model, tables, cfg, rounds_per_chunk=ROUNDS)
+        st = shard_state(st, mesh)
+        return runner._chunk_fn(st).lower(st, tables, END)
+
+    def chunk(*args):
+        return rnd._run_chunk(*args)
+
+    chunk.__name__ = rnd._run_chunk.__name__  # the module's name, as the driver jits it
+    fn = jax.jit(chunk, static_argnums=(2, 3, 5), donate_argnums=(0,))
+    return fn.lower(st, END, ROUNDS, model, tables, trace_static_cfg(cfg))
+
+
+@pytest.fixture(scope="module")
+def chunks():
+    """{case: (lowered text, lowered text without scopes, optimized HLO)}"""
+    out = {}
+    for case in CASES:
+        lowered = _lower(case)
+        with mock.patch.object(jax, "named_scope", lambda name: contextlib.nullcontext()):
+            bare = _lower(case).as_text()
+        out[case] = (lowered.as_text(), bare, lowered.compile().as_text())
+    return out
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_every_scope_names_operations_of_the_compiled_chunk(chunks, case):
+    table = scopes.parse_hlo_text(chunks[case][2])
+    found = {v[1] for v in table.values() if v[1]}
+    assert EXPECTED[case] <= found, EXPECTED[case] - found
+    # nothing outside the one list, and outermost is the path's head
+    for shape, inner, outer in table.values():
+        if inner:
+            assert all(p in scopes.SCOPES for p in inner.split("/"))
+            assert outer == inner.split("/")[0]
+
+
+def _scopes_of(text, opcode):
+    """The scope paths of the instructions of one opcode."""
+    return {
+        scopes.scope_path(op.group(1))
+        for line in text.splitlines()
+        if f" {opcode}(" in line and (op := scopes._OP_NAME.search(line))
+    }
+
+
+def test_sharded_collectives_lie_under_their_scopes(chunks):
+    text = chunks["tgen-sharded"][2]
+    assert _scopes_of(text, "all-to-all") == {"exchange/collective"}
+    # the window's pmin (an all_gather reduced locally), and the probe's
+    gathers = _scopes_of(text, "all-gather")
+    assert "window" in gathers and gathers <= {"window", "probe"}
+    # the staged-traffic test's psum: the window's, and the flush's own
+    assert _scopes_of(text, "all-reduce") <= {"window", "exchange", "probe"}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_scopes_are_metadata_only(chunks, case):
+    scoped, bare, _ = chunks[case]
+    assert len(scoped.splitlines()) > 100
+    assert scoped == bare
+    # the module's name carries the scope table's digest: the compile
+    # cache's key holds the name, and leaves the scopes themselves out
+    assert scopes.KEY in scoped.splitlines()[0]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_table_covers_the_chunk(chunks, case):
+    """Of the operations the program traced (those that kept an
+    `op_name`), at least 90 % lie under a scope; with the compiler's own
+    (copies, rewritten reductions — XLA:CPU drops their metadata) counted
+    as misses, still 80 %."""
+    table = scopes.parse_hlo_text(chunks[case][2])
+    paths = [v[1] for v in table.values()]
+    scoped = sum(1 for p in paths if p)
+    traced = sum(1 for p in paths if p is not None)
+    assert len(paths) > 50
+    assert scoped >= 0.9 * traced, (scoped, traced)
+    assert scoped >= 0.8 * len(paths), (scoped, len(paths))
+
+
+def test_a_table_without_a_scope_is_refused_loudly(chunks, monkeypatch):
+    """An executable whose text names no scope (what a compile-cache entry
+    of an unscoped build gives): one loud line, and None — never a guess."""
+    said = []
+    monkeypatch.setattr(scopes, "_loud", said.append)
+    bare = re.sub(r'op_name="[^"]*"', 'op_name="jit(_run_chunk)/while/body/add"',
+                  chunks["phold-plain"][2])
+
+    class Stale:
+        def as_text(self):
+            return bare
+
+    assert scopes.chunk_table(Stale()) is None
+    assert len(said) == 1 and "NONE" in said[0]
+    monkeypatch.setattr(scopes, "last_chunk", None)
+    assert scopes.chunk_table() is None and len(said) == 2
+
+    class Fresh:
+        def as_text(self):
+            return chunks["phold-plain"][2]
+
+    fresh = Fresh()
+    table = scopes.chunk_table(fresh)
+    assert table and scopes.chunk_table(fresh) is table  # memoised
+    assert len(said) == 2
+
+
+def test_scope_path_keeps_only_the_lists_names():
+    op = "jit(_run_chunk)/while/body/closed_call/cond/branch_1_fun/drain/while/body/handle/push_self/select_n"
+    assert scopes.scope_path(op) == "drain/handle/push_self"
+    assert scopes.scope_path("jit(_run_chunk)/while/body/add") == ""
+    # no scope is named like something JAX writes into an op_name itself
+    assert not set(scopes.SCOPES) & {"cond", "body", "while", "closed_call", "jit"}

@@ -19,6 +19,7 @@ Contracts pinned here:
     counter.
 """
 
+import contextlib
 import dataclasses
 import io
 import json
@@ -375,6 +376,148 @@ def test_chrome_trace_valid_and_well_nested(tmp_path):
                 disjoint = b0 >= a1 - eps
                 contained = b1 <= a1 + eps
                 assert disjoint or contained, (a, b)
+
+
+# --- the span tree: ids, parents, one `run` root per driver entry ---------
+
+
+DRIVER_SPANS = {
+    "run", "validate_runahead", "peek_next_time", "put_end_time", "donate_copy",
+    "compile+launch", "chunk_compile", "chunk_launch", "probe_fetch",
+    "probe_decide", "quiescent_restore",
+}
+
+
+def _driver_spans(sharded):
+    # the sharded runner validates the runahead once, when it is built
+    return DRIVER_SPANS - {"validate_runahead"} | {"shard_state"} if sharded else DRIVER_SPANS
+
+
+def _drive_thrice(driver):
+    """Three entries of one driver under one Tracker (the first compiles)."""
+    cfg, model, tables, st0 = _phold_world(64)
+    tracker = Tracker()
+    if driver == "sharded":
+        import numpy as np
+        from jax.sharding import Mesh
+
+        from shadow_tpu.engine.sharded import AXIS, ShardedRunner
+
+        mesh = Mesh(np.array(jax.devices()[:4]), (AXIS,))
+        runner = ShardedRunner(mesh, model, tables, cfg, rounds_per_chunk=16)
+
+        def run():
+            return runner.run_until(st0, 40 * NS_PER_MS, tracker=tracker)
+
+    else:
+
+        def run():
+            return run_until(
+                st0, 40 * NS_PER_MS, model, tables, cfg,
+                rounds_per_chunk=16, tracker=tracker,
+            )
+
+    for _ in range(3):
+        run()
+    return tracker.spans()
+
+
+@pytest.mark.parametrize("driver", ["single", "sharded"])
+def test_spans_form_one_tree_per_run(driver):
+    """Every span carries an id, its parent and its run; a driver entry is
+    one `run` root with every other span of the entry below it; and what a
+    `run` spends outside all of its children is under 5 % of it (in the
+    entry that compiles, and in the quieter of the two that do not: what
+    is left there is the tracker's own bookkeeping between spans)."""
+    spans = _drive_thrice(driver)
+    by_id = {e["args"]["id"]: e for e in spans}
+    assert len(by_id) == len(spans)  # ids are unique
+    roots = [e for e in spans if e["args"]["parent"] is None]
+    assert [e["name"] for e in roots] == ["run"] * 3
+    assert [e["args"]["run"] for e in roots] == [1, 2, 3]
+    want = _driver_spans(sharded=driver == "sharded")
+    outside = []
+    for root in roots:
+        mine = [e for e in spans if e["args"]["run"] == root["args"]["run"]]
+        assert {e["name"] for e in mine} == want
+        for e in mine:
+            if e is root:
+                continue
+            parent = by_id[e["args"]["parent"]]
+            assert parent["args"]["run"] == root["args"]["run"]
+            # a child lies inside its parent, on the tracker's one clock
+            assert parent["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-3
+        by_name = {e["name"]: e for e in mine}
+        assert by_name["chunk_compile"]["args"]["parent"] == by_name["compile+launch"]["args"]["id"]
+        children = [e for e in mine if e["args"]["parent"] == root["args"]["id"]]
+        outside.append((root["dur"] - sum(e["dur"] for e in children)) / root["dur"])
+    assert 0 <= outside[0] < 0.05 and 0 <= min(outside[1:]) < 0.05, outside
+
+
+def test_span_tree_is_per_thread_and_add_span_joins_it():
+    """A span opened on another thread is no child of this thread's open
+    span, and an already-measured interval (add_span) is booked under the
+    span that is open where it is recorded."""
+    import threading
+    import time
+
+    tracker = Tracker()
+
+    def elsewhere():
+        with tracker.span("elsewhere"):
+            pass
+
+    with tracker.span("run"):
+        with tracker.span("outer"):
+            t = threading.Thread(target=elsewhere)
+            t.start()
+            t.join()
+            t0 = time.perf_counter()
+            tracker.add_span("measured", t0, t0 + 0.001, worker=3)
+    with tracker.span("after"):
+        pass
+    spans = {e["name"]: e["args"] for e in tracker.spans()}
+    assert spans["outer"]["parent"] == spans["run"]["id"]
+    assert spans["elsewhere"]["parent"] is None and spans["elsewhere"]["run"] is None
+    assert spans["measured"]["parent"] == spans["outer"]["id"]
+    assert spans["measured"]["run"] == 1 and spans["measured"]["worker"] == 3
+    assert spans["after"] == {"id": spans["after"]["id"], "parent": None, "run": None}
+
+
+class _SeamTracker:
+    """What benchmarks/run.py hands the driver: `span` and
+    `host_heartbeat_due`, and nothing else."""
+
+    def __init__(self):
+        self.names = []
+
+    @contextlib.contextmanager
+    def span(self, name, **_args):
+        self.names.append(name)
+        yield
+
+    def host_heartbeat_due(self, _now):
+        return False
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_duck_typed_tracker_drives_scheduler_run(devices):
+    """The tracker seam stays duck-typed: an object with only `span` and
+    `host_heartbeat_due` takes `TpuScheduler.run` to the end, one device
+    or sharded, and sees every span of the driver."""
+    from shadow_tpu.runtime.scheduler import TpuScheduler
+
+    cfg, model, tables, st0 = _phold_world(8)
+    sched = TpuScheduler(model, tables, cfg, parallelism=devices, rounds_per_chunk=4)
+    assert sched.num_devices == devices
+    seam = _SeamTracker()
+    probes = []
+    out = sched.run(20 * NS_PER_MS, on_chunk=probes.append, tracker=seam, start_state=st0)
+    assert probes and int(out.events_handled.sum()) == probes[-1].events_handled > 0
+    want = _driver_spans(sharded=devices > 1)
+    assert set(seam.names) == want
+    assert seam.names[0] == "run"
 
 
 # --- CLI / manager end-to-end (the tier-1 tooling smoke) ----------------

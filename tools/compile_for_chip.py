@@ -30,7 +30,8 @@ Run with JAX_PLATFORMS=cpu, every call under a `timeout`, in the background:
 Only one process at a time may load the TPU's library; to run several of
 these at once, set ALLOW_MULTIPLE_LIBTPU_LOAD=1 in the shell (never in the
 repository). `--hlo-dump DIR` passes XLA's --xla_dump_to; `--hlo-stats`
-prints the op histogram of the optimized HLO of each piece.
+prints the op histogram of the optimized HLO of each piece, and how many
+of its operations lie under each of the engine's scopes.
 """
 
 from __future__ import annotations
@@ -105,7 +106,17 @@ def hlo_histogram(text: str, top: int = 25) -> str:
     return ", ".join(f"{k}:{v}" for k, v in ops.most_common(top))
 
 
-def report(name: str, lowered_fn, hlo_stats: bool) -> bool:
+def scope_histogram(text: str) -> str:
+    """Operations of the optimized HLO by the engine's `jax.named_scope`
+    path (shadow_tpu/scopes.py): "" traced under no scope, None made by the
+    compiler in a body no scope encloses."""
+    from shadow_tpu import scopes
+
+    paths = collections.Counter(v[1] for v in scopes.parse_hlo_text(text).values())
+    return ", ".join(f"{k!r}:{v}" for k, v in paths.most_common())
+
+
+def report(name: str, lowered_fn, hlo_stats: bool, dump_dir: str = "") -> bool:
     """Lower + compile one piece; print one block. Returns compiled?"""
     print(f"== {name}", flush=True)
     t0 = time.perf_counter()
@@ -130,10 +141,14 @@ def report(name: str, lowered_fn, hlo_stats: bool) -> bool:
         f"code {ma.generated_code_size_in_bytes / 2**20:.1f} MiB"
     )
     print(f"   COMPILED in {wall:.1f}s; {mem}", flush=True)
+    if dump_dir:  # the chip's compiler does not honour --xla_dump_to itself
+        with open(os.path.join(dump_dir, name + ".optimized.txt"), "w") as f:
+            f.write(compiled.as_text())
     if hlo_stats:
         text = compiled.as_text()
         print(f"   optimized HLO: {len(text.splitlines())} lines; {hlo_histogram(text)}",
               flush=True)
+        print(f"   operations by scope: {scope_histogram(text)}", flush=True)
     return True
 
 
@@ -270,7 +285,7 @@ def main(argv=None) -> int:
 
     ok = True
     for name in args.pieces:
-        ok &= report(name, piece(name), args.hlo_stats)
+        ok &= report(name, piece(name), args.hlo_stats, args.hlo_dump or "")
     return 0 if ok else 1
 
 
