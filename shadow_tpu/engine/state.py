@@ -66,10 +66,10 @@ class EngineConfig:
     # worker.rs:619-629). Two landing families, trajectory-identical by
     # contract (delivery slot order is key-driven; engine/round.py
     # flush_outbox):
-    #   dense  — route packets into a dest-major [H, deliver_lanes] grid
-    #            (one index sort by destination, the payload following
-    #            as packed rows — equeue.push_many_sorted) and merge it
-    #            with fused per-lane selects. "all_to_all"
+    #   dense  — land by pull (equeue.push_many_sorted): one index sort
+    #            by destination, the payload following as packed rows,
+    #            then every free queue slot gathers its arrival by rank
+    #            and one where pass merges it. "all_to_all"
     #            (default) buckets outbox entries by destination shard
     #            and exchanges only each peer's bucket over ICI;
     #            "all_gather" replicates every shard's whole outbox
@@ -82,9 +82,9 @@ class EngineConfig:
     #            replica vmap, unlike lax.all_to_all), and land with one
     #            M-sized free-slot scatter + segment offsets
     #            (equeue.push_many_segment) — cost scales with the
-    #            traffic actually in flight, not the [H, lanes] grid,
-    #            and capacity is checked once per round (pool/row
-    #            occupancy) instead of per lane.
+    #            traffic actually in flight (as the dense landing's
+    #            does since it became a pull), and capacity is checked
+    #            once per round (pool/row occupancy).
     exchange: str = "all_to_all"
     # per-peer bucket capacity for all_to_all:
     #  -1  (default) = the whole local outbox: never overflows. PDES
@@ -110,22 +110,14 @@ class EngineConfig:
     # ring-bucket bytes, and events beyond the pool are counted loudly
     # into outbox overflow (check_capacity names this knob).
     pool_capacity: int = 0
-    # Round-boundary delivery grid width: the exchange routes packets into
-    # a dest-major [H, deliver_lanes] grid (equeue.push_many_sorted: one
-    # (destination, position) sort, then one row gather and one row
-    # scatter of the packed payload) and merges it densely. An earlier
-    # spelling carried the whole payload through the sorts because XLA
-    # TPU scatter serializes per index (round-3 profile: ~125 ms/round
-    # for five per-column scatters vs ~4 ms for full-payload sorts); the
-    # chip's compiler takes ~14 s per operand word of such a sort, which
-    # no front-door run could afford (PR 22). tools/profile_landing.py
-    # times both spellings and the new one's pieces on the chip; the
-    # numbers are in CHANGES.md (PR 22) and ROADMAP speed item 2.
-    # Bounds deliveries per host per ROUND; beyond it overflows loudly
-    # via check_capacity. 0 (default) = queue_capacity: exact — a
-    # delivery wave the queue could hold can never be grid-bounded.
-    # Large worlds with bounded fan-in (e.g. the pairwise bench) set a
-    # small width so the grid sort stays at traffic scale.
+    # Per-destination bound of the round-boundary landing
+    # (equeue.push_many_sorted): a host takes at most its first
+    # deliver_lanes arrivals of a ROUND; beyond it overflows loudly via
+    # check_capacity. 0 (default) = queue_capacity: exact — a delivery
+    # wave the queue could hold is never bounded by this. The landing is
+    # a pull (every free queue slot gathers its arrival by rank from the
+    # destination-sorted payload), so this width sizes no buffer and
+    # costs nothing at run or compile time (CHANGES.md PR 27).
     deliver_lanes: int = 0
     # Active-set compaction (engine/round.py handle_one_iteration_compact):
     # per pop-iteration, gather only the <= active_lanes hosts that actually

@@ -22,7 +22,6 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
-from shadow_tpu import scopes
 from shadow_tpu.events import KIND_INVALID, pack_tie, tie_src_host
 from shadow_tpu.simtime import TIME_MAX
 
@@ -270,8 +269,8 @@ def push_many(
 
     This is the round-boundary exchange step (the analogue of
     Worker::push_packet_to_host, reference src/main/core/worker.rs:619-629,
-    minus the mutex). Delegates to the delivery-grid implementation with
-    a full-capacity grid (exact, never grid-bounded)."""
+    minus the mutex). push_many_sorted with no per-destination bound but
+    the queue's own capacity (exact: only a full row rejects)."""
     return push_many_sorted(
         q, dst, valid, time, tie, kind, data, aux,
         deliver_lanes=q.capacity,
@@ -289,88 +288,111 @@ def push_many_sorted(
     aux: "jax.Array | None" = None,  # [M] i32
     deliver_lanes: int = 48,
 ) -> EventQueue:
-    """push_many through a dest-major delivery grid [H, D] (D =
-    deliver_lanes), merged into the queue rows by the dense
-    push_self_lanes pattern:
+    """push_many as a PULL: every free queue slot works out which arrival,
+    if any, it receives, and gathers it.
 
       S   one stable sort of (destination, position) — two words per
-          entry, invalids last; per-destination ranks fall out of a dense
-          segment cummax over the sorted keys;
-      G   the payload follows as packed 32-bit rows: one row gather into
-          sorted order, one row scatter to grid slot dst * D + rank.
-          Entries that do not fit (rank >= D) and invalid ones get an
-          out-of-bounds slot and are dropped by the scatter; slots are
-          unique among fitting entries (ranks within a destination are
-          distinct), so no entry can land on another host's row or on an
-          occupied slot, overflow or not.
+          entry, invalids last — puts each destination's arrivals in one
+          run, in arrival order; cnt[h] of the runs is a histogram of the
+          keys (one product of two one-hot matrices), begin[h] its
+          exclusive cumulative sum;
+      G   the payload follows as 32-bit words (14 an entry): one gather
+          into sorted order;
+      P   slot c of row h is that row's fr[h, c]-th free (tombstoned)
+          slot; it takes arrival begin[h] + fr[h, c] while fr[h, c] <
+          land[h] = min(cnt[h], D, room[h]) — ONE [H, Q] row gather and
+          one where pass over the five queue arrays.
 
-    Per-host deliveries beyond D or queue capacity are counted loudly in
-    overflow. Slot order within a destination equals arrival order of
-    the stable sort; pop order is key-driven anyway.
+    Cost follows the queue and the batch, not D = deliver_lanes: there is
+    no [H, D] delivery grid, no scatter and no lane-by-lane merge. D keeps
+    its meaning: a destination takes at most its first D arrivals of a
+    call (rank < D fits); those beyond D are counted on overflow row 0,
+    those beyond the row's room on the row's own overflow — both loud via
+    check_capacity. The r-th arrival lands in the row's r-th free slot
+    (arrival order of the stable sort); pop order is key-driven anyway.
 
     Why the payload does not ride the sort: XLA:TPU's sort costs the
     chip's compiler ~14 s per 32-bit operand word once the array no
-    longer sorts in one tile (> 16k entries) — the former spelling
-    carried all 16 payload words through two sorts of max(M, H*D)
-    entries plus two index sorts to enumerate the unfilled slots, ~10
-    minutes of compile for this function alone at any real world size
-    (tools/compile_for_chip.py, CHANGES.md PR 22). Grid contents at valid
-    slots, and so every queue leaf, are identical to that spelling.
+    longer sorts in one tile (> 16k entries); and searchsorted's
+    method="sort" is one more such sort, 43 s of compile at this size
+    (tools/compile_for_chip.py, CHANGES.md PR 22).
+
+    Same TIME_MAX invariant as push_self: a push at TIME_MAX (the
+    free-slot marker) is rejected and counted into overflow — on row 0,
+    as push_many_segment does; always fatal via check_capacity.
     """
+    m = dst.shape[0]
+    if m == 0:
+        return q
     if aux is None:
         aux = jnp.zeros_like(kind)
-    m = dst.shape[0]
-    h = q.num_hosts
-    # a destination can receive at most M entries, so the grid never needs
-    # to be wider than M (keeps the exact push_many path — deliver_lanes ==
-    # capacity — at traffic scale for small-M callers like hybrid uploads)
-    d = min(deliver_lanes, m)
-    grid = h * d
+    h, cap = q.num_hosts, q.capacity
+    n_pushed = jnp.sum(valid, dtype=jnp.int32)
+    valid = valid & (time < TIME_MAX) & (dst >= 0) & (dst < h)
 
     # S: group by destination (stable; invalids sort last)
     key1 = jnp.where(valid, dst, h).astype(jnp.int32)
     pos = jnp.arange(m, dtype=jnp.int32)
-    key1_s, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), key1_s[1:] != key1_s[:-1]]
+    _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
+    # the runs: cnt[a * 128 + b] = sum_i [key_i // 128 == a][key_i % 128 == b],
+    # one product of two one-hot matrices (exact in int32; on the chip the
+    # MXU's, 0.1 ms where H+1 binary searches over the sorted keys took
+    # 1.2-1.4 ms a round: PERF.md, PR 27). Key h, the invalids', matches
+    # no column or one past the last host's.
+    blocks = -(-h // 128)
+    hot_a = (key1 >> 7)[:, None] == jnp.arange(blocks, dtype=jnp.int32)
+    hot_b = (key1 & 127)[:, None] == jnp.arange(128, dtype=jnp.int32)
+    cnt = jnp.dot(
+        hot_a.T.astype(jnp.int8), hot_b.astype(jnp.int8),
+        preferred_element_type=jnp.int32,
+    ).reshape(-1)[:h]
+    begin = jnp.cumsum(cnt, dtype=jnp.int32) - cnt  # [H] start of h's run
+
+    # G: the payload as 32-bit words, word-major [W, M]: time and tie as
+    # (low, high), kind, aux, the data lanes. Word-major because the chip
+    # tiles the two minor dimensions: a [.., W] minor of 14 pads to 128
+    # lanes and every field sliced out of it is a strided pass over that.
+    def lo(x):  # i64 -> its low 32 bits as i32
+        return x.astype(jnp.int32)
+
+    def hi(x):
+        return (x >> 32).astype(jnp.int32)
+
+    def long(low, high):  # the i64 back, bit-exact
+        low = jax.lax.bitcast_convert_type(low, jnp.uint32)
+        return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
+
+    words = jnp.concatenate(
+        [jnp.stack([lo(time), hi(time), lo(tie), hi(tie), kind, aux]), data.T]
     )
-    rank = pos - jax.lax.cummax(jnp.where(seg_start, pos, -1))
-    fits = (key1_s < h) & (rank < d)
-    slot = jnp.where(fits, key1_s * d + rank, grid)  # OOB -> dropped
+    words_s = words[:, order]
 
-    # G: packed rows [time(2) | tie(2) | kind | aux | used | data...]
-    def words(x):  # i64 [M] -> i32 [M, 2], bit-exact
-        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    # P: each free slot pulls its arrival by rank
+    free = q.time == TIME_MAX  # [H, Q]
+    fr = (jnp.cumsum(free, axis=1) - free).astype(jnp.int32)  # rank among free slots
+    fit = jnp.minimum(cnt, deliver_lanes)
+    land = jnp.minimum(fit, cap - q.count)  # [H]
+    take = free & (fr < land[:, None])
+    src = jnp.minimum(begin[:, None] + fr, m - 1)  # only read where take
+    g = words_s[:, src]  # [W, H, Q]
 
-    rows = jnp.concatenate(
-        [words(time), words(tie), kind[:, None], aux[:, None],
-         jnp.ones((m, 1), jnp.int32), data],
-        axis=1,
+    g_time = long(g[0], g[1])
+    return q.replace(
+        time=jnp.where(take, g_time, q.time),
+        tie=jnp.where(take, long(g[2], g[3]), q.tie),
+        kind=jnp.where(take, g[4], q.kind),
+        data=jnp.where(take[:, :, None], jnp.moveaxis(g[6:], 0, -1), q.data),
+        aux=jnp.where(take, g[5], q.aux),
+        count=q.count + land,
+        # beyond the row's room: on the row; beyond deliver_lanes, at
+        # TIME_MAX or to no host of this queue: globally on row 0
+        overflow=(q.overflow + (fit - land))
+        .at[0]
+        .add(n_pushed - jnp.sum(fit, dtype=jnp.int32)),
+        head_time=jnp.minimum(
+            q.head_time, jnp.min(jnp.where(take, g_time, TIME_MAX), axis=1)
+        ),
     )
-    g = (
-        jnp.zeros((grid, rows.shape[1]), jnp.int32)
-        .at[slot]
-        .set(rows[order], mode="drop")
-        .reshape(h, d, rows.shape[1])
-    )
-
-    def long(x):  # i32 [H, D, 2] -> i64 [H, D]
-        return jax.lax.bitcast_convert_type(x, jnp.int64)
-
-    g_valid = g[:, :, 6] != 0
-    n_valid = jnp.sum(valid.astype(jnp.int32))
-    overflow_extra = (n_valid - jnp.sum(g_valid.astype(jnp.int32))).astype(
-        jnp.int32
-    )
-
-    with jax.named_scope(scopes.PUSH_SELF):
-        q2 = push_self_lanes(
-            q, valid=g_valid, time=long(g[:, :, 0:2]), tie=long(g[:, :, 2:4]),
-            kind=g[:, :, 4], data=g[:, :, 7:], aux=g[:, :, 5],
-        )
-    # per-destination overflow beyond deliver_lanes is counted globally
-    # (loud via check_capacity), not per host
-    return q2.replace(overflow=q2.overflow.at[0].add(overflow_extra))
 
 
 def push_many_segment(
@@ -385,11 +407,10 @@ def push_many_segment(
 ) -> EventQueue:
     """Sort-based segment landing (event-exchange v2): one stable
     destination sort + ragged segment offsets + an M-sized free-slot
-    scatter, instead of push_many_sorted's [H, D] delivery grid.
+    scatter, where push_many_sorted gathers from the queue's side.
 
-    Where the dense path builds a full dest-major [H, D] grid and merges
-    it with a D-deep select chain per queue array, this lands the M
-    in-flight entries directly:
+    Where the dense path has every free queue slot pull its arrival (an
+    [H, Q] gather), this pushes the M in-flight entries to their slots:
 
       S1  stable sort of everything by destination (invalids last) —
           per-destination ranks from a dense segment cummax, and the
@@ -422,9 +443,9 @@ def push_many_segment(
 
     Same TIME_MAX invariant as push_self: a push at TIME_MAX (the
     free-slot marker) is rejected and counted into overflow — globally
-    on row 0 (the destination is not recoverable after masking), unlike
-    the dense path's per-row count; sentinel pushes are engine bugs and
-    always fatal via check_capacity either way."""
+    on row 0 (the destination is not recoverable after masking), as on
+    the dense path; sentinel pushes are engine bugs and always fatal via
+    check_capacity."""
     if aux is None:
         aux = jnp.zeros_like(kind)
     m = dst.shape[0]

@@ -78,9 +78,10 @@ class StateRetainer:
 def grown_cfg(cfg, err: CapacityError, growth: int):
     """The next rung of the escalation ladder: double (x`growth`) the
     capacity of the buffer the CapacityError names. Queue growth also
-    widens an explicit deliver_lanes grid (the round-boundary delivery
-    grid is a queue-side resource — its overflow counts into
-    queue.overflow). When the error carries no split (older callers),
+    widens an explicit deliver_lanes bound (the landing's per-destination
+    bound is a queue-side resource — its overflow counts into
+    queue.overflow; widening it costs nothing since the landing is a
+    pull). When the error carries no split (older callers),
     grow both."""
     q_ov = getattr(err, "queue_overflow", 0)
     o_ov = getattr(err, "outbox_overflow", 0)

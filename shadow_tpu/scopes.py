@@ -31,8 +31,10 @@ Every name is a single path component; nested scopes give paths:
     exchange/collective     all_to_all / all_gather / the ppermute ring
                             (sharded only)
     exchange/land           equeue.push_many_sorted / push_many_segment:
-                            destination sort, row gather, row scatter
-    exchange/land/push_self the delivery grid merged into the queue rows
+                            destination sort, the runs' bounds, row
+                            gather into sorted order, the [H, queue]
+                            pull gather and the one where pass that
+                            merges it (no push_self under land)
     probe                   state_probe and the tracker plane's per-round
                             high-water marks
 """

@@ -178,26 +178,35 @@ def test_exact_division_matches_numpy_and_compiles(one_chip):
     _compile(divmod_nonneg, sd, sd)
 
 
-def test_delivery_grid_landing_compiles(world, one_chip):
+@pytest.mark.parametrize(
+    "queue, deliver_lanes", [(16, 4), (QUEUE, QUEUE)], ids=["lanes4", "front_door"]
+)
+def test_delivery_grid_landing_compiles(world, one_chip, queue, deliver_lanes):
     """equeue.push_many_sorted — the front door's exchange landing — with
-    a whole 10,240-host outbox (655,360 entries) in flight, onto a
-    narrowed queue so the lane merge stays seconds: the index sort and
-    the packed row gather/scatter are what is asked here."""
+    a whole 10,240-host outbox (655,360 entries) in flight: onto a
+    narrowed queue at deliver_lanes=4, and as the front door runs it
+    (deliver_lanes 0 = the queue's capacity, 384). The landing is a pull,
+    so neither width sizes anything: the index sort, the one-hot product
+    that counts the runs and the two row gathers are what is asked here."""
     m = HOSTS * OUTBOX
-    q = _on(jax.eval_shape(lambda: equeue.create(HOSTS, 16)), one_chip)
+    q = _on(jax.eval_shape(lambda: equeue.create(HOSTS, queue)), one_chip)
 
     def sd(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     compiled = _compile(
-        lambda q, *a: equeue.push_many_sorted(q, *a, deliver_lanes=4),
+        lambda q, *a: equeue.push_many_sorted(q, *a, deliver_lanes=deliver_lanes),
         q, sd((m,), jnp.int32), sd((m,), bool), sd((m,), jnp.int64),
         sd((m,), jnp.int64), sd((m,), jnp.int32),
         sd((m, equeue.PAYLOAD_LANES), jnp.int32), sd((m,), jnp.int32),
     )
-    # the payload must not ride the sort: (destination, position) only
-    sorts = [ln.split(" sort(")[0] for ln in compiled.as_text().splitlines() if " sort(" in ln]
-    assert sorts and all(ln.count(f"[{m}]") == 2 and "s64[" not in ln for ln in sorts), sorts
+    text = compiled.as_text()
+    # the payload must not ride the sort: (destination, position) only,
+    # and nothing else is sorted (searchsorted's method="sort" would be)
+    sorts = [ln.split(" sort(")[0] for ln in text.splitlines() if " sort(" in ln]
+    assert len(sorts) == 1 and sorts[0].count(f"[{m}]") == 2 and "s64[" not in sorts[0], sorts
+    # no delivery grid: nothing of [H * D] rows is scattered; the landing gathers
+    assert " scatter(" not in text and " gather(" in text
 
 
 def test_sharded_window_and_exchange_collective_compile(topo):

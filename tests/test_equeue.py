@@ -4,8 +4,10 @@ validated property-style against a plain Python sorted list."""
 
 import random
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from shadow_tpu import equeue
 from shadow_tpu.equeue import PAYLOAD_LANES
@@ -207,6 +209,117 @@ def test_push_many_sorted_overflow_m_gt_grid_property():
             delivered += len(got)
         n_sent = sum(len(v) for v in sent.values())
         assert int(jnp.sum(q.overflow)) == n_sent - delivered
+
+
+def _push_many_grid_ref(q, dst, valid, time, tie, kind, data, aux, deliver_lanes):
+    """The landing as it was until PR 27, kept here as the plain reference:
+    a dest-major [H, D] delivery grid filled by one row scatter (slot
+    dst * D + rank of the stable destination sort) and merged into the
+    queue rows lane by lane through push_self_lanes."""
+    m, h = dst.shape[0], q.num_hosts
+    d = min(deliver_lanes, m)
+    key1 = jnp.where(valid, dst, h).astype(jnp.int32)
+    pos = jnp.arange(m, dtype=jnp.int32)
+    key1_s, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
+    seg_start = jnp.concatenate([jnp.ones((1,), bool), key1_s[1:] != key1_s[:-1]])
+    rank = pos - jax.lax.cummax(jnp.where(seg_start, pos, -1))
+    fits = (key1_s < h) & (rank < d)
+    slot = jnp.where(fits, key1_s * d + rank, h * d)  # OOB -> dropped
+
+    def words(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    def long(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int64)
+
+    rows = jnp.concatenate(
+        [words(time), words(tie), kind[:, None], aux[:, None],
+         jnp.ones((m, 1), jnp.int32), data], axis=1)
+    g = (jnp.zeros((h * d, rows.shape[1]), jnp.int32).at[slot]
+         .set(rows[order], mode="drop").reshape(h, d, rows.shape[1]))
+    g_valid = g[:, :, 6] != 0
+    beyond_d = jnp.sum(valid, dtype=jnp.int32) - jnp.sum(g_valid, dtype=jnp.int32)
+    q2 = equeue.push_self_lanes(
+        q, valid=g_valid, time=long(g[:, :, 0:2]), tie=long(g[:, :, 2:4]),
+        kind=g[:, :, 4], data=g[:, :, 7:], aux=g[:, :, 5])
+    return q2.replace(overflow=q2.overflow.at[0].add(beyond_d))
+
+
+def _batch(rng, m, hosts, p_valid=0.8, dst=None, t0=0):
+    """One push batch of m entries: (dst, valid, time, tie, kind, data, aux)."""
+    if dst is None:
+        dst = rng.integers(0, hosts, m)
+    return (
+        jnp.asarray(dst, jnp.int32),
+        jnp.asarray(rng.random(m) < p_valid),
+        jnp.asarray(t0 + rng.integers(0, 50, m), jnp.int64),
+        jnp.asarray(rng.integers(0, 1 << 62, m), jnp.int64),
+        jnp.asarray(rng.integers(1, 5, m), jnp.int32),
+        jnp.asarray(rng.integers(0, 1 << 30, (m, PAYLOAD_LANES)), jnp.int32),
+        jnp.asarray(rng.integers(0, 1500, m), jnp.int32),
+    )
+
+
+def _pop_some(rng, q, rounds):
+    for _ in range(rounds):
+        _, q = equeue.pop_min(q, jnp.asarray(rng.random(q.num_hosts) < 0.6))
+    return q
+
+
+# name -> (H, Q, D, [batches as (M, kwargs of _batch)], pops between batches)
+_LANDING_CASES = {
+    "empty_batch": (4, 8, 8, [(0, {})], 0),
+    "all_invalid": (4, 8, 8, [(12, {"p_valid": 0.0})], 0),
+    "d_below_fan_in_row0_overflow": (4, 16, 2, [(24, {"p_valid": 1.0})], 0),
+    "room_below_fan_in_row_overflow": (3, 4, 4, [(9, {}), (9, {}), (9, {})], 0),
+    "tombstones_from_interleaved_pops": (5, 12, 12, [(20, {})] * 5, 3),
+    "tombstones_and_narrow_d": (5, 12, 3, [(20, {})] * 5, 2),
+    "m_below_h": (16, 8, 8, [(5, {}), (3, {"p_valid": 1.0})], 1),
+    "m_above_h_times_d": (3, 64, 2, [(20, {}), (20, {})], 1),
+    "one_destination_takes_everything": (
+        4, 8, 8, [(6, {"dst": [2] * 6}), (6, {"dst": [2] * 6, "p_valid": 1.0})], 1),
+    "d_one": (4, 8, 1, [(10, {}), (10, {})], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LANDING_CASES))
+def test_push_many_sorted_equals_grid_reference(case):
+    """The pull landing against the delivery-grid spelling it replaced:
+    every queue leaf, bit for bit — slot placement, the stale contents of
+    popped slots, count, per-row overflow, row-0 overflow beyond
+    deliver_lanes, head_time — after every push of the case."""
+    hosts, cap, d, batches, pops = _LANDING_CASES[case]
+    rng = np.random.default_rng(sorted(_LANDING_CASES).index(case))
+    q_new = q_ref = equeue.create(hosts, cap)
+    for i, (m, kw) in enumerate(batches):
+        ent = _batch(rng, m, hosts, t0=40 * i, **kw)
+        q_new = equeue.push_many_sorted(q_new, *ent, deliver_lanes=d)
+        q_ref = _push_many_grid_ref(q_ref, *ent, deliver_lanes=d)
+        for name in ("time", "tie", "kind", "data", "aux", "count", "overflow", "head_time"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(q_new, name)), np.asarray(getattr(q_ref, name)),
+                err_msg=f"{case}: leaf {name} after batch {i}")
+        q_new = q_ref = _pop_some(rng, q_new, pops)
+
+
+def test_push_many_at_time_max_counted_on_row_zero():
+    """A batched push at TIME_MAX never takes a slot: it is masked before
+    the sort and counted on overflow row 0 (push_many_segment's rule);
+    the other entries land as if it had not been sent."""
+    H, Q = 3, 4
+    dst, valid, time, tie, kind, data, aux = _batch(np.random.default_rng(5), 6, H, p_valid=1.0)
+    time = time.at[2].set(TIME_MAX)
+    q = equeue.push_many(equeue.create(H, Q), dst, valid, time, tie, kind, data, aux)
+    keep = np.arange(6) != 2
+    want = equeue.push_many(
+        equeue.create(H, Q), dst[keep], valid[keep], time[keep], tie[keep],
+        kind[keep], data[keep], aux[keep])
+    assert int(q.overflow[0]) == int(want.overflow[0]) + 1
+    q = q.replace(overflow=want.overflow)
+    for a, b in zip(jax.tree.leaves(q), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    free = np.asarray(q.time) == TIME_MAX
+    assert free.sum(axis=1).tolist() == (Q - np.asarray(q.count)).tolist()
 
 
 def test_push_at_time_max_rejected_loudly():

@@ -40,7 +40,7 @@ ROUNDS = 2
 CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
     "window", "drain", "drain/handle", "drain/handle/push_self", "exchange",
-    "exchange/land", "exchange/land/push_self", "probe",
+    "exchange/land", "probe",
 }
 EXPECTED = {
     "tgen-plain": EVERYWHERE,
@@ -94,6 +94,8 @@ def test_every_scope_names_operations_of_the_compiled_chunk(chunks, case):
     table = scopes.parse_hlo_text(chunks[case][2])
     found = {v[1] for v in table.values() if v[1]}
     assert EXPECTED[case] <= found, EXPECTED[case] - found
+    # the landing is a pull (one gather, one where pass): no lane merge under it
+    assert "exchange/land/push_self" not in found
     # nothing outside the one list, and outermost is the path's head
     for shape, inner, outer in table.values():
         if inner:

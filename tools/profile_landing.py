@@ -1,7 +1,8 @@
 #!/usr/bin/env python3
 """Where a front-door round goes on the device: the event handler, the
 round-boundary flush, and the pieces of the flush's landing
-(equeue.push_many_sorted: index sort, row gather, row scatter, lane merge).
+(equeue.push_many_sorted: index sort and runs, word gather into sorted
+order, the [H, queue] pull gather, the merging where pass).
 
 Three parts, each printing one JSON line per timing (median and min of
 `--reps` blocked calls, milliseconds, on whatever backend JAX has — a
@@ -12,8 +13,8 @@ time is a device time only when `backend` says tpu):
            then flush_outbox on the outbox those iterations filled;
   landing  push_many_sorted alone on a synthetic whole outbox
            (hosts x outbox entries, `--fill` of them valid, uniform
-           destinations) at deliver_lanes 48 and queue_capacity, its four
-           pieces at each width, and — with `--parent-equeue PATH`, a copy
+           destinations) at deliver_lanes 48 and queue_capacity (one
+           program since the landing became a pull), its four pieces, and — with `--parent-equeue PATH`, a copy
            of an older shadow_tpu/equeue.py — that file's push_many_sorted
            on the same inputs (results compared leaf for leaf);
   divide   int64 `//` by a constant and by a per-host divisor against
@@ -132,44 +133,57 @@ def main(argv=None) -> int:
             """push_many_sorted's own steps, re-spelled here so each can be
             timed alone; `whole` composes them and is checked against the
             real function below, so this copy cannot drift unnoticed."""
-            grid = h * d
 
             def sort(dst, valid):
                 key1 = jnp.where(valid, dst, h).astype(jnp.int32)
                 pos = jnp.arange(m, dtype=jnp.int32)
-                key1_s, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
-                seg = jnp.concatenate([jnp.ones((1,), bool), key1_s[1:] != key1_s[:-1]])
-                rank = pos - jax.lax.cummax(jnp.where(seg, pos, -1))
-                fits = (key1_s < h) & (rank < d)
-                return order, jnp.where(fits, key1_s * d + rank, grid)
+                _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
+                hot_a = (key1 >> 7)[:, None] == jnp.arange(-(-h // 128), dtype=jnp.int32)
+                hot_b = (key1 & 127)[:, None] == jnp.arange(128, dtype=jnp.int32)
+                cnt = jnp.dot(hot_a.T.astype(jnp.int8), hot_b.astype(jnp.int8),
+                              preferred_element_type=jnp.int32).reshape(-1)[:h]
+                return order, jnp.cumsum(cnt, dtype=jnp.int32) - cnt, cnt
 
             def pack(tm, tie, kind, data, aux):
-                w2 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int32)  # noqa: E731
+                lo = lambda x: x.astype(jnp.int32)  # noqa: E731
+                hi = lambda x: (x >> 32).astype(jnp.int32)  # noqa: E731
                 return jnp.concatenate(
-                    [w2(tm), w2(tie), kind[:, None], aux[:, None],
-                     jnp.ones((m, 1), jnp.int32), data], axis=1)
+                    [jnp.stack([lo(tm), hi(tm), lo(tie), hi(tie), kind, aux]), data.T])
 
-            def gather(rows, order):
-                return rows[order]
+            def gather(words, order):
+                return words[:, order]
 
-            def scatter(slot, rows_s):
-                return jnp.zeros((grid, rows_s.shape[1]), jnp.int32).at[slot].set(
-                    rows_s, mode="drop")
+            def pull(q, begin, cnt, words_s):
+                free = q.time == equeue.TIME_MAX
+                fr = (jnp.cumsum(free, axis=1) - free).astype(jnp.int32)
+                land = jnp.minimum(jnp.minimum(cnt, d), qcap - q.count)
+                take = free & (fr < land[:, None])
+                return take, land, words_s[:, jnp.minimum(begin[:, None] + fr, m - 1)]
 
-            def merge(q, g):
-                g = g.reshape(h, d, g.shape[1])
-                l64 = lambda x: jax.lax.bitcast_convert_type(x, jnp.int64)  # noqa: E731
-                return equeue.push_self_lanes(
-                    q, valid=g[:, :, 6] != 0, time=l64(g[:, :, 0:2]), tie=l64(g[:, :, 2:4]),
-                    kind=g[:, :, 4], data=g[:, :, 7:], aux=g[:, :, 5])
+            def merge(q, take, land, cnt, g):
+                def l64(low, high):
+                    low = jax.lax.bitcast_convert_type(low, jnp.uint32)
+                    return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
 
-            return sort, pack, gather, scatter, merge
+                gt = l64(g[0], g[1])
+                return q.replace(
+                    time=jnp.where(take, gt, q.time),
+                    tie=jnp.where(take, l64(g[2], g[3]), q.tie),
+                    kind=jnp.where(take, g[4], q.kind),
+                    data=jnp.where(take[:, :, None], jnp.moveaxis(g[6:], 0, -1), q.data),
+                    aux=jnp.where(take, g[5], q.aux),
+                    count=q.count + land,
+                    overflow=q.overflow + (jnp.minimum(cnt, d) - land),
+                    head_time=jnp.minimum(q.head_time, jnp.min(
+                        jnp.where(take, gt, equeue.TIME_MAX), axis=1)))
+
+            return sort, pack, gather, pull, merge
 
         def jitted(d):
             """One set of compiled functions per grid width, shared by
             every fill level (a compile here is minutes on the chip)."""
             fns = {k: jax.jit(f) for k, f in zip(
-                ("sort", "pack", "gather", "scatter", "merge"), pieces(d))}
+                ("sort", "pack", "gather", "pull", "merge"), pieces(d))}
             fns["new"] = jax.jit(
                 lambda q, *a: equeue.push_many_sorted(q, *a, deliver_lanes=d))
             if parent is not None and d == 48:
@@ -192,16 +206,16 @@ def main(argv=None) -> int:
             for d, fn in widths.items():
                 f = dict(entries=m, valid=int(np.asarray(valid).sum()), deliver_lanes=d)
                 new = timed("landing_new", fn["new"], q0, *ent, **f)
-                order, slot = timed("landing_new.sort", fn["sort"], dst, valid, **f)
+                order, begin, cnt = timed("landing_new.sort", fn["sort"], dst, valid, **f)
                 rows = fn["pack"](tm, tie, kind, data, aux)
                 rows_s = timed("landing_new.gather", fn["gather"], rows, order, **f)
-                g = timed("landing_new.scatter", fn["scatter"], slot, rows_s, **f)
-                whole = timed("landing_new.merge", fn["merge"], q0, g, **f)
+                take, land, g = timed("landing_new.pull", fn["pull"], q0, begin, cnt, rows_s, **f)
+                whole = timed("landing_new.merge", fn["merge"], q0, take, land, cnt, g, **f)
                 emit(name="landing_new.pieces_equal_whole", **f, ok=all(
                     bool(jnp.array_equal(a, b)) for a, b in zip(
                         jax.tree.leaves(whole.replace(overflow=new.overflow)),
                         jax.tree.leaves(new))))
-                del g, rows, rows_s, whole
+                del g, take, rows, rows_s, whole
                 if "parent" in fn:
                     for_parent.append((f, ent, new))
                 del new
