@@ -1,17 +1,22 @@
 #!/usr/bin/env python3
 """Quickest proof that the system still starts on the chip.
 
-Drives the front door (`python -m shadow_tpu.cli run`) on the tgen-10k
-deployment (examples/tgen-10k/shadow.yaml: 10,240 hosts, 32-node lossy
-graph, 100 Mbit hosts, half the hosts tgen clients, 500 ms) and checks
-what comes out by the repo's own means. This process never imports JAX:
+Drives the front door (`python -m shadow_tpu.cli run`) on a deployment's
+own document and checks what comes out by the repo's own means.
+`--deployment tgen-10k` (the default; examples/tgen-10k/shadow.yaml:
+10,240 hosts, 32-node lossy graph, 100 Mbit hosts, half the hosts tgen
+clients, 500 ms) or `fattree-10k` (benchmarks/configs/fattree-10k.json:
+10,240 hosts on a k=16 fat-tree, 5 us lookahead, 5,120 saturating TCP
+flows at 1 Gbit, 5 ms). The full-size phases run the document's own host
+count and stop time. This process never imports JAX:
 one process at a time owns the chip, so every run is a child that exits
 before the next starts, and the device facts come back through the
 `device` block the run writes into sim-stats.json.
 
 Phases, each failing the script on its own:
 
-  parity  the same YAML cut to 256 hosts / 100 ms, once on the device
+  parity  the same document cut to 256 hosts and a shorter stop time
+          (100 ms; the fat-tree's 3 ms), once on the device
           and once on the independent scalar oracle
           (experimental.scheduler: cpu-ref, that child alone is given
           JAX_PLATFORMS=cpu): every per-host counter equal.
@@ -42,7 +47,11 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
-CONFIG = os.path.join(ROOT, "examples", "tgen-10k", "shadow.yaml")
+# deployment -> (its document, parity stop time, rehearsal hosts, rehearsal stop time)
+DEPLOYMENTS = {
+    "tgen-10k": (os.path.join("examples", "tgen-10k", "shadow.yaml"), "100 ms", 64, "50 ms"),
+    "fattree-10k": (os.path.join("benchmarks", "configs", "fattree-10k.json"), "3 ms", 128, "3 ms"),
+}
 # what `engine: auto` means for this config (pump_k unset) on every
 # backend — engine/round.py effective_engine
 AUTO_ENGINE = "plain"
@@ -75,21 +84,28 @@ def probe_device() -> dict:
     return json.loads(r.stdout.strip().splitlines()[-1])
 
 
-def cut_config(out_dir: str, name: str, hosts: int, stop_time: str,
-               parallelism: int = 1, scheduler: str = "tpu") -> str:
-    """The deployment's YAML with its host groups cut evenly to `hosts`,
-    its stop time, device count and scheduler set; returns the path
-    written. The world itself is never rebuilt here."""
+def document(deployment: str) -> str:
+    return os.path.join(ROOT, DEPLOYMENTS[deployment][0])
+
+
+def cut_config(deployment: str, out_dir: str, name: str, hosts: "int | None",
+               stop_time: "str | None", parallelism: int = 1, scheduler: str = "tpu") -> str:
+    """The deployment's document with its host groups cut evenly to
+    `hosts` and its stop time set (None: the document's own), device count
+    and scheduler set; returns the path written. The world itself is never
+    rebuilt here."""
     import yaml
 
-    with open(CONFIG) as f:
-        cfg = yaml.safe_load(f)
-    per, rem = divmod(hosts, len(cfg["hosts"]))
-    if rem or per < 1:
-        raise Failed(f"{hosts} hosts do not divide over {len(cfg['hosts'])} groups")
-    for spec in cfg["hosts"].values():
-        spec["quantity"] = per
-    cfg["general"]["stop_time"] = stop_time
+    with open(document(deployment)) as f:
+        cfg = yaml.safe_load(f)  # JSON is YAML
+    if hosts is not None:
+        per, rem = divmod(hosts, len(cfg["hosts"]))
+        if rem or per < 1:
+            raise Failed(f"{hosts} hosts do not divide over {len(cfg['hosts'])} groups")
+        for spec in cfg["hosts"].values():
+            spec["quantity"] = per
+    if stop_time is not None:
+        cfg["general"]["stop_time"] = stop_time
     cfg["general"]["data_directory"] = os.path.join(out_dir, name + ".data")
     cfg["general"]["parallelism"] = parallelism
     cfg["experimental"]["scheduler"] = scheduler
@@ -178,14 +194,21 @@ def cache_entries() -> "tuple[str, set[str]]":
         return path, set()
 
 
-def one_chip(out_dir: str, device: dict, rehearse: bool) -> None:
-    small, full = (64, 64) if rehearse else (256, 10240)
-    small_stop, full_stop = ("50 ms", "50 ms") if rehearse else ("100 ms", "500 ms")
+def sizes(deployment: str, rehearse: bool):
+    """(parity hosts, full hosts, parity stop, full stop); None = the document's own."""
+    _doc, parity_stop, rehearse_hosts, rehearse_stop = DEPLOYMENTS[deployment]
+    if rehearse:
+        return rehearse_hosts, rehearse_hosts, rehearse_stop, rehearse_stop
+    return 256, None, parity_stop, None
+
+
+def one_chip(out_dir: str, device: dict, rehearse: bool, deployment: str) -> None:
+    small, full, small_stop, full_stop = sizes(deployment, rehearse)
     cpu_env = dict(os.environ, JAX_PLATFORMS="cpu")
 
     # (i) parity at a small size against the scalar oracle
-    dev_cfg = cut_config(out_dir, "parity-device", small, small_stop)
-    ref_cfg = cut_config(out_dir, "parity-oracle", small, small_stop, scheduler="cpu-ref")
+    dev_cfg = cut_config(deployment, out_dir, "parity-device", small, small_stop)
+    ref_cfg = cut_config(deployment, out_dir, "parity-oracle", small, small_stop, scheduler="cpu-ref")
     on_dev = run_child(out_dir, "parity-device", dev_cfg)
     check_device_run("parity-device", on_dev, device, rehearse)
     oracle = run_child(out_dir, "parity-oracle", ref_cfg, env=cpu_env)
@@ -194,7 +217,7 @@ def one_chip(out_dir: str, device: dict, rehearse: bool) -> None:
     same_counters("parity-device", on_dev, "parity-oracle", oracle)
 
     # (ii) cold run at full size, (iii) the same command again
-    cfg = cut_config(out_dir, "full", full, full_stop)
+    cfg = cut_config(deployment, out_dir, "full", full, full_stop)
     cache_dir, before = cache_entries()
     cold = run_child(out_dir, "full", cfg)
     check_device_run("cold", cold, device, rehearse)
@@ -216,12 +239,12 @@ def one_chip(out_dir: str, device: dict, rehearse: bool) -> None:
         raise Failed(f"warm run added {len(added)} compile-cache entries: {sorted(added)[:5]}")
 
 
-def four_chips(out_dir: str, device: dict, rehearse: bool) -> None:
-    hosts, stop = (64, "50 ms") if rehearse else (10240, "500 ms")
+def four_chips(out_dir: str, device: dict, rehearse: bool, deployment: str) -> None:
+    _small, hosts, _small_stop, stop = sizes(deployment, rehearse)
     runs = {}
     for n in (4, 1):
         name = f"chips{n}"
-        cfg = cut_config(out_dir, name, hosts, stop, parallelism=n)
+        cfg = cut_config(deployment, out_dir, name, hosts, stop, parallelism=n)
         runs[n] = run_child(out_dir, name, cfg)
         check_device_run(name, runs[n], device, rehearse)
     ids = runs[4]["device"]["ids"]
@@ -235,14 +258,16 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="small size on whatever platform JAX has")
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--deployment", choices=sorted(DEPLOYMENTS), default="tgen-10k")
     ap.add_argument("--out", default=os.path.join(ROOT, "chip_smoke_out"),
                     help="directory for configs, logs and run data")
     args = ap.parse_args(argv)
 
+    config = document(args.deployment)
     device, ok = None, False
     try:
-        if not os.path.exists(CONFIG) or not os.path.isdir(os.path.join(ROOT, "shadow_tpu")):
-            raise Failed(f"{CONFIG} or the shadow_tpu package is missing beside this script")
+        if not os.path.exists(config) or not os.path.isdir(os.path.join(ROOT, "shadow_tpu")):
+            raise Failed(f"{config} or the shadow_tpu package is missing beside this script")
         device = probe_device()
         say(f"device: {json.dumps(device)}")
         if device["platform"] != "tpu" and not args.rehearse:
@@ -253,7 +278,8 @@ def main(argv=None) -> int:
         out_dir = os.path.abspath(args.out)
         shutil.rmtree(out_dir, ignore_errors=True)
         os.makedirs(out_dir)
-        (four_chips if args.chips == 4 else one_chip)(out_dir, device, args.rehearse)
+        (four_chips if args.chips == 4 else one_chip)(
+            out_dir, device, args.rehearse, args.deployment)
         ok = True
     except Failed as e:
         say(f"FAILED: {e}")

@@ -136,45 +136,46 @@ def handle_one_iteration(
     ready = ev.time
     size_in = jnp.zeros_like(ev.time)
     if cfg.use_netstack:
-        # --- ingress: down-bw relay + CoDel at the upstream router -------
-        # (relay/mod.rs:110-230 + router/mod.rs:59-115, reformulated as a
-        # closed-form deferred re-enqueue; see netstack.py).
-        is_pkt = ev.valid & (ev.kind == KIND_PACKET)
-        size_in = (ev.aux & AUX_SIZE_MASK).astype(jnp.int64)
-        shaped = (ev.aux & AUX_SHAPED_BIT) != 0
-        loopback = ev.src_host == host_ids
-        in_bootstrap = ev.time < cfg.bootstrap_end_ns
+        with jax.named_scope(scopes.NETSTACK):
+            # --- ingress: down-bw relay + CoDel at the upstream router -------
+            # (relay/mod.rs:110-230 + router/mod.rs:59-115, reformulated as a
+            # closed-form deferred re-enqueue; see netstack.py).
+            is_pkt = ev.valid & (ev.kind == KIND_PACKET)
+            size_in = (ev.aux & AUX_SIZE_MASK).astype(jnp.int64)
+            shaped = (ev.aux & AUX_SHAPED_BIT) != 0
+            loopback = ev.src_host == host_ids
+            in_bootstrap = ev.time < cfg.bootstrap_end_ns
 
-        # a shaped event is the deferred dequeue completing: drain backlog
-        finish = is_pkt & shaped
-        net = net.replace(
-            rx_backlog_bytes=net.rx_backlog_bytes - jnp.where(finish, size_in, 0)
-        )
+            # a shaped event is the deferred dequeue completing: drain backlog
+            finish = is_pkt & shaped
+            net = net.replace(
+                rx_backlog_bytes=net.rx_backlog_bytes - jnp.where(finish, size_in, 0)
+            )
 
-        need = is_pkt & ~shaped & ~loopback & ~in_bootstrap & (net.rx_refill > 0)
-        ready, rx_tok, rx_last = netstack.tb_depart(
-            net.rx_tokens, net.rx_last, net.rx_refill, ev.time, size_in, need
-        )
-        sojourn = ready - ev.time
-        codel_drop, net = netstack.codel_dequeue(net, ready, sojourn, need)
-        keep_in = need & ~codel_drop
-        # tokens are only consumed by packets that actually pass the relay
-        net = net.replace(
-            rx_tokens=jnp.where(keep_in, rx_tok, net.rx_tokens),
-            rx_last=jnp.where(keep_in, rx_last, net.rx_last),
-            codel_dropped=net.codel_dropped + codel_drop,
-        )
-        defer = keep_in & (ready > ev.time)
-        net = net.replace(
-            rx_backlog_bytes=net.rx_backlog_bytes + jnp.where(defer, size_in, 0)
-        )
-        if hasattr(model, "on_codel_drop"):
-            st = st.replace(model=model.on_codel_drop(st.model, ev, codel_drop))
-        ev = ev.replace(valid=ev.valid & ~(defer | codel_drop))
-        net = net.replace(
-            bytes_recv=net.bytes_recv
-            + jnp.where(ev.valid & is_pkt, size_in, 0)
-        )
+            need = is_pkt & ~shaped & ~loopback & ~in_bootstrap & (net.rx_refill > 0)
+            ready, rx_tok, rx_last = netstack.tb_depart(
+                net.rx_tokens, net.rx_last, net.rx_refill, ev.time, size_in, need
+            )
+            sojourn = ready - ev.time
+            codel_drop, net = netstack.codel_dequeue(net, ready, sojourn, need)
+            keep_in = need & ~codel_drop
+            # tokens are only consumed by packets that actually pass the relay
+            net = net.replace(
+                rx_tokens=jnp.where(keep_in, rx_tok, net.rx_tokens),
+                rx_last=jnp.where(keep_in, rx_last, net.rx_last),
+                codel_dropped=net.codel_dropped + codel_drop,
+            )
+            defer = keep_in & (ready > ev.time)
+            net = net.replace(
+                rx_backlog_bytes=net.rx_backlog_bytes + jnp.where(defer, size_in, 0)
+            )
+            if hasattr(model, "on_codel_drop"):
+                st = st.replace(model=model.on_codel_drop(st.model, ev, codel_drop))
+            ev = ev.replace(valid=ev.valid & ~(defer | codel_drop))
+            net = net.replace(
+                bytes_recv=net.bytes_recv
+                + jnp.where(ev.valid & is_pkt, size_in, 0)
+            )
 
     draw = Draw(st.rng_key, st.rng_counter)
     model_before = st.model  # pre-handler snapshot (tracker retrans delta)
@@ -214,29 +215,30 @@ def handle_one_iteration(
     dropped = pvalid & ~unroutable & ~(loss_u < rel)
 
     if cfg.use_netstack:
-        # --- egress: up-bw relay charged in lane order at emit time ------
-        # (the loss draw happens downstream of the relay in the reference,
-        # worker.rs:361-378, so loss-dropped packets still consume tokens;
-        # loopback and bootstrap-period packets are exempt,
-        # relay/mod.rs:144-230.)
-        sizes = pemits.size.astype(jnp.int64)
-        in_bootstrap_tx = ev.time < cfg.bootstrap_end_ns
-        tx_tok, tx_last = net.tx_tokens, net.tx_last
-        deps = []
-        for p in range(ep):
-            loopb = dst_clamped[:, p] == host_ids
-            charge = (pvalid[:, p] & ~unroutable[:, p]) & ~loopb & ~in_bootstrap_tx
-            dep_p, tx_tok, tx_last = netstack.tb_depart(
-                tx_tok, tx_last, net.tx_refill, ev.time, sizes[:, p], charge
+        with jax.named_scope(scopes.NETSTACK):
+            # --- egress: up-bw relay charged in lane order at emit time ------
+            # (the loss draw happens downstream of the relay in the reference,
+            # worker.rs:361-378, so loss-dropped packets still consume tokens;
+            # loopback and bootstrap-period packets are exempt,
+            # relay/mod.rs:144-230.)
+            sizes = pemits.size.astype(jnp.int64)
+            in_bootstrap_tx = ev.time < cfg.bootstrap_end_ns
+            tx_tok, tx_last = net.tx_tokens, net.tx_last
+            deps = []
+            for p in range(ep):
+                loopb = dst_clamped[:, p] == host_ids
+                charge = (pvalid[:, p] & ~unroutable[:, p]) & ~loopb & ~in_bootstrap_tx
+                dep_p, tx_tok, tx_last = netstack.tb_depart(
+                    tx_tok, tx_last, net.tx_refill, ev.time, sizes[:, p], charge
+                )
+                deps.append(dep_p)
+            dep = jnp.stack(deps, axis=1)  # [H, EP]
+            net = net.replace(
+                tx_tokens=tx_tok,
+                tx_last=tx_last,
+                bytes_sent=net.bytes_sent + jnp.sum(jnp.where(kept, sizes, 0), axis=1),
             )
-            deps.append(dep_p)
-        dep = jnp.stack(deps, axis=1)  # [H, EP]
-        net = net.replace(
-            tx_tokens=tx_tok,
-            tx_last=tx_last,
-            bytes_sent=net.bytes_sent + jnp.sum(jnp.where(kept, sizes, 0), axis=1),
-        )
-        deliver = jnp.maximum(dep + lat, window_end)  # [H, EP]
+            deliver = jnp.maximum(dep + lat, window_end)  # [H, EP]
     else:
         deliver = jnp.maximum(ev.time[:, None] + lat, window_end)  # [H, EP]
 
@@ -278,27 +280,28 @@ def handle_one_iteration(
         )
 
     # --- stage surviving packets into own outbox rows ---
-    ob = st.outbox
-    o_cap = ob.valid.shape[1]
-    lane_idx = jnp.arange(o_cap)[None, :]
-    fill, overflow = ob.fill, ob.overflow
-    obv, obd, obt, obtie, obdata = ob.valid, ob.dst, ob.time, ob.tie, ob.data
-    obaux = ob.aux
-    pkt_kind = jnp.full(host_ids.shape, KIND_PACKET, jnp.int32)
-    for p in range(ep):
-        has_room = fill < o_cap
-        write = kept[:, p] & has_room
-        at = (lane_idx == fill[:, None]) & write[:, None]
-        tie = pack_tie(pkt_kind, host_ids, pseq[:, p])
-        obv = obv | at
-        obd = jnp.where(at, dst_clamped[:, p][:, None], obd)
-        obt = jnp.where(at, deliver[:, p][:, None], obt)
-        obtie = jnp.where(at, tie[:, None], obtie)
-        obdata = jnp.where(at[:, :, None], pemits.data[:, p, None, :], obdata)
-        obaux = jnp.where(at, (pemits.size[:, p] & AUX_SIZE_MASK)[:, None], obaux)
-        fill = fill + write.astype(jnp.int32)
-        overflow = overflow + (kept[:, p] & ~has_room).astype(jnp.int32)
-    ob = ob.replace(valid=obv, dst=obd, time=obt, tie=obtie, data=obdata, aux=obaux, fill=fill, overflow=overflow)
+    with jax.named_scope(scopes.STAGE):
+        ob = st.outbox
+        o_cap = ob.valid.shape[1]
+        lane_idx = jnp.arange(o_cap)[None, :]
+        fill, overflow = ob.fill, ob.overflow
+        obv, obd, obt, obtie, obdata = ob.valid, ob.dst, ob.time, ob.tie, ob.data
+        obaux = ob.aux
+        pkt_kind = jnp.full(host_ids.shape, KIND_PACKET, jnp.int32)
+        for p in range(ep):
+            has_room = fill < o_cap
+            write = kept[:, p] & has_room
+            at = (lane_idx == fill[:, None]) & write[:, None]
+            tie = pack_tie(pkt_kind, host_ids, pseq[:, p])
+            obv = obv | at
+            obd = jnp.where(at, dst_clamped[:, p][:, None], obd)
+            obt = jnp.where(at, deliver[:, p][:, None], obt)
+            obtie = jnp.where(at, tie[:, None], obtie)
+            obdata = jnp.where(at[:, :, None], pemits.data[:, p, None, :], obdata)
+            obaux = jnp.where(at, (pemits.size[:, p] & AUX_SIZE_MASK)[:, None], obaux)
+            fill = fill + write.astype(jnp.int32)
+            overflow = overflow + (kept[:, p] & ~has_room).astype(jnp.int32)
+        ob = ob.replace(valid=obv, dst=obd, time=obt, tie=obtie, data=obdata, aux=obaux, fill=fill, overflow=overflow)
 
     min_used = st.min_used_lat
     if cfg.use_dynamic_runahead:

@@ -25,6 +25,14 @@ Every name is a single path component; nested scopes give paths:
                             compaction gather / scatter
     drain/handle            handle_one_iteration
     drain/pump              pump_stage / megakernel_stage
+    drain/handle/netstack   netstack.py as the handler calls it: the down
+                            relay's token bucket and CoDel at ingress,
+                            the up relay's token buckets at emit time
+    drain/handle/tcp        transport/tcp.py: tcp_handle on the fused slot
+                            view and commit_slot's one scatter
+    drain/handle/stage      the handler's staging of surviving packets
+                            into the host's own outbox row: one select
+                            over [H, outbox, ...] per packet lane
     drain/handle/push_self  equeue.push_self_lanes: the [H, queue] lane
     drain/pump/push_self    merges of the handler and the pump
     exchange                flush_outbox: flatten, bucket, clear
@@ -41,6 +49,7 @@ Every name is a single path component; nested scopes give paths:
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import re
 
@@ -48,6 +57,9 @@ WINDOW = "window"
 DRAIN = "drain"
 HANDLE = "handle"
 PUMP = "pump"
+NETSTACK = "netstack"
+TCP = "tcp"
+STAGE = "stage"
 PUSH_SELF = "push_self"
 EXCHANGE = "exchange"
 COLLECTIVE = "collective"
@@ -60,6 +72,9 @@ SCOPES = {
     DRAIN: "drain",
     HANDLE: "drain",
     PUMP: "drain",
+    NETSTACK: "drain",
+    TCP: "drain",
+    STAGE: "drain",
     PUSH_SELF: "kernels",
     EXCHANGE: "exchange",
     COLLECTIVE: "exchange",
@@ -81,6 +96,20 @@ def keyed(fn):
     """`fn`, renamed `<name>_<KEY>` for the compile cache's sake."""
     fn.__name__ = f"{fn.__name__}_{KEY}"
     return fn
+
+
+def scoped(name: str):
+    """Decorator: the function's operations lie under scope `name`. The
+    scope is entered at each call, through `jax.named_scope` as it is then."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            import jax
+
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return wrap
 
 
 # The chunk executable of the newest driver entry (engine/round.py

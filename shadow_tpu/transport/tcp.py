@@ -62,6 +62,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
+from shadow_tpu import scopes
 from shadow_tpu.equeue import PAYLOAD_LANES
 from shadow_tpu.events import KIND_MODEL_BASE
 from shadow_tpu.simtime import NS_PER_MS, NS_PER_SEC, TIME_MAX
@@ -460,6 +461,7 @@ def view_close(v: TcpState, mask) -> TcpState:
     return v.replace(fin_pending=jnp.where(m, True, v.fin_pending))
 
 
+@scopes.scoped(scopes.TCP)
 def commit_slot(ts: TcpState, slot, touched, view: TcpState) -> TcpState:
     """Write the fused view back — the ONE scatter of the whole event."""
     return scatter_slot(ts, slot, touched, view)
@@ -530,6 +532,7 @@ def _mk_seg(lport, rport, seq, ack, flags, plen, wnd, sack_s=None, sack_e=None):
 # --- the unified handler --------------------------------------------------
 
 
+@scopes.scoped(scopes.TCP)
 def tcp_handle(
     ts: TcpState,
     ev,

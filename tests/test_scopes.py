@@ -39,16 +39,18 @@ END = jnp.asarray(30_000_000, jnp.int64)
 ROUNDS = 2
 CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
-    "window", "drain", "drain/handle", "drain/handle/push_self", "exchange",
-    "exchange/land", "probe",
+    "window", "drain", "drain/handle", "drain/handle/push_self",
+    "drain/handle/stage", "exchange", "exchange/land", "probe",
 }
+# the tgen world shapes its hosts and speaks TCP; phold's does neither
+TGEN = EVERYWHERE | {"drain/handle/netstack", "drain/handle/tcp"}
 EXPECTED = {
-    "tgen-plain": EVERYWHERE,
-    "tgen-pump": EVERYWHERE | {"drain/pump", "drain/pump/push_self"},
+    "tgen-plain": TGEN,
+    "tgen-pump": TGEN | {"drain/pump", "drain/pump/push_self"},
     # phold publishes no pump_spec: every engine value takes the handler
     "phold-plain": EVERYWHERE,
     "phold-pump": EVERYWHERE,
-    "tgen-sharded": EVERYWHERE | {"exchange/collective"},
+    "tgen-sharded": TGEN | {"exchange/collective"},
 }
 
 
