@@ -1026,11 +1026,9 @@ def _event_slot_bytes(ob) -> int:
     import numpy as np
 
     total = 0
+    slots = ob.valid.size
     for a in (ob.valid, ob.dst, ob.time, ob.tie, ob.aux, ob.data):
-        per_slot = a.dtype.itemsize
-        for d in a.shape[2:]:
-            per_slot *= d
-        total += per_slot
+        total += a.dtype.itemsize * (a.size // slots)
     return int(np.asarray(total))
 
 

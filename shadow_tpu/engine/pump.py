@@ -149,7 +149,7 @@ class PumpCarry:
     obd: jax.Array
     obt: jax.Array
     obtie: jax.Array
-    obdata: jax.Array
+    obdata: jax.Array  # [H, PAYLOAD_LANES, O], as Outbox.data
     obaux: jax.Array
     obfill: jax.Array
     obover: jax.Array
@@ -812,7 +812,7 @@ def pump_microstep(
         obd = jnp.where(at, dst[:, None], obd)
         obt = jnp.where(at, deliver_l[:, lane][:, None], obt)
         obtie = jnp.where(at, ptie[:, None], obtie)
-        obdata = jnp.where(at[:, :, None], l_data2[lane][:, None, :], obdata)
+        obdata = jnp.where(at[:, None, :], l_data2[lane][:, :, None], obdata)
         obaux = jnp.where(
             at, (lsz_all[:, lane].astype(jnp.int32) & AUX_SIZE_MASK)[:, None],
             obaux,

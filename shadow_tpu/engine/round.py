@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from shadow_tpu import equeue, netstack, rng, scopes
-from shadow_tpu.engine.state import EngineConfig, SimState, trace_static_cfg
+from shadow_tpu.engine.state import EngineConfig, Outbox, SimState, trace_static_cfg
 from shadow_tpu.events import KIND_PACKET, pack_tie
 from shadow_tpu.graph.routing import RoutingTables
 from shadow_tpu.netstack import AUX_SHAPED_BIT, AUX_SIZE_MASK
@@ -109,6 +109,43 @@ def bootstrap(st: SimState, model, cfg: EngineConfig) -> SimState:
         queue=queue,
         seq=seq_final,
         rng_counter=st.rng_counter + jnp.uint32(model.BOOTSTRAP_DRAWS),
+    )
+
+
+def stage_packets(
+    ob: Outbox,
+    kept: jax.Array,  # [H, EP] bool
+    dst: jax.Array,  # [H, EP] i32
+    deliver: jax.Array,  # [H, EP] i64
+    tie: jax.Array,  # [H, EP] i64
+    data: jax.Array,  # [H, EP, PAYLOAD_LANES] i32
+    size: jax.Array,  # [H, EP] i32
+) -> Outbox:
+    """Append each host's kept packet lanes, in lane order, to its own
+    outbox row: lane p lands in slot fill[h], or counts on overflow[h]
+    when the row is full. One fused select chain per array over the
+    [H, O] grid; the payload's is over [H, 8, O], words on the sublanes
+    and slots on the lanes (engine/state.py Outbox)."""
+    o_cap = ob.valid.shape[1]
+    lane_idx = jnp.arange(o_cap)[None, :]
+    fill, overflow = ob.fill, ob.overflow
+    obv, obd, obt, obtie, obdata = ob.valid, ob.dst, ob.time, ob.tie, ob.data
+    obaux = ob.aux
+    for p in range(kept.shape[1]):
+        has_room = fill < o_cap
+        write = kept[:, p] & has_room
+        at = (lane_idx == fill[:, None]) & write[:, None]
+        obv = obv | at
+        obd = jnp.where(at, dst[:, p][:, None], obd)
+        obt = jnp.where(at, deliver[:, p][:, None], obt)
+        obtie = jnp.where(at, tie[:, p][:, None], obtie)
+        obdata = jnp.where(at[:, None, :], data[:, p, :, None], obdata)
+        obaux = jnp.where(at, (size[:, p] & AUX_SIZE_MASK)[:, None], obaux)
+        fill = fill + write.astype(jnp.int32)
+        overflow = overflow + (kept[:, p] & ~has_room).astype(jnp.int32)
+    return ob.replace(
+        valid=obv, dst=obd, time=obt, tie=obtie, data=obdata, aux=obaux,
+        fill=fill, overflow=overflow,
     )
 
 
@@ -281,27 +318,11 @@ def handle_one_iteration(
 
     # --- stage surviving packets into own outbox rows ---
     with jax.named_scope(scopes.STAGE):
-        ob = st.outbox
-        o_cap = ob.valid.shape[1]
-        lane_idx = jnp.arange(o_cap)[None, :]
-        fill, overflow = ob.fill, ob.overflow
-        obv, obd, obt, obtie, obdata = ob.valid, ob.dst, ob.time, ob.tie, ob.data
-        obaux = ob.aux
-        pkt_kind = jnp.full(host_ids.shape, KIND_PACKET, jnp.int32)
-        for p in range(ep):
-            has_room = fill < o_cap
-            write = kept[:, p] & has_room
-            at = (lane_idx == fill[:, None]) & write[:, None]
-            tie = pack_tie(pkt_kind, host_ids, pseq[:, p])
-            obv = obv | at
-            obd = jnp.where(at, dst_clamped[:, p][:, None], obd)
-            obt = jnp.where(at, deliver[:, p][:, None], obt)
-            obtie = jnp.where(at, tie[:, None], obtie)
-            obdata = jnp.where(at[:, :, None], pemits.data[:, p, None, :], obdata)
-            obaux = jnp.where(at, (pemits.size[:, p] & AUX_SIZE_MASK)[:, None], obaux)
-            fill = fill + write.astype(jnp.int32)
-            overflow = overflow + (kept[:, p] & ~has_room).astype(jnp.int32)
-        ob = ob.replace(valid=obv, dst=obd, time=obt, tie=obtie, data=obdata, aux=obaux, fill=fill, overflow=overflow)
+        pkt_kind = jnp.full(kept.shape, KIND_PACKET, jnp.int32)
+        ptie = pack_tie(pkt_kind, jnp.broadcast_to(host_ids[:, None], kept.shape), pseq)
+        ob = stage_packets(
+            st.outbox, kept, dst_clamped, deliver, ptie, pemits.data, pemits.size
+        )
 
     min_used = st.min_used_lat
     if cfg.use_dynamic_runahead:
@@ -529,6 +550,13 @@ def flush_outbox(
         return jax.lax.cond(has_traffic, _do_flush, _skip, st)
 
 
+def _payload_words(ob: Outbox) -> jax.Array:
+    """The outbox payload [H, 8, O] as [8, M] words, slot (h, o) in column
+    h * O + o: the flat order of the other outbox arrays."""
+    lanes = ob.data.shape[1]
+    return jnp.moveaxis(ob.data, 1, 0).reshape(lanes, -1)
+
+
 def _flush_outbox_traffic(
     st: SimState, axis_name: Optional[str], cfg: "EngineConfig | None" = None
 ) -> SimState:
@@ -539,10 +567,12 @@ def _flush_outbox_traffic(
     m = h_local * o_cap
 
     def flat(x):
-        return x.reshape((m,) + x.shape[2:])
+        return x.reshape(m)
 
     valid, dst, time, tie = flat(ob.valid), flat(ob.dst), flat(ob.time), flat(ob.tie)
-    data, aux = flat(ob.data), flat(ob.aux)
+    # the landing reads the payload word-major (push_many_sorted's data.T
+    # folds with this one); the sharded buckets read it as [M, 8] rows
+    data, aux = _payload_words(ob).T, flat(ob.aux)
     overflow_extra = None
 
     base = 0
@@ -693,10 +723,10 @@ def _flush_segment(
     m = h_local * o_cap
 
     def flat(x):
-        return x.reshape((m,) + x.shape[2:])
+        return x.reshape(m)
 
     valid, dst, time, tie = flat(ob.valid), flat(ob.dst), flat(ob.time), flat(ob.tie)
-    data, aux = flat(ob.data), flat(ob.aux)
+    words, aux = _payload_words(ob), flat(ob.aux)
 
     # 1. pool compaction: valids first, grouped by destination, time-
     # sorted within each destination segment
@@ -704,7 +734,7 @@ def _flush_segment(
     key = jnp.where(valid, dst, big)
     _, time_p, tie_p, aux_p, valid_p, dst_p, *data_cols = jax.lax.sort(
         (key, time, tie, aux, valid, dst)
-        + tuple(data[:, i] for i in range(data.shape[1])),
+        + tuple(words),
         num_keys=3,
         is_stable=True,
     )

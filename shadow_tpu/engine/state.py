@@ -238,13 +238,18 @@ class Outbox:
     round-boundary flush turns rows into a batched cross-host push (the
     all-to-all exchange when sharded). Delivery times are already computed
     (and clamped to >= round end, as in reference worker.rs:399-402).
+
+    The payload keeps its 8 words on the second axis and the O slots on
+    the last: the chip tiles the two minor axes (8 sublanes x 128 lanes),
+    so [H, 8, O] is dense where a minor axis of 8 pads to 128 lanes and
+    every pass over it moves 16 times its bytes (PERF.md, PR 29).
     """
 
     valid: jax.Array  # [H, O] bool
     dst: jax.Array  # [H, O] i32
     time: jax.Array  # [H, O] i64 delivery time
     tie: jax.Array  # [H, O] i64
-    data: jax.Array  # [H, O, PAYLOAD_LANES] i32
+    data: jax.Array  # [H, PAYLOAD_LANES, O] i32, slots minor
     aux: jax.Array  # [H, O] i32 (packet size in bytes)
     fill: jax.Array  # [H] i32 next free lane
     overflow: jax.Array  # [H] i32 emissions dropped for lack of lanes
@@ -256,7 +261,7 @@ def _empty_outbox(h: int, o: int) -> Outbox:
         dst=jnp.zeros((h, o), jnp.int32),
         time=jnp.full((h, o), TIME_MAX, jnp.int64),
         tie=jnp.zeros((h, o), jnp.int64),
-        data=jnp.zeros((h, o, PAYLOAD_LANES), jnp.int32),
+        data=jnp.zeros((h, PAYLOAD_LANES, o), jnp.int32),
         aux=jnp.zeros((h, o), jnp.int32),
         fill=jnp.zeros((h,), jnp.int32),
         overflow=jnp.zeros((h,), jnp.int32),
@@ -534,9 +539,9 @@ def grow_state(
     refused — it could drop live slots."""
     from shadow_tpu.events import KIND_INVALID
 
-    def pad(a, extra, fill, dtype):
-        shape = (a.shape[0], extra) + a.shape[2:]
-        return jnp.concatenate([a, jnp.full(shape, fill, dtype)], axis=1)
+    def pad(a, extra, fill, dtype, axis=1):
+        shape = a.shape[:axis] + (extra,) + a.shape[axis + 1:]
+        return jnp.concatenate([a, jnp.full(shape, fill, dtype)], axis=axis)
 
     q = st.queue
     if queue_capacity is not None and queue_capacity != q.capacity:
@@ -561,7 +566,7 @@ def grow_state(
             dst=pad(ob.dst, extra, 0, jnp.int32),
             time=pad(ob.time, extra, TIME_MAX, jnp.int64),
             tie=pad(ob.tie, extra, 0, jnp.int64),
-            data=pad(ob.data, extra, 0, jnp.int32),
+            data=pad(ob.data, extra, 0, jnp.int32, axis=2),  # [H, 8, O]
             aux=pad(ob.aux, extra, 0, jnp.int32),
         )
     return st.replace(queue=q, outbox=ob)

@@ -55,7 +55,10 @@ from shadow_tpu.config.fingerprint import (  # noqa: F401
 from shadow_tpu.engine.state import SimState, state_from_host
 from shadow_tpu.utils.shadow_log import slog
 
-CHECKPOINT_VERSION = 1
+# 2: Outbox.data is [H, PAYLOAD_LANES, O]. A version-1 file holds it as
+# [H, O, PAYLOAD_LANES]: refused by version, not by the leaf-shape check
+# (which an outbox of 8 slots would pass, transposed)
+CHECKPOINT_VERSION = 2
 
 
 class CheckpointError(ValueError):

@@ -32,7 +32,8 @@ Every name is a single path component; nested scopes give paths:
                             view and commit_slot's one scatter
     drain/handle/stage      the handler's staging of surviving packets
                             into the host's own outbox row: one select
-                            over [H, outbox, ...] per packet lane
+                            chain over [H, outbox] per array, the
+                            payload's over [H, 8, outbox] (slots minor)
     drain/handle/push_self  equeue.push_self_lanes: the [H, queue] lane
     drain/pump/push_self    merges of the handler and the pump
     exchange                flush_outbox: flatten, bucket, clear

@@ -105,6 +105,32 @@ def test_every_scope_names_operations_of_the_compiled_chunk(chunks, case):
             assert outer == inner.split("/")[0]
 
 
+@pytest.mark.parametrize("case", ["tgen-plain", "tgen-pump", "tgen-sharded"])
+def test_the_staged_payload_keeps_its_slots_minor(chunks, case):
+    """Outbox.data is [H, 8, O] all through the chunk: under the staging
+    scopes no s32[H, O, 8] result is left (a minor axis of 8 pads to the
+    chip's 128 lanes: 16 times the bytes, PERF.md PR 29), and the select
+    chain's result is the payload on its new axes."""
+    h = 2 if case == "tgen-sharded" else 8  # 8 hosts over 4 devices
+    o, w = 32, 8  # test_pump._world's outbox_capacity; PAYLOAD_LANES
+    stage = "drain/pump" if case == "tgen-pump" else "drain/handle/stage"
+
+    class Chunk:
+        def as_text(self):
+            return chunks[case][2]
+
+    staged = [
+        shape for shape, inner, _ in scopes.chunk_table(Chunk()).values()
+        if inner == stage
+    ]
+    assert f"s32[{h},{w},{o}]" in staged, staged
+    assert f"s32[{h},{o},{w}]" not in staged
+    # nor anywhere else in the program, inside a fusion or out
+    assert f"s32[{h},{o},{w}]" not in chunks[case][2]
+    assert f"tensor<{h}x{w}x{o}xi32>" in chunks[case][0]
+    assert f"tensor<{h}x{o}x{w}xi32>" not in chunks[case][0]
+
+
 def _scopes_of(text, opcode):
     """The scope paths of the instructions of one opcode."""
     return {

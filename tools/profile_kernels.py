@@ -838,6 +838,7 @@ def profile_exchange(hosts: int = 0, reps: int = 10):
     from shadow_tpu.engine import EngineConfig, ShardedRunner, init_state
     from shadow_tpu.engine.round import (
         _flush_outbox_traffic,
+        _payload_words,
         _peek_capacity,
         bootstrap,
         handle_one_iteration,
@@ -909,14 +910,13 @@ def profile_exchange(hosts: int = 0, reps: int = 10):
     @jax.jit
     def _pool_sort(ob):
         def flat(x):
-            return x.reshape((m,) + x.shape[2:])
+            return x.reshape(m)
 
         valid, dst = flat(ob.valid), flat(ob.dst)
-        t, tie, aux, data = flat(ob.time), flat(ob.tie), flat(ob.aux), flat(ob.data)
+        t, tie, aux = flat(ob.time), flat(ob.tie), flat(ob.aux)
         key = jnp.where(valid, dst, jnp.int32(1 << 30))
         return jax.lax.sort(
-            (key, t, tie, aux, valid, dst)
-            + tuple(data[:, i] for i in range(data.shape[1])),
+            (key, t, tie, aux, valid, dst) + tuple(_payload_words(ob)),
             num_keys=3,
             is_stable=True,
         )
@@ -936,14 +936,14 @@ def profile_exchange(hosts: int = 0, reps: int = 10):
     @jax.jit
     def _land_dense(q, ob):
         def flat(x):
-            return x.reshape((m,) + x.shape[2:])
+            return x.reshape(m)
 
         lanes = cfg.deliver_lanes if cfg.deliver_lanes > 0 else q.capacity
         return equeue.push_many_sorted(
             deliver_lanes=lanes, q=q, dst=flat(ob.dst), valid=flat(ob.valid),
             time=flat(ob.time), tie=flat(ob.tie),
             kind=jnp.full((m,), KIND_PACKET, jnp.int32),
-            data=flat(ob.data), aux=flat(ob.aux),
+            data=_payload_words(ob).T, aux=flat(ob.aux),
         )
 
     peek = jax.jit(_peek_capacity)
