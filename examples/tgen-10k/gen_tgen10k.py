@@ -1,18 +1,17 @@
 #!/usr/bin/env python3
-"""Generate the tgen-10k deployment: the headline world bench.py builds by
-hand, as a config the front door runs (`shadow-tpu run`).
+"""Generate the tgen-10k deployment: the headline world, as a config the
+front door runs (`shadow-tpu run`).
 
 10,240 hosts on a 32-node random graph (each node links to 6 random peers
 and its ring successor, 2-11 ms edges with 0.5 % loss, 2 ms self-loops),
 100 Mbit hosts, tgen with half the hosts clients (the model default) fetching 100 kB responses
 with a 500 ms pause, queue 384 / outbox 64 / 256 iterations per round,
-500 ms of simulated time. (bench.py stages through 32 outbox slots; on the
-chip this world dropped 32 packets there in its first chunk — a server in
+500 ms of simulated time. (With 32 outbox slots this world dropped 32
+packets on the chip in its first chunk — a server in
 slow start emits a 39-segment flight inside one adaptive window, measured
 high water 35 at 256 hosts — so the front-door deployment takes 64 rather
 than lean on rollback-and-regrow, which recompiles for minutes.) Hosts are laid out by node block (320 per node,
-clients on nodes 0-15, servers on 16-31), not bench.py's `i % 32`: a
-user-real size is what matters here, not equality with bench.py's world.
+clients on nodes 0-15, servers on 16-31).
 
   gen_tgen10k.py > shadow.yaml
 

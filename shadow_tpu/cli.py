@@ -161,7 +161,7 @@ def main(argv: "list[str] | None" = None) -> int:
         action="append",
         metavar="SPEC",
         help="inject a deterministic fault: KIND[@AT][:key=val...], e.g. "
-        "'capacity@2', 'stall@1:stall_s=0.5', 'compile:target=megakernel' "
+        "'capacity@2', 'stall@1:stall_s=0.5', 'compile:target=pump' "
         "(repeatable; kinds: capacity, stall, compile, ckpt-corrupt, "
         "ckpt-truncate, worker-kill, worker-hang, preempt; chaos.faults)",
     )

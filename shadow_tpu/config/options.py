@@ -284,11 +284,11 @@ class ExperimentalOptions:
     # Bit-identical results at any value.
     active_lanes: int = 0
     # Round-engine selection (engine/state.py EngineConfig.engine): all
-    # four values are bit-identical on every model; determinism-relevant
+    # three values are bit-identical on every model; determinism-relevant
     # only in that the config fingerprint pins a resumed run to the exact
     # executable its checkpoints were written under.
-    engine: str = "auto"  # "auto" | "plain" | "pump" | "megakernel"
-    pump_k: int = 0  # microsteps per pump/megakernel iteration (0 = off)
+    engine: str = "auto"  # "auto" | "plain" | "pump"
+    pump_k: int = 0  # microsteps per pump iteration (0 = off)
     queue_capacity: int = 64
     outbox_capacity: int = 16
     record_capacity: int = 128  # hybrid per-host outcome-record ring
@@ -409,10 +409,11 @@ class ExperimentalOptions:
                 f"unknown scheduler {out.scheduler!r} "
                 "(expected 'tpu', 'cpu-ref', or 'managed')"
             )
-        if out.engine not in ("auto", "plain", "pump", "megakernel"):
+        from shadow_tpu.engine.state import ENGINES
+
+        if out.engine not in ENGINES:
             raise ValueError(
-                f"unknown engine {out.engine!r} "
-                "(expected 'auto', 'plain', 'pump', or 'megakernel')"
+                f"unknown engine {out.engine!r} (expected one of {ENGINES})"
             )
         _reject_unknown("experimental", d)
         return out

@@ -2,9 +2,9 @@
 TCP core (cpu_ref/tcp_ref.py) plus the TgenModel application wrapper —
 clients cycle request/response streams over fresh ports against
 round-robin servers; servers respond-and-close when the request is fully
-delivered (models/tgen.py). This is the exact code path bench.py measures,
-so the benchmark's semantics are independently bit-checked the same way
-bulk-tcp's are (round-2 verdict item 3)."""
+delivered (models/tgen.py). This is the code path of the benchmark's
+tgen-10k cells, so their semantics are independently bit-checked the
+same way bulk-tcp's are (round-2 verdict item 3)."""
 
 from __future__ import annotations
 

@@ -8,8 +8,7 @@ statistically unsound. The TPU answer is batching: every leaf of the
 HBM-resident SimState gains a leading replica axis [R, ...] and the
 existing round engines run under ONE jax.vmap — one compile, one kernel
 launch per drain iteration, R worlds. Compilation and dispatch overhead
-(the dominant cost at small/medium H, tools/profile_kernels.py part 5)
-amortize across the whole batch.
+(the dominant cost at small/medium H) amortize across the whole batch.
 
 Independence is exact, not statistical: replica r's PRNG streams come
 from rng.replica_keys — row r IS host_keys(seed + r * stride) — and the
@@ -35,13 +34,9 @@ What makes the batch correct under vmap:
     names the replica — rollback-and-regrow (runtime/recovery.py) then
     rolls back and regrows the WHOLE batch, keeping every replica on
     the one shared compiled shape;
-  * engine support — plain and pump vmap directly. The megakernel's
-    pallas_call is not exercised under vmap here; engine="megakernel"
-    falls back to the pump microscan (ensemble_engine_cfg), which is
-    bit-identical by construction (tests/test_megakernel.py), so the
-    fallback cannot change any replica's trajectory. Ensembles run on a
-    single device; sharding the host axis under an ensemble is future
-    work (docs/ensemble.md).
+  * engine support — plain and pump both vmap directly. Ensembles run
+    on a single device; replicas x host shards is the mesh plane
+    (engine/mesh.py).
 
 The driver below mirrors engine/round.py `_drive` (depth-2 pipelining,
 donated chunk states, two-phase checkpoint commit) with the probe logic
@@ -113,17 +108,8 @@ _SUM_LANES = frozenset(range(PROBE_LANES)) - {
 def ensemble_engine_cfg(cfg: EngineConfig) -> EngineConfig:
     """The engine config an ensemble actually traces: cfg.ensemble arms
     the per-replica done-mask in run_round (semantics-neutral; unbatched
-    runs skip its cost — engine/state.py), and the megakernel's
-    pallas_call is not exercised under vmap here, so an explicit
-    megakernel engine ("auto" never resolves to it — effective_engine)
-    falls back to the XLA pump microscan: the SAME pump microsteps,
-    bit-identical results (tests/test_megakernel.py), one vmappable
-    program."""
-    if cfg.engine == "megakernel":
-        return dataclasses.replace(
-            cfg, ensemble=True, engine="pump",
-            pump_k=cfg.pump_k if cfg.pump_k > 0 else 8,
-        )
+    runs skip its cost — engine/state.py). Nothing else changes: the
+    engine, pump_k and exchange are the ones asked for."""
     return dataclasses.replace(cfg, ensemble=True)
 
 
@@ -514,8 +500,7 @@ def run_ensemble_until(
     replica has work left before end_time. `st` is an init_ensemble_state
     [R, ...] pytree; the returned state has the same shape. `cfg` must be
     the per-replica (single-world) config — it is resolved through
-    ensemble_engine_cfg, so engine="megakernel" transparently runs the
-    pump microscan. Everything else matches run_until: depth-2 pipeline,
+    ensemble_engine_cfg. Everything else matches run_until: depth-2 pipeline,
     donated chunk states, ChunkProbe on_chunk callbacks (aggregated
     across replicas), tracker spans, on_state checkpoint taps.
     `on_rows(rows)` streams the raw per-replica probe (see

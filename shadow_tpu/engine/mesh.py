@@ -41,13 +41,10 @@ by the same init_ensemble_state stack.
 
 One mesh-specific wrinkle: the destination-bucketed all_to_all exchange
 is not batchable under the replica vmap (jax has no batching rule for
-lax.all_to_all), so dense mesh configs resolve `exchange` to
-"all_gather" — trajectory-neutral by the exchange-mode contract
-(delivery order is key-driven; engine/round.py flush_outbox), at the
-cost of more ICI traffic per round. exchange="segment" lifts the pin:
-its bucketed collective is a ppermute ring (engine/round.py
-_ring_exchange), and ppermute batches under vmap, so segment mesh runs
-move only per-peer buckets over ICI like the 1-D sharded plane does.
+lax.all_to_all), so mesh configs resolve `exchange` to "all_gather" —
+trajectory-neutral by the exchange-mode contract (delivery order is
+key-driven; engine/round.py flush_outbox), at the cost of more ICI
+traffic per round.
 """
 
 from __future__ import annotations
@@ -195,19 +192,11 @@ class MeshPlan:
 
 def mesh_engine_cfg(cfg: EngineConfig) -> EngineConfig:
     """The engine config a mesh batch actually traces: the ensemble
-    resolution (done-mask armed, megakernel -> pump under the replica
-    vmap) plus the exchange resolution. Dense modes pin to all_gather —
-    lax.all_to_all has no vmap batching rule — while "segment" passes
-    through unpinned: its bucketed collective is a ppermute ring
-    (engine/round.py _ring_exchange) and ppermute DOES batch under the
-    replica vmap, giving the mesh plane a destination-bucketed exchange
-    with no all_gather blowup. The exchange modes are trajectory-
-    identical by contract (flush_outbox: delivery order is key-driven),
-    so neither resolution can change a slice."""
-    cfg = ensemble_engine_cfg(cfg)
-    if cfg.exchange not in ("all_gather", "segment"):
-        cfg = dataclasses.replace(cfg, exchange="all_gather")
-    return cfg
+    resolution (done-mask armed) plus the exchange pinned to all_gather:
+    lax.all_to_all has no vmap batching rule. The exchange modes are
+    trajectory-identical by contract (flush_outbox: delivery order is
+    key-driven), so the pin cannot change a slice."""
+    return dataclasses.replace(ensemble_engine_cfg(cfg), exchange="all_gather")
 
 
 def mesh_state_specs(st: SimState, plan: MeshPlan):

@@ -49,22 +49,19 @@ The carry landing (pump_carry_finish) only ever touches the host's OWN
 row — defer-FIFO leftovers re-enter via conflict-free self-lane pushes
 and packet emissions re-enter the per-host outbox — so the pump is
 exchange-mode agnostic: the round-boundary cross-host landing happens
-entirely in flush_outbox afterwards (dense grid or sort-based segment
-exchange per cfg.exchange), identically for every engine.
+entirely in flush_outbox afterwards (all_to_all or all_gather per
+cfg.exchange, landed by pull), identically for every engine.
 
 Models opt in by exposing `pump_spec` (see TcpPumpSpec); the spec's
 `block` hook vetoes steps where the embedding model itself would act on
 the new state (e.g. tgen's request-complete -> respond trigger).
 
 Structure (round 6): the per-microstep body is factored into an explicit
-carry — `pump_carry_init` / `pump_microstep` / `pump_carry_finish` — so
-the SAME arithmetic runs in two engines: `pump_stage` (plain XLA, each
-microstep its own HLO program) and the Pallas round megakernel
-(engine/megakernel.py), which executes the identical `pump_microstep`
-function over VMEM-resident state tiles inside ONE kernel launch. There
-is deliberately no second copy of the fast-path semantics anywhere: the
-megakernel's bit-identity to this stage (and hence, transitively, to the
-full handler and the scalar oracle) is structural, not hand-mirrored.
+carry — `pump_carry_init` / `pump_microstep` / `pump_carry_finish` —
+which `pump_stage` strings together: init, pump_k cond-guarded
+microsteps, finish. One microstep is a pure function of the carry, so it
+can be traced, compiled and tested alone (tools/compile_for_chip.py
+`pump_microstep`, tests/test_chip_compile.py).
 """
 
 from __future__ import annotations
@@ -128,15 +125,13 @@ class TcpPumpSpec:
 class PumpCarry:
     """Everything a pump microstep reads or writes, host-axis leading.
 
-    This is the exact working set the megakernel keeps VMEM-resident
-    between microsteps; every leaf leads with the (local) host axis except
-    `min_used` (scalar, reduced per tile by the megakernel). `ts` is the
+    This is the exact working set of the microsteps; every leaf leads
+    with the (local) host axis except `min_used` (scalar). `ts` is the
     focus TcpState extracted by spec.get_tcp at init and merged back by
     spec.set_tcp at finish; `mstate` carries the rest of the model pytree
     (its embedded TcpState copy is stale during the scan and unused).
-    `key_data` is the raw-u32 view of the per-host threefry keys (typed
-    key arrays cannot cross a pallas_call boundary; wrap_key_data inside
-    the step restores bit-identical draws).
+    `key_data` is the raw-u32 view of the per-host threefry keys
+    (wrap_key_data inside the step restores bit-identical draws).
     """
 
     # mutated simulation state
@@ -169,8 +164,8 @@ class PumpCarry:
     packets_dropped: jax.Array
     packets_unroutable: jax.Array
     # tracker plane ([H] i64 when cfg.tracker, else None — a None leaf
-    # is absent from the flattened pytree, so the megakernel tiles and
-    # streams NOTHING for them with the plane off. These are the only
+    # is absent from the flattened pytree, so the carry holds NOTHING
+    # for them with the plane off. These are the only
     # TrackerState leaves a pump microstep can touch: pump-taken events
     # are all packets, so the per-kind local/tcp counters never move
     # here.)
@@ -185,9 +180,6 @@ class PumpCarry:
     host_ids: jax.Array
     src_node: jax.Array
     key_data: jax.Array  # [H, ...] u32 raw threefry key words
-    # read-only replicated context: the CoDel control-law table (a Pallas
-    # kernel body cannot capture constant arrays, so it rides the carry)
-    codel_table: jax.Array  # [1 + _CODEL_TABLE_LEN] i64
 
 
 def _fifo_peek(f_time, f_tie, f_head, f_cnt):
@@ -248,7 +240,6 @@ def pump_carry_init(
         host_ids=st.host_id,
         src_node=tables.host_node[st.host_id],
         key_data=jax.random.key_data(st.rng_key),
-        codel_table=netstack.codel_table(),
     )
 
 
@@ -263,8 +254,8 @@ def pump_microstep(
     """One pump microstep: select each live host's true next event,
     classify against P1/P2/P3, commit taken steps, mark the rest
     rejected. Pure function of the carry — every op is row-local
-    (elementwise over [H] / [H, S] / [H, K]), which is what lets the
-    megakernel tile the host axis.
+    (elementwise over [H] / [H, S] / [H, K]), which is what lets
+    active-set compaction hand it any subset of rows.
 
     Cost shape: every per-step update is elementwise over [H] or [H, S]
     with a slot-one-hot mask — no gather/scatter of the TcpState (the
@@ -371,9 +362,7 @@ def pump_microstep(
             net.rx_tokens, net.rx_last, net.rx_refill, ev_time, size_in, need
         )
         sojourn = ready - ev_time
-        codel_drop, net_c = netstack.codel_dequeue(
-            net, ready, sojourn, need, control_table=c.codel_table
-        )
+        codel_drop, net_c = netstack.codel_dequeue(net, ready, sojourn, need)
         keep_in = need & ~codel_drop
         defer = keep_in & (ready > ev_time)
         p1_take = is_pkt & ~shaped & (defer | codel_drop)
@@ -832,8 +821,8 @@ def pump_microstep(
         # (engine/round.py): control = wire size <= the model's header
         # size (the P2 ACK / P3 FIN lanes), data = the rest; retrans is
         # the same per-event segment count the step adds to
-        # ts.retransmits — so pump/megakernel tracker leaves stay
-        # leaf-exact vs the plain engine.
+        # ts.retransmits — so the pump's tracker leaves stay leaf-exact
+        # vs the plain engine.
         hdr = int(getattr(model, "WIRE_HEADER_BYTES", 0))
         is_ctrl = kept_l & (lsz_all <= hdr)
         trk_bytes_ctrl = trk_bytes_ctrl + jnp.sum(

@@ -337,8 +337,8 @@ def handle_one_iteration(
     # in every engine); byte classes split kept emissions by wire size
     # vs the model's header size; retrans counts the per-event delta of
     # the flow table's retransmits counter — the pump adds the exact
-    # same per-event count, so plain/pump/megakernel tracker leaves are
-    # leaf-exact identical (tests/test_tracker.py).
+    # same per-event count, so plain/pump tracker leaves are leaf-exact
+    # identical (tests/test_tracker.py).
     tracker = st.tracker
     if cfg.tracker:
         # kind integers are only unique within a model (events.py), so
@@ -420,10 +420,10 @@ def compact_step(
     the <= `lanes` hosts whose next event is inside the window
     (_compact_rows), gather their rows of the *entire* SimState into a
     [lanes]-row sub-state, run the unchanged `body` (the plain handler,
-    or the pump/megakernel stage followed by the handler) there, and
-    scatter the rows back — so the pump microscan and the megakernel's
-    Pallas tiles cover only occupied lanes instead of paying full-[H]
-    microsteps when a handful of hosts are active.
+    or the pump stage followed by the handler) there, and scatter the
+    rows back — so the pump microscan covers only occupied lanes
+    instead of paying full-[H] microsteps when a handful of hosts are
+    active.
 
     Correctness: hosts are independent within a conservative window (the
     PDES invariant — packets land next round, local emits stay on-row),
@@ -479,7 +479,7 @@ def handle_one_iteration_compact(
 
 
 def model_pump_capable(model) -> bool:
-    """Whether the pump/megakernel fast paths can honor this model: it
+    """Whether the pump fast path can honor this model: it
     must publish a pump_spec and use none of the hooks the microscan
     cannot replay (loss counters, packet-outcome / codel-drop callbacks).
     Models failing this always take the plain handler — bit-identical on
@@ -510,9 +510,9 @@ def flush_outbox(
     """Round-boundary exchange: deliver staged packets into destination queues.
 
     Sharded, this is the cross-chip step (the analogue of the locked
-    cross-host EventQueue push, worker.rs:619-629), with three modes:
+    cross-host EventQueue push, worker.rs:619-629), with two modes:
 
-      * all_to_all (default; "dense" is an alias): bucket outbox entries
+      * all_to_all (default): bucket outbox entries
         by destination shard, exchange only each peer's bucket over ICI
         — per-shard traffic is O(devices x bucket) instead of
         O(devices x whole outbox). Bucket capacity is static (XLA
@@ -520,13 +520,8 @@ def flush_outbox(
         check_capacity, like every other fixed-slot resource.
       * all_gather: every shard receives every shard's whole outbox and
         filters its own rows (simple, never overflows, more traffic).
-      * segment: sort-based segment exchange (_flush_segment) — compact
-        the staged events into a flat dst-sorted pool, move per-peer
-        buckets over a ppermute ring (vmap-batchable, so the mesh plane
-        uses it unpinned), land via equeue.push_many_segment with
-        capacity checked once per round from pool/row occupancy.
 
-    In every mode the destination pops by the (time, tie) key, so
+    In both modes the destination pops by the (time, tie) key, so
     delivery slot order — which differs between the modes — cannot
     affect results.
     """
@@ -560,8 +555,6 @@ def _payload_words(ob: Outbox) -> jax.Array:
 def _flush_outbox_traffic(
     st: SimState, axis_name: Optional[str], cfg: "EngineConfig | None" = None
 ) -> SimState:
-    if cfg is not None and getattr(cfg, "exchange", "") == "segment":
-        return _flush_segment(st, axis_name, cfg)
     ob = st.outbox
     h_local, o_cap = ob.valid.shape
     m = h_local * o_cap
@@ -579,7 +572,7 @@ def _flush_outbox_traffic(
     if axis_name is not None:
         mode = getattr(cfg, "exchange", "all_to_all") if cfg is not None else "all_gather"
         base = jax.lax.axis_index(axis_name) * h_local
-        if mode in ("all_to_all", "dense"):
+        if mode == "all_to_all":
             d = jax.lax.axis_size(axis_name)
             cap = getattr(cfg, "a2a_capacity", 0) or 0
             if cap <= 0:
@@ -655,172 +648,6 @@ def _flush_outbox_traffic(
     return st.replace(queue=queue, outbox=fresh)
 
 
-def _ring_exchange(arrs: tuple, axis_name: str, d: int) -> tuple:
-    """Bucketed ring collective for the segment exchange: every array in
-    `arrs` is a [d, cap, ...] per-peer bucket stack; shard i's bucket
-    for peer p moves to p over d-1 ppermute steps (step k sends bucket
-    (i+k)%d to peer (i+k)%d). Returns [d, cap, ...] arrays of received
-    buckets, own bucket first — reception order is static, and delivery
-    order is key-driven anyway.
-
-    Unlike lax.all_to_all, ppermute HAS a vmap batching rule, which is
-    what lets the 2-D mesh plane run this bucketed exchange under its
-    replica vmap instead of pinning to all_gather (engine/mesh.py).
-    Bytes over ICI: (d-1) x cap per array vs all_gather's (d-1) x m —
-    the lane-factor saving when cap (the measured per-round traffic)
-    is below the dense outbox width m."""
-    with jax.named_scope(scopes.COLLECTIVE):
-        idx = jax.lax.axis_index(axis_name)
-        received = [
-            tuple(
-                jax.lax.dynamic_index_in_dim(a, idx, 0, keepdims=False)
-                for a in arrs
-            )
-        ]
-        for k in range(1, d):
-            perm = [(i, (i + k) % d) for i in range(d)]
-            send = tuple(
-                jax.lax.dynamic_index_in_dim(
-                    a, (idx + k) % d, 0, keepdims=False
-                )
-                for a in arrs
-            )
-            received.append(
-                tuple(jax.lax.ppermute(s, axis_name, perm) for s in send)
-            )
-        return tuple(
-            jnp.stack([r[j] for r in received]) for j in range(len(arrs))
-        )
-
-
-def _flush_segment(
-    st: SimState, axis_name: Optional[str], cfg: "EngineConfig"
-) -> SimState:
-    """Segment-exchange flush (exchange="segment", event-exchange v2):
-
-      1. POOL — one stable (dst, time, tie) multi-operand sort compacts
-         the round's staged events into the first slots of a flat
-         buffer; the leading pool_capacity entries (0 = the whole
-         flattened outbox, never truncates) ARE the time-sorted compact
-         pool (count + ragged offsets implicit in the sorted keys).
-         Events beyond the pool overflow loudly (outbox lane).
-      2. EXCHANGE (sharded/mesh) — the pool is already grouped by
-         destination shard (global dst sort), so per-peer buckets fall
-         out of the same rank arithmetic as the dense all_to_all; the
-         buckets move over a ppermute ring (_ring_exchange), which —
-         unlike lax.all_to_all — batches under the mesh plane's replica
-         vmap. Bucket capacity follows a2a_capacity (<=0 = whole pool,
-         never overflows).
-      3. LAND — equeue.push_many_segment: one destination sort + a
-         free-slot gather + M-sized scatters, with capacity checked
-         once per row from pool/row occupancy instead of per lane.
-
-    Trajectory/stat-leaf bit-exact vs the dense path by the pop-order
-    contract (delivery slot order is key-driven); queue arrays are
-    slot-permuted only."""
-    ob = st.outbox
-    h_local, o_cap = ob.valid.shape
-    m = h_local * o_cap
-
-    def flat(x):
-        return x.reshape(m)
-
-    valid, dst, time, tie = flat(ob.valid), flat(ob.dst), flat(ob.time), flat(ob.tie)
-    words, aux = _payload_words(ob), flat(ob.aux)
-
-    # 1. pool compaction: valids first, grouped by destination, time-
-    # sorted within each destination segment
-    big = jnp.int32(1 << 30)
-    key = jnp.where(valid, dst, big)
-    _, time_p, tie_p, aux_p, valid_p, dst_p, *data_cols = jax.lax.sort(
-        (key, time, tie, aux, valid, dst)
-        + tuple(words),
-        num_keys=3,
-        is_stable=True,
-    )
-    e_max = min(getattr(cfg, "pool_capacity", 0) or m, m)
-    n_valid = jnp.sum(valid, dtype=jnp.int32)
-    pool_drop = (
-        jnp.maximum(n_valid - e_max, 0).astype(jnp.int32)
-        if e_max < m
-        else None
-    )
-    valid_p = valid_p[:e_max]
-    dst_p, time_p, tie_p, aux_p = (
-        dst_p[:e_max], time_p[:e_max], tie_p[:e_max], aux_p[:e_max],
-    )
-    data_p = jnp.stack([c[:e_max] for c in data_cols], axis=-1)
-    overflow_extra = pool_drop
-
-    base = 0
-    if axis_name is not None:
-        d = jax.lax.axis_size(axis_name)
-        base = jax.lax.axis_index(axis_name) * h_local
-        cap = getattr(cfg, "a2a_capacity", 0)
-        cap = e_max if cap <= 0 else min(cap, e_max)
-        # per-peer buckets: the pool is dst-sorted, so destination-shard
-        # segments are contiguous; same rank/bucketize pattern as the
-        # dense all_to_all branch
-        pos = jnp.arange(e_max)
-        shard_of = jnp.where(valid_p, dst_p // h_local, d).astype(jnp.int32)
-        seg_start = jnp.concatenate(
-            [jnp.ones((1,), bool), shard_of[1:] != shard_of[:-1]]
-        )
-        rank = (pos - jax.lax.cummax(jnp.where(seg_start, pos, -1))).astype(
-            jnp.int32
-        )
-        fits = valid_p & (rank < cap)
-        sdst = jnp.where(fits, shard_of, d)
-        sslot = jnp.where(fits, rank, cap)
-        ring_over = jnp.sum(valid_p & ~fits).astype(jnp.int32)
-        overflow_extra = (
-            ring_over if overflow_extra is None else overflow_extra + ring_over
-        )
-
-        def bucketize(x, fill):
-            buf = jnp.full((d, cap) + x.shape[1:], fill, x.dtype)
-            return buf.at[sdst, sslot].set(x, mode="drop")
-
-        valid_p, dst_p, time_p, tie_p, aux_p, data_p = (
-            b.reshape((d * cap,) + b.shape[2:])
-            for b in _ring_exchange(
-                (
-                    bucketize(valid_p, False),
-                    bucketize(dst_p, 0),
-                    bucketize(time_p, TIME_MAX),
-                    bucketize(tie_p, 0),
-                    bucketize(aux_p, 0),
-                    bucketize(data_p, 0),
-                ),
-                axis_name,
-                d,
-            )
-        )
-
-    local_dst = dst_p - base
-    mine = valid_p & (local_dst >= 0) & (local_dst < h_local)
-    with jax.named_scope(scopes.LAND):
-        queue = equeue.push_many_segment(
-            q=st.queue,
-            dst=local_dst,
-            valid=mine,
-            time=time_p,
-            tie=tie_p,
-            kind=jnp.full(valid_p.shape, KIND_PACKET, jnp.int32),
-            data=data_p,
-            aux=aux_p,
-        )
-
-    fresh = ob.replace(
-        valid=jnp.zeros_like(ob.valid),
-        time=jnp.full_like(ob.time, TIME_MAX),
-        fill=jnp.zeros_like(ob.fill),
-    )
-    if overflow_extra is not None:
-        fresh = fresh.replace(overflow=fresh.overflow.at[0].add(overflow_extra))
-    return st.replace(queue=queue, outbox=fresh)
-
-
 def run_round(
     st: SimState,
     window_end: jax.Array,
@@ -846,33 +673,12 @@ def run_round(
     # pump_k > 0, else plain, on every backend). Models without a
     # pump_spec (or with hooks the fast paths can't honor) always take the
     # plain handler, so every engine value is bit-identical on every model.
-    # With compaction, the WHOLE iteration body — pump/megakernel stage
-    # plus the rejection-handler pass — runs on the gathered
-    # [active_lanes]-row sub-state, so the stage's microsteps and the
-    # megakernel's tiles cover only occupied lanes.
-    pump_capable = model_pump_capable(model)
-    eng = effective_engine(cfg)
-    stage, stage_cfg = None, cfg
-    if eng == "megakernel" and pump_capable:
-        from shadow_tpu.engine.megakernel import (
-            megakernel_stage,
-            resolve_stage_cfg,
-        )
-
-        stage_cfg = resolve_stage_cfg(cfg)
-        if axis_name is None:
-            stage = megakernel_stage
-        else:
-            # sharded runs keep the XLA pump for now (pallas_call under
-            # shard_map is untested here); same microsteps, same results
-            from shadow_tpu.engine.pump import pump_stage
-
-            stage = pump_stage
-    elif eng == "pump" and cfg.pump_k > 0 and pump_capable:
+    # With compaction, the WHOLE iteration body — pump stage plus the
+    # rejection-handler pass — runs on the gathered [active_lanes]-row
+    # sub-state, so the stage's microsteps cover only occupied lanes.
+    use_pump = effective_engine(cfg) == "pump" and model_pump_capable(model)
+    if use_pump:
         from shadow_tpu.engine.pump import pump_stage
-
-        stage = pump_stage
-    use_pump = stage is not None
 
     def cond(carry):
         s, iters = carry
@@ -888,7 +694,7 @@ def run_round(
         """One iteration over whatever rows `s` holds (full or compacted)."""
         if use_pump:
             with jax.named_scope(scopes.PUMP):
-                s, rej = stage(s, window_end, model, tables, stage_cfg)
+                s, rej = pump_stage(s, window_end, model, tables, cfg)
             # the full handler only runs when some host's head event
             # failed pump classification — pump-only iterations cover the
             # steady packet streams (chains longer than pump_k keep
@@ -944,9 +750,9 @@ def run_round(
                     ),
                     # per-round exchange traffic high-water (row 0, like
                     # iters_done): sum of staged events right before the
-                    # flush — the measured figure that sizes a2a/segment
-                    # ring buckets (sharded.auto_a2a_capacity) and the
-                    # pool occupancy CapacityError reports
+                    # flush — the measured figure that sizes a2a buckets
+                    # (sharded.auto_a2a_capacity) and the exchange
+                    # occupancy CapacityError reports
                     exch_hwm=st.tracker.exch_hwm.at[0].max(
                         jnp.sum(st.outbox.fill).astype(jnp.int32)
                     ),
@@ -1170,14 +976,14 @@ PROBE_ROUNDS_IDLE = 18
 # numerator), and the summed simulated width of all live windows. NB the
 # derived window_ns_mean needs the tracker's rounds_live as denominator,
 # so it reads 0.0 on tracker-off runs even though win_ns_sum accrues —
-# consumers of the mean (bench, profiler, --tracker stats) run tracker-on
+# consumers of the mean (--tracker stats) run tracker-on
 PROBE_ITERS = 19
 PROBE_LANES_LIVE = 20
 PROBE_WIN_NS = 21
 # exchange traffic high-water: most events any shard flushed in one
-# round (tracker plane, pmax'd sharded) — feeds measured a2a/segment
-# bucket sizing (sharded.auto_a2a_capacity) and the pool-occupancy
-# figure in CapacityError
+# round (tracker plane, pmax'd sharded) — feeds measured a2a bucket
+# sizing (sharded.auto_a2a_capacity) and the exchange-occupancy figure
+# in CapacityError
 PROBE_EXCH_HWM = 22
 PROBE_LANES = 23
 
@@ -1310,7 +1116,7 @@ class CapacityError(RuntimeError):
     bytes_regrown: int = 0
     # exchange-pool occupancy high-water (most events flushed in one
     # round, PROBE_EXCH_HWM; 0 without cfg.tracker) — the figure that
-    # says whether a segment pool / a2a bucket was sized too small
+    # says whether an a2a bucket was sized too small
     exchange_hwm: int = 0
     shard_detail: "str | None" = None
     # ensemble runs (engine/ensemble.py): index of the replica whose
@@ -1416,8 +1222,8 @@ class EngineCompileError(RuntimeError):
     """The selected engine failed to compile/trace its chunk program.
     The engines are leaf-exact bit-identical, so this is recoverable by
     degradation: runtime/chaos.py run_with_engine_ladder falls one rung
-    (megakernel → pump → plain), logging the reason; only a plain-engine
-    failure is terminal."""
+    (pump → plain), logging the reason; only a plain-engine failure is
+    terminal."""
 
     def __init__(self, engine: str, cause: "BaseException | None" = None):
         super().__init__(
@@ -1431,17 +1237,10 @@ def effective_engine(cfg) -> str:
     """The engine an "auto" config actually runs — the single resolution
     seam run_round's engine selection, the chaos `compile` fault targets,
     and the fallback-ladder records all share (runtime/chaos.py,
-    runtime/scheduler.py). One rule on every backend (docs/megakernel.md
-    "Engine selection"):
+    runtime/scheduler.py). One rule on every backend:
 
       1. an explicit engine name always wins;
       2. "auto" is the pump when pump_k > 0, else the plain handler.
-
-    "auto" never means the megakernel: it is an interpret-mode-verified
-    design that the chip's compiler refuses (`ZeroDivisionError: integer
-    modulo by zero` from Mosaic's block-mapping check — the carry is
-    int64 and a 64-bit block has tiling 128 * (32 // 64) = 0), reachable
-    only by `engine: megakernel`, which on a TPU fails with that error.
 
     What the rule rests on — compile walls of the whole chunk program
     asked of the chip's compiler for a described v5e at the tgen-10k
@@ -1560,9 +1359,7 @@ def _capacity_error(
         f"({which}); increase queue_capacity/"
         f"outbox_capacity — or, for sharded all_to_all runs with "
         f"pair-skewed destinations, set a2a_capacity=-1 (whole-outbox "
-        f"buckets, never overflow); segment-exchange runs "
-        f"(exchange='segment') raise the pool with pool_capacity "
-        f"(0 = whole outbox, never truncates)"
+        f"buckets, never overflow)"
     )
     err.queue_overflow = int(queue_ov or 0)
     err.outbox_overflow = int(outbox_ov or 0)

@@ -49,11 +49,10 @@ def auto_a2a_capacity(
     safety: int = 4,
     measured_hwm: "int | None" = None,
 ) -> int:
-    """Size the per-peer exchange bucket (all_to_all buckets; the
-    segment mode's ring buckets) rather than the never-overflow default
-    (= the whole local outbox / pool). Overflow is counted on device and
-    fails loudly via check_capacity, so a too-small bucket is an error,
-    never silent corruption (the exchange seam the reference locks a
+    """Size the per-peer all_to_all bucket rather than the
+    never-overflow default (= the whole local outbox). Overflow is
+    counted on device and fails loudly via check_capacity, so a
+    too-small bucket is an error, never silent corruption (the exchange seam the reference locks a
     mutex for, worker.rs:619-629).
 
     With `measured_hwm` — the per-round per-shard exchange high-water
@@ -111,10 +110,7 @@ class ShardedRunner:
                 f"{mesh.shape[AXIS]} devices on axis {AXIS!r}"
             )
         validate_runahead(cfg, tables)
-        if (
-            cfg.exchange in ("all_to_all", "dense", "segment")
-            and cfg.a2a_capacity == 0
-        ):
+        if cfg.exchange == "all_to_all" and cfg.a2a_capacity == 0:
             # a2a_capacity == 0 asks for the auto bucket: measured from
             # per-round traffic when the caller supplies a prior run's
             # probe high-water (ChunkProbe.exch_hwm), else the topology
@@ -122,7 +118,7 @@ class ShardedRunner:
             # fallback saves no ICI traffic). Overflow still fails
             # loudly via check_capacity, so an undersized bucket is an
             # error telling the user to set a2a_capacity=-1 (whole
-            # outbox/pool, never overflows), never silent loss.
+            # outbox, never overflows), never silent loss.
             import dataclasses
 
             cfg = dataclasses.replace(

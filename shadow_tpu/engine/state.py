@@ -24,6 +24,10 @@ from shadow_tpu.netstack import NetDevState
 from shadow_tpu.simtime import TIME_MAX
 
 
+ENGINES = ("auto", "plain", "pump")
+EXCHANGES = ("all_to_all", "all_gather")
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Static (trace-time) engine parameters."""
@@ -63,28 +67,15 @@ class EngineConfig:
     # semantics-bearing there and stays fixed.
     adaptive_window: bool = True
     # Round-boundary exchange mode (the cross-chip seam, the analogue of
-    # worker.rs:619-629). Two landing families, trajectory-identical by
-    # contract (delivery slot order is key-driven; engine/round.py
-    # flush_outbox):
-    #   dense  — land by pull (equeue.push_many_sorted): one index sort
-    #            by destination, the payload following as packed rows,
-    #            then every free queue slot gathers its arrival by rank
-    #            and one where pass merges it. "all_to_all"
-    #            (default) buckets outbox entries by destination shard
-    #            and exchanges only each peer's bucket over ICI;
-    #            "all_gather" replicates every shard's whole outbox
-    #            (more traffic, never overflows); "dense" is an explicit
-    #            alias for "all_to_all".
-    #   "segment" — sort-based segment exchange (event-exchange v2):
-    #            compact the round's in-flight events into a flat
-    #            dst-sorted pool (pool_capacity), move shard buckets
-    #            over a ppermute ring (batchable under the mesh plane's
-    #            replica vmap, unlike lax.all_to_all), and land with one
-    #            M-sized free-slot scatter + segment offsets
-    #            (equeue.push_many_segment) — cost scales with the
-    #            traffic actually in flight (as the dense landing's
-    #            does since it became a pull), and capacity is checked
-    #            once per round (pool/row occupancy).
+    # worker.rs:619-629). Both modes land by pull
+    # (equeue.push_many_sorted): one index sort by destination, the
+    # payload following as packed rows, then every free queue slot
+    # gathers its arrival by rank and one where pass merges it.
+    # "all_to_all" (default) buckets outbox entries by destination shard
+    # and exchanges only each peer's bucket over ICI; "all_gather"
+    # replicates every shard's whole outbox (more traffic, never
+    # overflows). Trajectory-identical by contract: delivery slot order
+    # is key-driven (engine/round.py flush_outbox).
     exchange: str = "all_to_all"
     # per-peer bucket capacity for all_to_all:
     #  -1  (default) = the whole local outbox: never overflows. PDES
@@ -97,19 +88,7 @@ class EngineConfig:
     #        factor fails loudly via check_capacity. Direct flush_outbox
     #        callers treat 0 like -1;
     #  >0  = explicit bucket size.
-    # Under exchange="segment" the same knob sizes the per-peer ring
-    # buckets (-1/0-direct = the whole pool: never overflows; 0 under
-    # ShardedRunner = auto, measured when an exchange high-water is
-    # supplied — see auto_a2a_capacity).
     a2a_capacity: int = -1
-    # Segment-exchange pool size (exchange="segment" only): the flat
-    # [E_max] dst-sorted buffer the round's in-flight events compact
-    # into before the collective/landing. 0 (default) = the whole
-    # flattened outbox (num_hosts_local * outbox_capacity — never
-    # truncates); >0 = explicit, smaller pools cut sort width and
-    # ring-bucket bytes, and events beyond the pool are counted loudly
-    # into outbox overflow (check_capacity names this knob).
-    pool_capacity: int = 0
     # Per-destination bound of the round-boundary landing
     # (equeue.push_many_sorted): a host takes at most its first
     # deliver_lanes arrivals of a ROUND; beyond it overflows loudly via
@@ -135,27 +114,14 @@ class EngineConfig:
     # to the unpumped engine (tests/test_pump.py).
     pump_k: int = 0
     # Engine selection for the round drain loop:
-    #   "auto"       — on every backend: the pump microscan when
-    #                  pump_k > 0 and the model is pump-capable, else the
-    #                  plain one-event-per-host handler loop; never the
-    #                  megakernel (engine/round.py effective_engine).
-    #   "plain"      — always the full handler, even with pump_k set.
-    #   "pump"       — the XLA pump microscan (requires pump_k > 0).
-    #   "megakernel" — the fused Pallas round megakernel
-    #                  (engine/megakernel.py): the SAME pump microsteps,
-    #                  executed over VMEM-resident host-state tiles inside
-    #                  one kernel launch per iteration (pump_k defaults to
-    #                  8 when unset). Falls back to the plain handler for
-    #                  models without a pump_spec. Bit-identical results
-    #                  across all four values (tests/test_megakernel.py)
-    #                  — interpreted on the CPU; the chip's compiler
-    #                  refuses the kernel today (docs/megakernel.md).
+    #   "auto"  — on every backend: the pump microscan when pump_k > 0
+    #             and the model is pump-capable, else the plain
+    #             one-event-per-host handler loop (engine/round.py
+    #             effective_engine).
+    #   "plain" — always the full handler, even with pump_k set.
+    #   "pump"  — the XLA pump microscan (requires pump_k > 0).
+    # Bit-identical results across all three values (tests/test_pump.py).
     engine: str = "auto"
-    # Megakernel host-tile rows per Pallas program (the VMEM working-set
-    # knob; see docs/megakernel.md for the byte budget). 0 = auto: the
-    # largest power-of-two divisor of the local host count whose carry
-    # tile fits the VMEM budget. Must divide num_hosts when set.
-    megakernel_tile: int = 0
     # Device-side tracker plane (docs/observability.md; reference
     # tracker.c:407-430 + sim_stats.rs): accumulate per-host per-kind
     # event counters, byte classes, and high-water marks into
@@ -180,34 +146,16 @@ class EngineConfig:
             raise ValueError(f"num_hosts must be in (0, {MAX_HOSTS}]")
         if self.runahead_ns <= 0:
             raise ValueError("runahead must be > 0")
-        if self.engine not in ("auto", "plain", "pump", "megakernel"):
+        if self.engine not in ENGINES:
             raise ValueError(
-                f"unknown engine {self.engine!r} "
-                "(expected 'auto', 'plain', 'pump', or 'megakernel')"
+                f"unknown engine {self.engine!r} (expected one of {ENGINES})"
             )
-        if self.exchange not in ("all_to_all", "all_gather", "dense", "segment"):
+        if self.exchange not in EXCHANGES:
             raise ValueError(
-                f"unknown exchange {self.exchange!r} (expected 'all_to_all', "
-                "'all_gather', 'dense', or 'segment')"
+                f"unknown exchange {self.exchange!r} (expected one of {EXCHANGES})"
             )
-        if self.pool_capacity < 0:
-            raise ValueError("pool_capacity must be >= 0 (0 = whole outbox)")
         if self.engine == "pump" and self.pump_k <= 0:
             raise ValueError("engine='pump' requires pump_k > 0")
-        if self.megakernel_tile < 0 or (
-            self.megakernel_tile > 0 and self.num_hosts % self.megakernel_tile
-        ):
-            raise ValueError("megakernel_tile must be 0 or divide num_hosts")
-        if (
-            0 < self.active_lanes
-            and self.megakernel_tile > 0
-            and self.active_lanes % self.megakernel_tile
-        ):
-            # compacted iterations hand the megakernel an active_lanes-row
-            # sub-state; an explicit tile must divide that too
-            raise ValueError(
-                "megakernel_tile must divide active_lanes when both are set"
-            )
 
 
 def trace_static_cfg(cfg: EngineConfig) -> EngineConfig:
@@ -221,13 +169,8 @@ def trace_static_cfg(cfg: EngineConfig) -> EngineConfig:
     it to 0 here means two worlds differing ONLY in seed hash to the
     same jit cache key and reuse one compiled chunk executable, which is
     what lets a sweep of N seeds pay one XLA compile
-    (runtime/compile_cache.py; docs/service.md).
-
-    "dense" is a pure alias of "all_to_all" (same trace), so it
-    canonicalizes too — the alias exists so configs/tests can name the
-    dense landing family explicitly against "segment"."""
-    exchange = "all_to_all" if cfg.exchange == "dense" else cfg.exchange
-    return dataclasses.replace(cfg, seed=0, exchange=exchange)
+    (runtime/compile_cache.py; docs/service.md)."""
+    return dataclasses.replace(cfg, seed=0)
 
 
 @flax.struct.dataclass
@@ -304,8 +247,8 @@ class TrackerState:
     # single round (sum of outbox.fill at flush time), accumulated on
     # row 0 like SimState.iters_done so the leaf stays host-led under
     # sharding. This is the measured per-round traffic that sizes
-    # all_to_all / segment-ring buckets (sharded.auto_a2a_capacity) and
-    # the pool-occupancy figure CapacityError reports.
+    # all_to_all buckets (sharded.auto_a2a_capacity) and the exchange
+    # occupancy figure CapacityError reports.
     exch_hwm: jax.Array  # [H] i32
 
 

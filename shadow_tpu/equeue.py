@@ -318,8 +318,8 @@ def push_many_sorted(
     (tools/compile_for_chip.py, CHANGES.md PR 22).
 
     Same TIME_MAX invariant as push_self: a push at TIME_MAX (the
-    free-slot marker) is rejected and counted into overflow — on row 0,
-    as push_many_segment does; always fatal via check_capacity.
+    free-slot marker) is rejected and counted into overflow — on row 0;
+    always fatal via check_capacity.
     """
     m = dst.shape[0]
     if m == 0:
@@ -392,122 +392,6 @@ def push_many_sorted(
         head_time=jnp.minimum(
             q.head_time, jnp.min(jnp.where(take, g_time, TIME_MAX), axis=1)
         ),
-    )
-
-
-def push_many_segment(
-    q: EventQueue,
-    dst: jax.Array,  # [M] i32 destination host ids
-    valid: jax.Array,  # [M] bool
-    time: jax.Array,  # [M] i64
-    tie: jax.Array,  # [M] i64
-    kind: jax.Array,  # [M] i32
-    data: jax.Array,  # [M, PAYLOAD_LANES] i32
-    aux: "jax.Array | None" = None,  # [M] i32
-) -> EventQueue:
-    """Sort-based segment landing (event-exchange v2): one stable
-    destination sort + ragged segment offsets + an M-sized free-slot
-    scatter, where push_many_sorted gathers from the queue's side.
-
-    Where the dense path has every free queue slot pull its arrival (an
-    [H, Q] gather), this pushes the M in-flight entries to their slots:
-
-      S1  stable sort of everything by destination (invalids last) —
-          per-destination ranks from a dense segment cummax, and the
-          ragged segment offsets (per-dest arrival counts) from ONE
-          searchsorted over [0..H];
-      F   per-row free-slot positions: one [H, Q] (free-rank, column)
-          sort turns the tombstone mask into col_of[h, r] = the column
-          of row h's r-th free slot;
-      L   entry i (destination d, in-segment rank r) lands at flat slot
-          d*Q + col_of[d, r] via a single M-index scatter per queue
-          array (mode="drop"); indices are provably unique among
-          fitting entries — ranks within a row are distinct and col_of
-          is injective below the row's free count — and non-fitting
-          entries get an out-of-bounds index, so the scatter can never
-          tread on a live slot.
-
-    Capacity is checked ONCE per row per call: fits = rank < room
-    (room = capacity - count = the exact free-slot count, a queue
-    invariant), with per-destination overflow counted densely as
-    max(arrivals - room, 0) — the same events the dense path would
-    reject, counted on the same destination rows, so dense and segment
-    runs stay trajectory-identical right up to (and loudly through) an
-    overflow. head_time updates via a segment min over the sorted
-    destination keys. Slot placement differs from the dense path
-    (tombstone columns fill in sorted-arrival order rather than lane
-    order) but pop order is (time, tie)-key-driven, so trajectories and
-    every stat leaf are bit-exact; only the within-row slot permutation
-    of the queue arrays differs (compare queues with
-    debug_sorted_events, as the equivalence suite does).
-
-    Same TIME_MAX invariant as push_self: a push at TIME_MAX (the
-    free-slot marker) is rejected and counted into overflow — globally
-    on row 0 (the destination is not recoverable after masking), as on
-    the dense path; sentinel pushes are engine bugs and always fatal via
-    check_capacity."""
-    if aux is None:
-        aux = jnp.zeros_like(kind)
-    m = dst.shape[0]
-    h = q.num_hosts
-    cap = q.capacity
-    sentinel = valid & (time >= TIME_MAX)
-    valid = valid & ~sentinel
-
-    # S1: group by destination (stable; invalids sort last)
-    key1 = jnp.where(valid, dst, h).astype(jnp.int32)
-    key1_s, time_s, tie_s, kind_s, aux_s, valid_s, *data_cols = jax.lax.sort(
-        (key1, time, tie, kind, aux, valid)
-        + tuple(data[:, i] for i in range(data.shape[1])),
-        num_keys=1,
-        is_stable=True,
-    )
-    pos = jnp.arange(m, dtype=jnp.int32)
-    seg_start = jnp.concatenate(
-        [jnp.ones((1,), bool), key1_s[1:] != key1_s[:-1]]
-    )
-    rank = (pos - jax.lax.cummax(jnp.where(seg_start, pos, -1))).astype(
-        jnp.int32
-    )
-    # ragged segment offsets: bounds[d] = start of destination d's run
-    hosts = jnp.arange(h + 1, dtype=jnp.int32)
-    bounds = jnp.searchsorted(key1_s, hosts, side="left", method="sort")
-    cnt = (bounds[1:] - bounds[:-1]).astype(jnp.int32)  # [H] arrivals
-
-    # F: column of each row's r-th free slot (col_of[h, r]; occupied
-    # columns sort to the tail with rank `cap`)
-    free = q.time == TIME_MAX  # [H, Q]
-    freerank = jnp.where(
-        free, jnp.cumsum(free, axis=1) - 1, cap
-    ).astype(jnp.int32)
-    cols = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (h, cap))
-    _, col_of = jax.lax.sort((freerank, cols), num_keys=1, is_stable=True)
-
-    room = (cap - q.count).astype(jnp.int32)  # [H] == free-slot count
-    dst_i = jnp.minimum(key1_s, h - 1)
-    fits = valid_s & (rank < room[dst_i])
-    col = col_of[dst_i, jnp.minimum(rank, cap - 1)]
-    idx = jnp.where(fits, dst_i * cap + col, h * cap)  # OOB -> dropped
-
-    def land(arr, vals):
-        flatq = arr.reshape((h * cap,) + arr.shape[2:])
-        return flatq.at[idx].set(vals, mode="drop").reshape(arr.shape)
-
-    landed = jnp.minimum(cnt, room)  # [H]
-    time_fit = jnp.where(fits, time_s, TIME_MAX)
-    seg_min = jax.ops.segment_min(
-        time_fit, dst_i, num_segments=h, indices_are_sorted=True
-    )
-    return q.replace(
-        time=land(q.time, time_s),
-        tie=land(q.tie, tie_s),
-        kind=land(q.kind, kind_s),
-        data=land(q.data, jnp.stack(data_cols, axis=-1)),
-        aux=land(q.aux, aux_s),
-        count=q.count + landed,
-        overflow=q.overflow
-        + (cnt - landed).at[0].add(jnp.sum(sentinel).astype(jnp.int32)),
-        head_time=jnp.minimum(q.head_time, seg_min),
     )
 
 

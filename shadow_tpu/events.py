@@ -34,7 +34,7 @@ KIND_MODEL_BASE = 1  # local (task/timer) kinds start here
 # shares the integer with KIND_TCP_TIMER, so the range must be
 # model-owned); every other handled kind is a model-local task. The
 # classification depends only on (model, kind), so per-kind counters
-# are identical across plain/pump/megakernel by construction.
+# are identical across plain/pump by construction.
 
 _SEQ_BITS = 32
 _SRC_BITS = 30

@@ -58,21 +58,12 @@ _codel_div_np = np.array(
 )
 
 
-def codel_control_law(count, table=None):
-    """interval / sqrt(count) in ns, table-driven (works on ints or arrays).
-
-    `table` overrides the module-level constant: a Pallas kernel body
-    (engine/megakernel.py) cannot capture a constant array, so the caller
-    threads the same table through the kernel boundary as an input."""
+def codel_control_law(count):
+    """interval / sqrt(count) in ns, table-driven (works on ints or arrays)."""
     if hasattr(count, "astype"):
         idx = jnp.clip(count, 1, _CODEL_TABLE_LEN)
-        return (jnp.asarray(_codel_div_np) if table is None else table)[idx]
+        return jnp.asarray(_codel_div_np)[idx]
     return int(_codel_div_np[min(max(int(count), 1), _CODEL_TABLE_LEN)])
-
-
-def codel_table() -> jax.Array:
-    """The control-law table as a device array (for kernel threading)."""
-    return jnp.asarray(_codel_div_np)
 
 
 @flax.struct.dataclass
@@ -233,7 +224,7 @@ def tb_depart_lanes(tokens, last, refill, now, sizes, charge):
     return departs, tokens_out, last_out
 
 
-def codel_dequeue(net: NetDevState, now, sojourn, active, control_table=None):
+def codel_dequeue(net: NetDevState, now, sojourn, active):
     """One CoDel dequeue step per host (codel_queue.rs:23-540, RFC 8289).
 
     `now` is the dequeue time, `sojourn` the packet's queue delay, `active`
@@ -263,7 +254,7 @@ def codel_dequeue(net: NetDevState, now, sojourn, active, control_table=None):
     count_in = count + drop_in_episode.astype(jnp.int32)
     next_in = jnp.where(
         drop_in_episode,
-        drop_next + codel_control_law(count_in, control_table),
+        drop_next + codel_control_law(count_in),
         drop_next,
     )
 
@@ -272,7 +263,7 @@ def codel_dequeue(net: NetDevState, now, sojourn, active, control_table=None):
     enter = ~dropping & ok_to_drop
     recent = (now - drop_next) < CODEL_INTERVAL_NS
     count_enter = jnp.where(recent & (count > 2), count - 2, 1).astype(jnp.int32)
-    next_enter = now + codel_control_law(count_enter, control_table)
+    next_enter = now + codel_control_law(count_enter)
 
     drop = active & (drop_in_episode | enter)
     new_dropping = jnp.where(active, (dropping & ~leave) | enter, dropping)

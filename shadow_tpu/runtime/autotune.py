@@ -1,10 +1,10 @@
 """Compile-budget autotuner: pick `rounds_per_chunk` (and a pump_k cap)
 BEFORE paying a full-scale XLA compile.
 
-BENCH_r05 published **null** because one rounds_per_chunk=128 compile at
-10240 hosts blew the entire 1100 s attempt before any fallback rung ran.
-The fix (PR 6) was a bench-local pre-probe; this module is that probe
-generalized into a reusable service every driver can run under:
+One rounds_per_chunk=128 compile at 10240 hosts once took a whole
+1100 s attempt before any fallback ran (BENCH_r05 published null). This
+module is the pre-probe that prevents it, a service every driver can run
+under:
 
   * scan-chunk compile cost is ~linear in the scan length
     (rounds_per_chunk), so compiling a TINY chunk (probe_rpc rounds)
@@ -132,8 +132,8 @@ def plan_rounds_per_chunk(
     """Measure (or recall) the tiny-chunk compile wall and choose the
     largest rounds_per_chunk whose projected compile cost fits
     `budget_s`. `n_compiles` scales the projection by how many engine
-    compiles the caller is about to pay (e.g. a bench auto-select trial
-    compiles three engines) times any engine-variance headroom.
+    compiles the caller is about to pay times any engine-variance
+    headroom.
 
     The probe runs a real `run_until` of `probe_end_ns` sim-ns at
     `probe_rpc` rounds per chunk on the caller's initial state (the
@@ -270,7 +270,7 @@ def plan_pump_k(
 ) -> AutotunePlan:
     """Cap pump_k under the same compile budget: one pump microstep's
     trace is a few hundred ops repeated pump_k times per iteration, so
-    the pump/megakernel compile grows ~linearly in pump_k the same way
+    the pump compile grows ~linearly in pump_k the same way
     the scan grows in rounds_per_chunk. Project from the measured probe
     wall (plain engine ≈ one microstep-equivalent per iteration) and pick
     the largest candidate whose extra compile cost fits `budget_share`

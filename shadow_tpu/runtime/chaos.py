@@ -35,7 +35,7 @@ Fault kinds (the injection catalog):
                     (`experimental.chunk_watchdog_s`).
   ``compile``       fail the chunk compile for the engine named by
                     `target` (or whichever tries first) — exercises the
-                    engine fallback ladder (megakernel → pump → plain).
+                    engine fallback ladder (pump → plain).
   ``ckpt-corrupt``  flip bytes inside checkpoint file number `at` after
                     it is written — exercises the sha-256 integrity
                     check and `latest_path`'s fall-back-to-valid.
@@ -396,10 +396,9 @@ def damage_file(path: str, truncate: bool) -> None:
 
 
 # --- engine fallback ladder --------------------------------------------
-# megakernel → pump → plain. Sound as a *degradation* ladder because the
-# three engines are leaf-exact bit-identical on every model
-# (tests/test_megakernel.py, tests/test_pump.py): falling a rung changes
-# wall-clock, never a single result leaf.
+# pump → plain. Sound as a *degradation* ladder because the two engines
+# are leaf-exact bit-identical on every model (tests/test_pump.py):
+# falling the rung changes wall-clock, never a single result leaf.
 
 
 def next_engine_cfg(cfg):
@@ -410,12 +409,7 @@ def next_engine_cfg(cfg):
 
     from shadow_tpu.engine.round import effective_engine
 
-    effective = effective_engine(cfg)
-    if effective == "megakernel":
-        return _dc.replace(
-            cfg, engine="pump", pump_k=cfg.pump_k if cfg.pump_k > 0 else 8
-        )
-    if effective == "pump":
+    if effective_engine(cfg) == "pump":
         return _dc.replace(cfg, engine="plain")
     return None
 
@@ -425,8 +419,8 @@ def run_with_engine_ladder(cfg, attempt, on_fallback=None, fail_fast=False):
     EngineCompileError until plain fails too (then the original error
     propagates — a structured, named failure). Returns
     (attempt result, fallback records). Each record lands in
-    sim-stats.json's `degraded` section and bench's salvage line, so a
-    degraded run is visibly degraded, never silently slower. With
+    sim-stats.json's `degraded` section, so a degraded run is visibly
+    degraded, never silently slower. With
     `fail_fast` (--no-recover: the runners pass `recovery is None`) the
     first EngineCompileError propagates and no rung is walked."""
     from shadow_tpu.engine.round import EngineCompileError

@@ -8,7 +8,7 @@ counters, scheduler progress (`now`), and the tracker plane all live on
 the state pytree. A run resumed from a checkpoint re-executes exactly the
 chunk sequence the uninterrupted run would have run from that boundary,
 so the final state is bit-identical (tests/test_robustness.py pins this
-leaf-exactly across plain/pump/megakernel and the sharded runner).
+leaf-exactly across plain/pump and the sharded runner).
 
 On-disk format (versioned): one .npz per checkpoint holding the
 state_to_host leaves (typed PRNG keys stored as raw uint32 words) as
@@ -33,6 +33,7 @@ instead of a lost run.
 
 from __future__ import annotations
 
+import dataclasses
 import glob
 import hashlib
 import json
@@ -125,6 +126,23 @@ def peek_checkpoint_meta(path: str) -> dict:
             f"checkpoint {path} is unreadable (corrupt or truncated): "
             f"{type(e).__name__}: {e}"
         ) from e
+
+
+def resume_engine_cfg(path: str, ecfg):
+    """`ecfg` at the widths the checkpoint at `path` records: an
+    interrupted run may have regrown them past the config values, and the
+    exchange/grid knobs grown alongside must follow or the resumed replay
+    re-hits the very overflow that was recovered. Other meta fields —
+    those of knobs since retired included — are passed by."""
+    meta = peek_checkpoint_meta(path)
+    overrides = {}
+    qc, oc = meta.get("queue_capacity"), meta.get("outbox_capacity")
+    if qc and oc:
+        overrides.update(queue_capacity=qc, outbox_capacity=oc)
+    for knob in ("deliver_lanes", "a2a_capacity"):
+        if knob in meta:
+            overrides[knob] = meta[knob]
+    return dataclasses.replace(ecfg, **overrides)
 
 
 def verify_checkpoint(path: str) -> "str | None":
@@ -309,7 +327,6 @@ class CheckpointManager:
         if self.engine_cfg is not None:
             meta["deliver_lanes"] = self.engine_cfg.deliver_lanes
             meta["a2a_capacity"] = self.engine_cfg.a2a_capacity
-            meta["pool_capacity"] = self.engine_cfg.pool_capacity
         t0 = time.perf_counter()
         save_checkpoint(path, host_state, meta)
         # flight recorder: checkpoint walls are part of the metrics

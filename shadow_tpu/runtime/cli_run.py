@@ -228,7 +228,7 @@ def run_mem(
                 tx_bytes_per_interval=tx, rx_bytes_per_interval=rx,
             )
         )
-    report = memtrack.price_state(st, ecfg)
+    report = memtrack.price_state(st)
     if json_out:
         print(json.dumps(report, indent=2))
     else:

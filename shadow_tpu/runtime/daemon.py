@@ -582,7 +582,7 @@ def parse_quota_class(arg: str) -> "tuple[str, dict]":
 
 def _percentiles(samples: "list[float]") -> dict:
     """p50/p90/p99 by the nearest-rank method — the admission-latency
-    summary of daemon-manifest.json and bench detail.service."""
+    summary of daemon-manifest.json."""
     if not samples:
         return {}
     xs = sorted(samples)

@@ -63,9 +63,8 @@ class EnsembleRunner:
     ):
         if num_replicas < 1:
             raise ValueError("num_replicas must be >= 1")
-        # megakernel falls back to the (bit-identical) pump under vmap —
-        # resolved once here so initial_state, the chunk jit cache key,
-        # and every recovery recompile agree on the engine
+        # the done-mask is armed once here so initial_state, the chunk
+        # jit cache key, and every recovery recompile agree on the cfg
         self.cfg = ensemble_engine_cfg(cfg)
         self.model = model
         self.tables = tables

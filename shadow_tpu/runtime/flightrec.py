@@ -502,10 +502,6 @@ class FlightRecorder:
             self._xprof_active = False
             self.xprof_dir = None
 
-    def series_tail(self, n: int = 32) -> "list[dict]":
-        """The newest n samples (bench publishes these per trial)."""
-        return list(self.samples)[-n:]
-
     def close(self) -> None:
         """End of run: stop a live xprof window, final prom snapshot,
         flush + close the metrics stream."""

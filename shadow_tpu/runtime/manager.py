@@ -855,9 +855,7 @@ class Manager:
             try:
                 from shadow_tpu.runtime import memtrack
 
-                results.extra_stats["memory"] = memtrack.memory_section(
-                    final, ecfg
-                )
+                results.extra_stats["memory"] = memtrack.memory_section(final)
             except Exception:  # noqa: BLE001
                 pass
         if recorder.metrics_path or recorder.prom_path:
@@ -927,7 +925,7 @@ class Manager:
             CheckpointManager,
             InterruptGuard,
             config_fingerprint,
-            peek_checkpoint_meta,
+            resume_engine_cfg,
         )
 
         g = self.config.general
@@ -951,22 +949,7 @@ class Manager:
                 raise CheckpointError(
                     f"--resume: no checkpoint found in {g.checkpoint_dir}"
                 )
-            meta = peek_checkpoint_meta(resume_path)
-            # rebuild at the checkpoint's recorded widths: an interrupted
-            # run may have regrown them past the config values, and the
-            # exchange/grid knobs grown alongside must follow or the
-            # resumed replay re-hits the very overflow that was recovered
-            overrides = {}
-            qc, oc = meta.get("queue_capacity"), meta.get("outbox_capacity")
-            if qc and oc:
-                overrides.update(queue_capacity=qc, outbox_capacity=oc)
-            for knob in ("deliver_lanes", "a2a_capacity", "pool_capacity"):
-                if knob in meta:
-                    overrides[knob] = meta[knob]
-            if any(
-                overrides.get(k) != getattr(ecfg, k) for k in overrides
-            ):
-                ecfg = dataclasses.replace(ecfg, **overrides)
+            ecfg = resume_engine_cfg(resume_path, ecfg)
         layout = None
         if self.mesh_plan is not None:
             layout = f"{self.mesh_plan.rows}x{self.mesh_plan.shards}"

@@ -74,19 +74,13 @@ def _leaf_name(path) -> str:
     return ".".join(parts) or "<root>"
 
 
-def price_state(st, cfg=None) -> dict:
+def price_state(st) -> dict:
     """Walk a SimState pytree (concrete, numpy host snapshot, or
     jax.eval_shape abstract) into the bytes/host report. The leading
     replica axis of ensemble/mesh states is detected from the scalar
     `now` leaf; `bytes_per_host` is total/(hosts) — the marginal cost of
     one more host row across all replicas, the number the max-hosts
-    projection divides by.
-
-    With `cfg` (EngineConfig), the report adds the TRANSIENT exchange
-    pool projection for segment-exchange runs: the flush's sorted pool
-    buffer is round-local temp, not resident state, but it is real HBM
-    the chunk program touches (pool_capacity slots, 0 = whole outbox).
-    """
+    projection divides by."""
     import jax
 
     leaves_with_path = jax.tree_util.tree_flatten_with_path(st)[0]
@@ -131,20 +125,6 @@ def price_state(st, cfg=None) -> dict:
         "groups": groups,
         "dominant": dominant,
     }
-    if cfg is not None and getattr(cfg, "exchange", "") == "segment":
-        # slot width from the outbox leaf dtypes (the pool compacts
-        # outbox slots), per replica-row of the batch
-        ob = getattr(st, "outbox", None)
-        if ob is not None and num_hosts:
-            row_bytes = buffer_nbytes(ob, len(ob.fill.shape)) - tree_nbytes(
-                (ob.fill, ob.overflow)
-            )
-            o_cap = int(ob.valid.shape[-1])
-            slot = row_bytes // max(num_hosts * o_cap * replicas, 1)
-            slots = cfg.pool_capacity or num_hosts * o_cap
-            report["exchange_pool_transient_bytes"] = int(
-                slot * slots * replicas
-            )
     return report
 
 
@@ -218,11 +198,6 @@ def render_report(report: dict, hbm_gb: "float | None" = None) -> str:
         f"{fmt_bytes(dom['bytes'])} "
         f"({100 * dom['bytes'] / max(report['total_bytes'], 1):.1f}% of state)"
     )
-    if "exchange_pool_transient_bytes" in report:
-        lines.append(
-            "  + transient exchange pool (segment flush): "
-            f"{fmt_bytes(report['exchange_pool_transient_bytes'])}"
-        )
     if hbm_gb:
         budget = int(hbm_gb * 1024**3)
         fits = max_hosts_for_budget(report, budget)
@@ -234,11 +209,11 @@ def render_report(report: dict, hbm_gb: "float | None" = None) -> str:
     return "\n".join(lines)
 
 
-def memory_section(st, cfg=None, compiled: "dict | None" = None) -> dict:
+def memory_section(st, compiled: "dict | None" = None) -> dict:
     """The compact `memory` block for sim-stats.json: group totals +
     dominant grid + best-effort device/compiled numbers (the full grid
     list stays in `shadow-tpu mem`)."""
-    report = price_state(st, cfg=cfg)
+    report = price_state(st)
     out = {
         "num_hosts": report["num_hosts"],
         "replicas": report["replicas"],
@@ -249,10 +224,6 @@ def memory_section(st, cfg=None, compiled: "dict | None" = None) -> dict:
         },
         "dominant": report["dominant"],
     }
-    if "exchange_pool_transient_bytes" in report:
-        out["exchange_pool_transient_bytes"] = report[
-            "exchange_pool_transient_bytes"
-        ]
     dev = device_memory()
     if dev is not None:
         out["device"] = dev

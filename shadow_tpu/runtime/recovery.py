@@ -99,11 +99,6 @@ def grown_cfg(cfg, err: CapacityError, growth: int):
             # lane; an explicit bucket size must grow too or the replay
             # would deterministically hit the identical bucket overflow
             changes["a2a_capacity"] = cfg.a2a_capacity * growth
-        if getattr(cfg, "pool_capacity", 0) > 0:
-            # segment-exchange pool truncation counts into the outbox
-            # lane too; same argument as a2a_capacity (pool_capacity=0
-            # is the whole outbox and never truncates, nothing to grow)
-            changes["pool_capacity"] = cfg.pool_capacity * growth
     return dataclasses.replace(cfg, **changes)
 
 
@@ -123,7 +118,6 @@ def run_until_recovering(
     checkpoints=None,
     guard=None,
     runner_factory=None,
-    on_recovery=None,
     grow_fn=None,
     watchdog_s: float = 0.0,
     replan_fn=None,
@@ -134,8 +128,7 @@ def run_until_recovering(
     on_state=...) -> SimState` overrides the driver (the sharded
     scheduler passes a ShardedRunner builder); the default is the
     single-device run_until. `checkpoints`/`guard` ride the same StateTap
-    (one shared snapshot per due point). `on_recovery(record)` fires per
-    recovery (bench progress lines). `grow_fn` overrides the regrow step
+    (one shared snapshot per due point). `grow_fn` overrides the regrow step
     (default grow_state; the ensemble runner passes the replica-vmapped
     grow_ensemble_state so the whole [R, ...] batch widens together).
     `replan_fn(err)` arms the mesh-degradation rung for DeviceLossError
@@ -390,8 +383,6 @@ def run_until_recovering(
                 failure={"kind": f"recovery:{record['kind']}",
                          "recovered": True, **record},
             )
-            if on_recovery is not None:
-                on_recovery(record)
             cur_st, cur_cfg = grown, new_cfg
             if retainer is None:
                 retainer = StateRetainer(policy.snapshot_interval_chunks)

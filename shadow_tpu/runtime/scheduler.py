@@ -52,17 +52,12 @@ class TpuScheduler:
         self.num_devices = n
         # the engine run_round actually executes for THIS model ("auto"
         # is pump when pump_k > 0, else plain — engine/round.py
-        # effective_engine, docs/megakernel.md "Engine selection"),
-        # mirroring run_round's own substitutions so the start log never
-        # advertises a faster engine than runs: models the fast paths
-        # can't honor take the plain handler, and sharded runs of an
-        # explicit megakernel keep the XLA pump (pallas_call under
-        # shard_map untested)
+        # effective_engine), mirroring run_round's own substitution so
+        # the start log never advertises a faster engine than runs:
+        # models the pump can't honor take the plain handler
         self.engine = effective_engine(cfg)
         if not model_pump_capable(model):
             self.engine = "plain"
-        elif n > 1 and self.engine == "megakernel":
-            self.engine = "pump"
         if n > 1:
             from jax.sharding import Mesh
 
@@ -133,7 +128,7 @@ class TpuScheduler:
         chunk-boundary states (runtime/checkpoint.py); `recovery` (a
         RecoveryPolicy) turns CapacityError into rollback-and-regrow, and
         with one a compile/trace failure of the selected engine walks the
-        fallback ladder (megakernel → pump → plain, bit-identical
+        fallback ladder (pump → plain, bit-identical
         results) instead of failing the run. `recovery=None` — what
         --no-recover and every programmatic caller that passes no policy
         get — is fail-fast for both: the first

@@ -712,7 +712,7 @@ class SweepService:
         from shadow_tpu.runtime.checkpoint import (
             CheckpointManager,
             load_checkpoint,
-            peek_checkpoint_meta,
+            resume_engine_cfg,
         )
         from shadow_tpu.runtime.ensemble import EnsembleRunner
         from shadow_tpu.runtime.recovery import RecoveryPolicy
@@ -728,16 +728,7 @@ class SweepService:
         # the same for --resume)
         ecfg = world.ecfg
         if batch.resume_ckpt is not None:
-            meta = peek_checkpoint_meta(batch.resume_ckpt)
-            overrides = {}
-            qc, oc = meta.get("queue_capacity"), meta.get("outbox_capacity")
-            if qc and oc:
-                overrides.update(queue_capacity=qc, outbox_capacity=oc)
-            for knob in ("deliver_lanes", "a2a_capacity", "pool_capacity"):
-                if knob in meta:
-                    overrides[knob] = meta[knob]
-            if any(overrides.get(k) != getattr(ecfg, k) for k in overrides):
-                ecfg = dataclasses.replace(ecfg, **overrides)
+            ecfg = resume_engine_cfg(batch.resume_ckpt, ecfg)
 
         rows_map = {j.name: r for r, j in enumerate(batch.jobs)}
 

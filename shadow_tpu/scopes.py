@@ -24,7 +24,7 @@ Every name is a single path component; nested scopes give paths:
     drain                   run_round's while loop: cond, lanes_live,
                             compaction gather / scatter
     drain/handle            handle_one_iteration
-    drain/pump              pump_stage / megakernel_stage
+    drain/pump              pump_stage
     drain/handle/netstack   netstack.py as the handler calls it: the down
                             relay's token bucket and CoDel at ingress,
                             the up relay's token buckets at emit time
@@ -37,13 +37,12 @@ Every name is a single path component; nested scopes give paths:
     drain/handle/push_self  equeue.push_self_lanes: the [H, queue] lane
     drain/pump/push_self    merges of the handler and the pump
     exchange                flush_outbox: flatten, bucket, clear
-    exchange/collective     all_to_all / all_gather / the ppermute ring
-                            (sharded only)
-    exchange/land           equeue.push_many_sorted / push_many_segment:
-                            destination sort, the runs' bounds, row
-                            gather into sorted order, the [H, queue]
-                            pull gather and the one where pass that
-                            merges it (no push_self under land)
+    exchange/collective     all_to_all / all_gather (sharded only)
+    exchange/land           equeue.push_many_sorted: destination sort,
+                            the runs' bounds, row gather into sorted
+                            order, the [H, queue] pull gather and the
+                            one where pass that merges it (no push_self
+                            under land)
     probe                   state_probe and the tracker plane's per-round
                             high-water marks
 """

@@ -55,8 +55,7 @@ tracker.c:407-430, and the worker-local SimStats fold, sim_stats.rs):
   * a stats fold (`stats_dict`) for sim-stats.json: per-kind event
     counts, drop reasons, byte classes, high-water marks, round
     live/idle split, and per-phase wall-time percentiles — the
-    breakdown every perf round is tuned against (bench.py publishes the
-    same fold per trial).
+    breakdown a run's wall time is read from.
 """
 
 from __future__ import annotations
@@ -73,7 +72,7 @@ RUN_SPAN = "run"  # the root span of one driver entry
 
 # Span-list bound: beyond this many recorded events new spans fold into
 # the running per-phase totals only (the Chrome trace and percentiles
-# cover the first _MAX_EVENTS spans). Keeps a million-chunk bench run at
+# cover the first _MAX_EVENTS spans). Keeps a million-chunk run at
 # bounded memory while every progress line still shows true totals.
 _MAX_EVENTS = 200_000
 
@@ -222,8 +221,7 @@ class Tracker:
 
     def spans(self, name: "str | None" = None) -> "list[dict]":
         """Recorded complete-spans (optionally filtered by name), in
-        record order — tools/profile_kernels.py reads dispatch timing
-        from these instead of keeping its own stopwatch."""
+        record order."""
         with self._lock:
             evs = list(self.events)
         return [
@@ -315,7 +313,7 @@ class Tracker:
 
     def phase_totals(self) -> dict:
         """{span name: total wall seconds} — the compact per-phase view
-        bench.py prints on every progress line. Served from the running
+        of the flight recorder's post-mortem. Served from the running
         totals (O(phases), not O(spans)): emitting it once per chunk in
         a million-chunk dispatch loop costs nothing."""
         with self._lock:

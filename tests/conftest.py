@@ -112,11 +112,6 @@ SLOW_TESTS = {
     "test_tracker_counters_cross_engine_pump_tgen",
     "test_onion_example_replicas_aggregate",
     "test_netstack_unlimited_is_noop",
-    # ~103s: the forced-CPU bench harness subprocess canary — the
-    # biggest single quick-tier item after the rebalance and a harness
-    # smoke rather than a correctness pin; the capped rerun still
-    # landed only ~30s under the 870s wall, so it funds the margin
-    "test_bench_cpu_rung_publishes_non_null",
     "test_streams_cycle",
     "test_streams_deterministic",
     "test_system_curl_run_twice_strace_identical",
@@ -131,19 +126,10 @@ SLOW_TESTS = {
     # the adaptive-window equivalence MATRIX (engines x tgen, sharded,
     # ensemble) pays an XLA compile per cell (~40-90 s each on this box);
     # the quick tier keeps the tentpole pins (phold leaf-exactness +
-    # iteration reduction, checkpoint roundtrip, the bench smoke)
+    # iteration reduction, checkpoint roundtrip)
     "test_adaptive_matches_fixed_tgen_engines",
     "test_adaptive_matches_fixed_sharded",
     "test_adaptive_matches_fixed_ensemble_slices",
-    # Event-exchange v2 (tests/test_exchange.py): the quick tier keeps
-    # one dense-vs-segment phold smoke per engine plus the pure
-    # pool/ergonomics pins (~50s); the full 6-model x 3-engine matrix
-    # (an XLA compile per cell), the ensemble/mesh slice cells, and the
-    # segment chaos-recovery pin run in the full tier
-    "test_segment_matches_dense_matrix",
-    "test_ensemble_segment_slices_exact",
-    "test_mesh_segment_slices_match_single_dense",
-    "test_segment_chaos_capacity_recovers_leaf_exact",
     # ~25 s; the quick tier already runs the real checkpoint machinery
     # with adaptive windows on by default (tests/test_robustness.py)
     "test_adaptive_checkpoint_roundtrip_leaf_exact",

@@ -4,7 +4,7 @@ the LBTS bound — must be LEAF-IDENTICAL to fixed-width rounds: the
 delivery clamp max(t + lat, window_end) provably never binds under the
 bound, so widening the window regroups rounds without moving a single
 event, draw, or byte. Pinned here on phold + tgen across
-plain/pump/megakernel, sharded, ensemble slices, and through a
+plain/pump, sharded, ensemble slices, and through a
 checkpoint roundtrip; plus the perf pin — a sparse-in-time scenario
 drains in provably fewer iterations/rounds.
 
@@ -218,7 +218,7 @@ def test_adaptive_checkpoint_roundtrip_leaf_exact():
         )
 
 
-@pytest.mark.parametrize("engine,pump_k", [("plain", 0), ("pump", 4), ("megakernel", 4)])
+@pytest.mark.parametrize("engine,pump_k", [("plain", 0), ("pump", 4)])
 def test_adaptive_matches_fixed_tgen_engines(engine, pump_k):
     """tgen (TCP + shaping + loss) under every engine: adaptive must
     equal the fixed-width PLAIN reference after canonicalization — one

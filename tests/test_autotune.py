@@ -1,8 +1,6 @@
 """Unit tests for the compile-budget autotuner's planner
 (runtime/autotune.py) — the pure decision logic, exercised through the
-persisted probe cache so no XLA compile is paid here. The end-to-end
-pin (a real bench child whose requested rounds_per_chunk is corrected
-by a real probe) lives in tests/test_bench_smoke.py."""
+persisted probe cache so no XLA compile is paid here."""
 
 import json
 

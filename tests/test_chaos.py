@@ -157,7 +157,7 @@ def test_chaos_config_section_validates_values_eagerly():
     assert ok.faults == [{"kind": "stall", "at": "2", "stall_s": "0.5"}]
 
 
-# ---- the engine fallback ladder (megakernel -> pump -> plain) -----------
+# ---- the engine fallback ladder (pump -> plain) -------------------------
 
 
 def _ecfg(engine, pump_k=3):
@@ -168,8 +168,8 @@ def _ecfg(engine, pump_k=3):
 
 
 def test_next_engine_cfg_walks_the_ladder():
-    assert next_engine_cfg(_ecfg("megakernel")).engine == "pump"
-    assert next_engine_cfg(_ecfg("pump")).engine == "plain"
+    down = next_engine_cfg(_ecfg("pump"))
+    assert (down.engine, down.pump_k) == ("plain", 3)  # only the engine changes
     assert next_engine_cfg(_ecfg("plain")) is None
     # "auto" resolves to what it would actually run before stepping down
     assert next_engine_cfg(_ecfg("auto", pump_k=3)).engine == "plain"
@@ -185,12 +185,10 @@ def test_engine_ladder_falls_to_plain_then_fails_structured():
             raise EngineCompileError(cfg.engine, RuntimeError("boom"))
         return "done"
 
-    result, fallbacks = run_with_engine_ladder(_ecfg("megakernel"), flaky)
+    result, fallbacks = run_with_engine_ladder(_ecfg("pump"), flaky)
     assert result == "done"
-    assert attempts == ["megakernel", "pump", "plain"]
-    assert [(f["from"], f["to"]) for f in fallbacks] == [
-        ("megakernel", "pump"), ("pump", "plain"),
-    ]
+    assert attempts == ["pump", "plain"]
+    assert [(f["from"], f["to"]) for f in fallbacks] == [("pump", "plain")]
     assert "boom" in fallbacks[0]["reason"]
 
     def hopeless(cfg):

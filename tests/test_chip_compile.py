@@ -93,23 +93,34 @@ def _compile(fn, *args):
     return compiled
 
 
-def test_megakernel_launch_is_refused(world, one_chip):
-    """The Pallas megakernel has only ever run interpreted; Mosaic refuses
-    its int64 carry before looking at the body. `auto` therefore never
-    selects it (engine/round.py effective_engine). The day this test
-    fails because the launch COMPILES, revisit that rule."""
-    from shadow_tpu.engine import megakernel, pump
+def test_pump_microstep_compiles(one_chip):
+    """The fast path beside the plain handler: one pump microstep on a
+    PumpCarry, as tools/compile_for_chip.py's piece of that name builds it,
+    at a small shape (32 hosts of the tgen-10k world, queue 16, outbox 8):
+    the chip's compiler takes every operation the pump is made of."""
+    import os
+    import sys
 
-    w, state = world
-    cfg = dataclasses.replace(w.ecfg, engine="megakernel", pump_k=8)
+    from shadow_tpu.engine import pump
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "tools"))
+    from compile_for_chip import build_world, state_shapes
+
+    _, w = build_world(os.path.join(root, "examples", "tgen-10k", "shadow.yaml"), 32)
+    cfg = dataclasses.replace(
+        w.ecfg, engine="pump", pump_k=1, queue_capacity=16, outbox_capacity=8
+    )
+    assert rnd.model_pump_capable(w.model)
     carry = jax.eval_shape(
-        lambda s, tb: pump.pump_carry_init(s, w.model, tb, cfg), state, w.tables
+        lambda s, tb: pump.pump_carry_init(s, w.model, tb, cfg),
+        state_shapes(w, cfg), w.tables,
     )
     t64 = jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
-    with pytest.raises(ZeroDivisionError, match="modulo by zero"):
-        jax.jit(
-            lambda c, we, tb: megakernel._launch(c, we, w.model, tb, cfg, interpret=False)
-        ).lower(_on(carry, one_chip), t64, _on(w.tables, one_chip))
+    _compile(
+        lambda c, we, tb: pump.pump_microstep(c, we, w.model, tb, cfg),
+        _on(carry, one_chip), t64, _on(w.tables, one_chip),
+    )
 
 
 def test_next_window_end_compiles(world, one_chip):

@@ -1,6 +1,6 @@
 """The scalar TCP oracle vs the device engine on the *flagship* tgen
-workload (the exact model bench.py measures): repeated request/response
-streams with port recycling, slot reuse, loss, shaping + CoDel, TIMEWAIT
+workload (the model of the benchmark's tgen-10k cells): repeated
+request/response streams with port recycling, slot reuse, loss, shaping + CoDel, TIMEWAIT
 turnover. Two independent implementations of the same specification must
 agree bit-for-bit — every TCP state field, every model counter, every
 leftover queue entry (round-2 verdict item 3; reference analogue:

@@ -15,8 +15,7 @@ persistent poison-job capacity fault. The acceptance bar:
     a crash;
   * the persistent compile cache amortizes across restarts (the
     restarted daemons pay near-zero recompiles);
-  * jobs/hour and cache-hit-rate are published (the numbers bench
-    mirrors under detail.service).
+  * jobs/hour and cache-hit-rate are published.
 
 A second scenario soaks the FLEET contract (docs/service.md "Running a
 fleet"): two daemons on one spool, one SIGKILLed mid-batch — the

@@ -14,8 +14,8 @@ Contracts pinned here:
   * one replica's capacity blowup raises a CapacityError naming the
     replica, and rollback-and-regrow recovers the WHOLE batch to a
     final state leaf-exact vs starting with the larger capacity;
-  * engine="megakernel" resolves to the (bit-identical) pump under the
-    ensemble vmap.
+  * the ensemble resolution arms the done-mask and changes nothing else
+    of the config.
 """
 
 import dataclasses
@@ -241,18 +241,18 @@ def test_ensemble_recovery_regrows_whole_batch():
     _assert_leaves_exact(final, ens_big)
 
 
-def test_megakernel_falls_back_to_pump_under_vmap():
+def test_ensemble_cfg_keeps_the_engine_asked_for():
     cfg, _, _, _ = _phold_world()
-    mk = dataclasses.replace(cfg, engine="megakernel", pump_k=0)
-    resolved = ensemble_engine_cfg(mk)
-    assert resolved.engine == "pump" and resolved.pump_k == 8
-    assert resolved.ensemble
-    mk2 = dataclasses.replace(cfg, engine="megakernel", pump_k=4)
-    assert ensemble_engine_cfg(mk2).pump_k == 4
-    # non-megakernel engines pass through except for the done-mask flag
-    plain = ensemble_engine_cfg(cfg)
-    assert plain.ensemble and plain.engine == cfg.engine
-    assert dataclasses.replace(plain, ensemble=False) == cfg
+    for asked in (
+        dataclasses.replace(cfg, engine="plain", pump_k=4, exchange="all_gather"),
+        dataclasses.replace(cfg, engine="pump", pump_k=4),
+        cfg,
+    ):
+        assert not asked.ensemble
+        resolved = ensemble_engine_cfg(asked)
+        # the done-mask is armed, and engine, pump_k, exchange are as given
+        assert resolved.ensemble
+        assert dataclasses.replace(resolved, ensemble=False) == asked
 
 
 def test_run_ensemble_until_rejects_single_state():

@@ -304,7 +304,7 @@ def test_push_many_sorted_equals_grid_reference(case):
 
 def test_push_many_at_time_max_counted_on_row_zero():
     """A batched push at TIME_MAX never takes a slot: it is masked before
-    the sort and counted on overflow row 0 (push_many_segment's rule);
+    the sort and counted on overflow row 0;
     the other entries land as if it had not been sent."""
     H, Q = 3, 4
     dst, valid, time, tie, kind, data, aux = _batch(np.random.default_rng(5), 6, H, p_valid=1.0)

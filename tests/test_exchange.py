@@ -1,62 +1,35 @@
-"""Event-exchange v2 (engine/round.py _flush_segment + equeue.
-push_many_segment): the dense-vs-segment equivalence matrix and the
-compact-pool behavior pins.
+"""The round-boundary exchange (engine/round.py flush_outbox): all_to_all
+or all_gather across shards, landed by pull (equeue.push_many_sorted).
 
 Contracts pinned here:
 
-  * exchange="segment" is trajectory- and stat-leaf-exact vs the dense
-    landing family ("dense" == "all_to_all", state.py trace_static_cfg)
-    on every registered model across every engine; queue grids compare
-    as live content in canonical (time, tie) pop order — slot PLACEMENT
-    is the one fact the segment landing lays out differently (free-slot
-    rank order vs the dense [H, deliver_lanes] grid), and pop order is
-    key-driven either way;
-  * a bursty fan-in round that overflows a narrow dense deliver-lanes
-    grid lands in full under the segment pool (the per-row capacity
-    check replaces the per-lane one) and stays equal to a roomy dense
-    landing;
-  * pool_capacity truncation is LOUD (outbox overflow lane +
-    CapacityError) and the error names the knob, the exchange-pool
-    occupancy high-water, and the top destination hosts;
-  * segment ensemble slices are leaf-exact vs standalone segment runs
-    and pop-order-equal vs dense singles; the 2-D mesh plane runs the
-    ppermute-ring segment exchange unpinned under its replica vmap
-    (test_mesh pins the cfg seam; the slice equivalence lives here);
-  * an injected chaos capacity fault under exchange="segment" takes the
-    standard rollback-and-regrow path and recovers leaf-exact.
-
-Quick tier: one dense-vs-segment phold smoke per engine plus the pure
-pool/validation pins; the full model x engine matrix, the sharded /
-ensemble / mesh cells, and the chaos pin run in the full tier
-(tests/conftest.py SLOW_TESTS).
+  * the two exchange modes and the single-device run are trajectory- and
+    stat-leaf-exact; queue grids compare as live content in canonical
+    (time, tie) pop order — slot PLACEMENT follows arrival order, which
+    the modes lay out differently, and pop order is key-driven either way;
+  * a bursty fan-in lands up to the destination row's free room, the
+    earliest keys first; what does not fit is counted on that row and
+    check_capacity names it — never a silent drop;
+  * the choices this engine once had and retired (engine "megakernel",
+    exchange "segment" / "dense", pool_capacity, megakernel_tile) are
+    refused with a message naming the values left.
 """
 
 import dataclasses
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
-from test_mesh import _assert_mesh_slice_exact, _single_run
-from test_overlay import _onion, _world as _overlay_world
-from test_pipeline import _phold_world
-from test_pump import _normalize
+from test_mesh import _assert_mesh_slice_exact
+from test_overlay import _world as _overlay_world
 
 from shadow_tpu import equeue
-from shadow_tpu.engine import EngineConfig, init_state
-from shadow_tpu.engine.ensemble import (
-    init_ensemble_state,
-    replica_seeds,
-    replica_slice,
-    run_ensemble_until,
-)
-from shadow_tpu.engine.mesh import (
-    MeshPlan,
-    init_mesh_state,
-    replica_slice as mesh_replica_slice,
-    run_mesh_until,
-)
+from shadow_tpu.config import load_config_str
+from shadow_tpu.engine import EngineConfig, ShardedRunner, init_state
 from shadow_tpu.engine.round import (
     CapacityError,
     bootstrap,
@@ -65,113 +38,43 @@ from shadow_tpu.engine.round import (
     flush_outbox,
     run_until,
 )
-from shadow_tpu.models.bulk import BulkTcpModel
-from shadow_tpu.models.overlay import CdnModel, GossipModel
+from shadow_tpu.engine.sharded import AXIS
 from shadow_tpu.models.phold import PholdModel
-from shadow_tpu.models.tgen import TgenModel
-from shadow_tpu.netstack import bw_bits_per_sec_to_refill
 from shadow_tpu.simtime import NS_PER_MS
 
-_ENGINES = [("plain", 0), ("pump", 3), ("megakernel", 3)]
+REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
-def _run_mode(model, cfg, tables, bw, engine, k, mode, end):
-    c = dataclasses.replace(cfg, engine=engine, pump_k=k, exchange=mode)
-    st = init_state(
-        c, model.init(), tx_bytes_per_interval=bw, rx_bytes_per_interval=bw
-    )
-    st = bootstrap(st, model, c)
-    st = run_until(st, end, model, tables, c, rounds_per_chunk=8)
-    check_capacity(st)
-    return st
-
-
-def _assert_pop_order_equal(a, b, what=""):
-    """Dense-vs-segment equality: every leaf exact after the queue rows
-    are canonicalized to (time, tie) pop order with tombstone payloads
-    zeroed (test_pump._normalize — the established cross-engine idiom).
-    Slot layout is the ONLY deviation the segment landing is allowed."""
-    a, b = _normalize(a), _normalize(b)
-    fa = jax.tree_util.tree_leaves_with_path(a)
-    fb = jax.tree.leaves(b)
-    assert len(fa) == len(fb)
-    for (path, la), lb in zip(fa, fb):
-        ks = jax.tree_util.keystr(path)
-        assert jnp.array_equal(la, lb), f"mismatch{what} at {ks}"
-
-
-@pytest.mark.parametrize("engine,k", _ENGINES, ids=[e for e, _ in _ENGINES])
-def test_segment_matches_dense_smoke(engine, k):
-    """Quick-tier acceptance pin: exchange='segment' equals the dense
-    landing on phold for every engine (the 'dense' alias is exercised on
-    purpose — it must select the all_to_all trace)."""
+@pytest.mark.parametrize("engine,k", [("plain", 0), ("pump", 3)], ids=["plain", "pump"])
+def test_all_to_all_matches_all_gather_and_single_smoke(engine, k):
+    """Quick-tier acceptance pin: 12 phold hosts block-sharded over 4
+    virtual devices, under either exchange mode, equal the one-device run
+    in every leaf (queues in pop order)."""
+    assert jax.device_count() >= 4
     model = PholdModel(
         num_hosts=12, min_delay_ns=1 * NS_PER_MS, max_delay_ns=8 * NS_PER_MS
     )
     cfg, tables = _overlay_world(model, seed=5)
+    cfg = dataclasses.replace(cfg, engine=engine, pump_k=k)
     end = 40 * NS_PER_MS
-    dense = _run_mode(model, cfg, tables, None, engine, k, "dense", end)
-    seg = _run_mode(model, cfg, tables, None, engine, k, "segment", end)
-    assert int(dense.events_handled.sum()) > 0
-    _assert_pop_order_equal(dense, seg, f" ({engine} dense vs segment)")
+    st0 = bootstrap(init_state(cfg, model.init()), model, cfg)
+    single = run_until(st0, end, model, tables, cfg, rounds_per_chunk=8)
+    check_capacity(single)
+    assert int(single.events_handled.sum()) > 0
+    assert int(single.packets_sent.sum()) > 0  # the exchange carried traffic
 
-
-def _matrix_world(name):
-    """One small world per registered model (the six names the
-    acceptance matrix runs; registry.py _REGISTRY)."""
-    if name == "phold":
-        model = PholdModel(
-            num_hosts=12, min_delay_ns=1 * NS_PER_MS, max_delay_ns=8 * NS_PER_MS
+    mesh = Mesh(np.array(jax.devices()[:4]), (AXIS,))
+    for mode in ("all_to_all", "all_gather"):
+        c = dataclasses.replace(cfg, exchange=mode)
+        sharded = ShardedRunner(mesh, model, tables, c, rounds_per_chunk=8).run_until(
+            st0, end
         )
-        cfg, tables = _overlay_world(model, seed=5)
-        return model, cfg, tables, None
-    if name == "bulk-tcp":
-        model = BulkTcpModel(num_hosts=12, num_pairs=3, total_bytes=20_000)
-    elif name == "tgen":
-        model = TgenModel(
-            num_hosts=12, num_clients=6, num_servers=6, resp_bytes=20_000,
-            pause_ns=30 * NS_PER_MS,
-        )
-    elif name == "onion":
-        model = _onion()
-    elif name == "cdn":
-        model = CdnModel(num_hosts=12, num_mids=1, num_leaves=2, objects=32)
-    elif name == "gossip":
-        model = GossipModel(
-            num_hosts=12, view_size=4, fanout=2, churn_ppm=100_000
-        )
-    else:  # pragma: no cover - parametrize list is closed
-        raise AssertionError(name)
-    cfg, tables = _overlay_world(model, seed=5)
-    bw = None
-    if name in ("bulk-tcp", "tgen"):
-        cfg = dataclasses.replace(cfg, use_netstack=True, deliver_lanes=48)
-        bw = bw_bits_per_sec_to_refill(50_000_000)
-    return model, cfg, tables, bw
+        _assert_mesh_slice_exact(sharded, single, f" ({engine} {mode} vs one device)")
 
 
-@pytest.mark.parametrize("engine,k", _ENGINES, ids=[e for e, _ in _ENGINES])
-@pytest.mark.parametrize(
-    "name", ["phold", "bulk-tcp", "tgen", "onion", "cdn", "gossip"]
-)
-def test_segment_matches_dense_matrix(name, engine, k):
-    """The full acceptance matrix: all six registered models x all three
-    engines, dense vs segment pop-order-exact."""
-    model, cfg, tables, bw = _matrix_world(name)
-    end = 150 * NS_PER_MS
-    dense = _run_mode(model, cfg, tables, bw, engine, k, "dense", end)
-    seg = _run_mode(model, cfg, tables, bw, engine, k, "segment", end)
-    assert int(dense.events_handled.sum()) > 0
-    assert int(dense.packets_sent.sum()) > 0  # exchange actually exercised
-    _assert_pop_order_equal(
-        dense, seg, f" ({name}/{engine} dense vs segment)"
-    )
-
-
-def _bursty_state(cfg, model, tables):
+def _bursty_state(cfg, model):
     """A deliberately bursty flush: every host stages 2 packets, ALL to
-    host 0 — 16 deliveries into one row, more than a narrow dense
-    deliver-lanes grid can land but well inside the queue row."""
+    host 0 — 16 deliveries into one row, keys rising in staging order."""
     st = init_state(cfg, model.init())  # NO bootstrap: queue stays empty
     h, o = st.outbox.valid.shape
     valid = np.zeros((h, o), bool)
@@ -193,167 +96,69 @@ def _bursty_state(cfg, model, tables):
     return st.replace(outbox=ob)
 
 
-def test_bursty_fanin_overflows_lane_but_fits_pool():
-    """The satellite pin: the same staged burst overflows a
-    deliver_lanes=4 dense grid (loudly) but lands in full under the
-    segment pool, equal to a roomy dense landing in pop order."""
+def test_bursty_fanin_lands_to_queue_room_then_overflows_loudly():
+    """A 16-arrival burst on one row lands in full where the row has 16
+    free slots; with 15 the 15 earliest keys land, the one more is counted
+    on the destination's own row, and check_capacity names the queue and
+    the host."""
     model = PholdModel(num_hosts=8)
-    cfg, tables = _overlay_world(
-        model, seed=3, queue_capacity=64, outbox_capacity=4
-    )
+    keys = [(10 * NS_PER_MS + n, n + 1) for n in range(16)]
 
-    narrow = dataclasses.replace(cfg, deliver_lanes=4, exchange="dense")
-    st_n = flush_outbox(_bursty_state(narrow, model, tables), None, narrow)
-    assert int(st_n.queue.count[0]) == 4  # grid-bounded landing
-    dropped = int(st_n.queue.overflow.sum()) + int(st_n.outbox.overflow.sum())
-    assert dropped == 12
+    def landed(queue_capacity):
+        cfg, _tables = _overlay_world(
+            model, seed=3, queue_capacity=queue_capacity, outbox_capacity=4
+        )
+        st = flush_outbox(_bursty_state(cfg, model), None, cfg)
+        assert not bool(st.outbox.valid.any())  # the flush clears the outbox
+        events = equeue.debug_sorted_events(st.queue, 0)
+        return st, [(t, tie) for t, tie, _kind, _data in events]
+
+    st, got = landed(16)
+    check_capacity(st)  # exactly the row's room: nothing dropped
+    assert got == keys
+    assert int(st.queue.count[0]) == 16 and int(st.queue.count[1:].sum()) == 0
+
+    st, got = landed(15)
+    assert got == keys[:15]
+    assert np.asarray(st.queue.overflow).tolist() == [1] + [0] * 7
+    assert int(st.outbox.overflow.sum()) == 0
     with pytest.raises(CapacityError) as ei:
-        check_capacity(st_n)
-    assert "pool_capacity" in str(ei.value)  # the message names the knob
-    topk = capacity_topk(st_n)
+        check_capacity(st)
+    assert "saturated: queue " in str(ei.value)
+    assert ei.value.queue_overflow == 1 and ei.value.outbox_overflow == 0
+    topk = capacity_topk(st)
     assert topk.startswith("top destination hosts by landed events")
     assert "host 0" in topk
 
-    seg = dataclasses.replace(cfg, deliver_lanes=4, exchange="segment")
-    st_s = flush_outbox(_bursty_state(seg, model, tables), None, seg)
-    check_capacity(st_s)  # no drops: capacity is per ROW, not per lane
-    assert int(st_s.queue.count[0]) == 16
-    assert int(st_s.queue.overflow.sum()) == 0
 
-    roomy = dataclasses.replace(cfg, exchange="dense")  # full-width grid
-    st_r = flush_outbox(_bursty_state(roomy, model, tables), None, roomy)
-    for h in range(cfg.num_hosts):
-        assert equeue.debug_sorted_events(
-            st_s.queue, h
-        ) == equeue.debug_sorted_events(st_r.queue, h), f"host {h}"
-
-
-def test_pool_capacity_truncates_loudly():
-    """pool_capacity below the round's traffic drops the tail into the
-    outbox overflow lane and check_capacity reports the pool occupancy
-    high-water plus the sizing advice — never a silent truncation."""
-    model = PholdModel(num_hosts=8)
-    cfg, tables = _overlay_world(
-        model, seed=3, queue_capacity=64, outbox_capacity=4
+def _yaml_engine(name):
+    text = (REPO / "examples/phold/shadow.yaml").read_text()
+    assert "  scheduler: tpu\n" in text
+    return load_config_str(
+        text.replace("  scheduler: tpu\n", f"  scheduler: tpu\n  engine: {name}\n")
     )
-    small = dataclasses.replace(cfg, exchange="segment", pool_capacity=6)
-    st = flush_outbox(_bursty_state(small, model, tables), None, small)
-    assert int(st.queue.count[0]) == 6
-    assert int(st.outbox.overflow.sum()) == 10
-    # the occupancy high-water rides the tracker plane into the message
-    st = st.replace(
-        tracker=st.tracker.replace(
-            exch_hwm=st.tracker.exch_hwm.at[0].set(jnp.int32(16))
-        )
-    )
-    with pytest.raises(CapacityError) as ei:
-        check_capacity(st)
-    msg = str(ei.value)
-    assert "exchange pool occupancy hwm=16 events/round" in msg
-    assert "pool_capacity" in msg and "0 = whole outbox" in msg
-    assert ei.value.exchange_hwm == 16
-    assert ei.value.outbox_overflow == 10
 
 
-def test_exchange_config_validation():
-    with pytest.raises(ValueError, match="exchange"):
-        EngineConfig(num_hosts=4, exchange="bogus")
-    with pytest.raises(ValueError, match="pool_capacity"):
-        EngineConfig(num_hosts=4, pool_capacity=-1)
-    # "dense" is a pure alias of all_to_all: same compile-cache key
-    from shadow_tpu.engine.state import trace_static_cfg
-
-    a = trace_static_cfg(EngineConfig(num_hosts=4, exchange="dense"))
-    b = trace_static_cfg(EngineConfig(num_hosts=4, exchange="all_to_all"))
-    assert a == b
-    s = trace_static_cfg(EngineConfig(num_hosts=4, exchange="segment"))
-    assert s.exchange == "segment"  # distinct trace family
-
-
-def test_ensemble_segment_slices_exact():
-    """Segment ensemble slices equal standalone segment runs leaf-exact
-    (same mode -> identical slot layout too), and equal dense singles in
-    canonical pop order (cross mode)."""
-    cfg, model, tables, _ = _phold_world(num_hosts=8)
-    cfg = dataclasses.replace(cfg, tracker=True, exchange="segment")
-    end = 60 * NS_PER_MS
-    stride = 3
-    ens = run_ensemble_until(
-        init_ensemble_state(cfg, model, 2, stride), end, model, tables, cfg,
-        rounds_per_chunk=8,
-    )
-    assert int(ens.events_handled.sum()) > 0
-    for r, seed in enumerate(replica_seeds(cfg, 2, stride)):
-        sl = replica_slice(ens, r)
-        single = _single_run(cfg, model, tables, seed, end, 8)
-        fa = jax.tree_util.tree_leaves_with_path(sl)
-        for (path, la), lb in zip(fa, jax.tree.leaves(single)):
-            assert jnp.array_equal(la, lb), (
-                f"replica {r} mismatch at {jax.tree_util.keystr(path)}"
-            )
-        dense = _single_run(
-            dataclasses.replace(cfg, exchange="dense"), model, tables, seed,
-            end, 8,
-        )
-        _assert_pop_order_equal(dense, single, f" (replica {r} vs dense)")
-
-
-def test_mesh_segment_slices_match_single_dense():
-    """The mesh cell of the acceptance bar: a 2x4 Mesh(replica, hosts)
-    run with the ppermute-ring segment exchange — which, unlike
-    all_to_all, batches under the replica vmap (engine/round.py
-    _ring_exchange) — matches single-device DENSE runs slice by slice."""
-    assert jax.device_count() == 8
-    cfg, model, tables, _ = _phold_world(num_hosts=8)
-    cfg = dataclasses.replace(cfg, tracker=True, exchange="segment")
-    end = 40 * NS_PER_MS
-    stride = 7
-    plan = MeshPlan(replicas=2, shards=4, rows=2)
-    ens = run_mesh_until(
-        init_mesh_state(cfg, model, plan, stride), end, model, tables, cfg,
-        plan, rounds_per_chunk=4,
-    )
-    assert int(ens.events_handled.sum()) > 0
-    for r, seed in enumerate(replica_seeds(cfg, 2, stride)):
-        single = _single_run(
-            dataclasses.replace(cfg, exchange="dense"), model, tables, seed,
-            end, 4,
-        )
-        _assert_mesh_slice_exact(
-            mesh_replica_slice(ens, r), single, f" (segment replica {r})"
-        )
-
-
-def test_segment_chaos_capacity_recovers_leaf_exact():
-    """Chaos cell of the acceptance bar: an injected capacity fault on
-    the onion scenario running exchange='segment' rolls back, regrows,
-    replays, and finishes leaf-exact vs a fault-free segment run started
-    at the regrown capacity (mirror of test_overlay's dense pin)."""
-    from shadow_tpu.runtime import chaos
-    from shadow_tpu.runtime.chaos import FaultPlan
-    from shadow_tpu.runtime.recovery import RecoveryPolicy, run_until_recovering
-
-    model = _onion(h=10, clients=4)
-    cfg, tables = _overlay_world(model, queue_capacity=96, outbox_capacity=48)
-    cfg = dataclasses.replace(cfg, exchange="segment")
-    end = 200 * NS_PER_MS
-    st0 = bootstrap(init_state(cfg, model.init()), model, cfg)
-    plan = FaultPlan(faults=[{"kind": "capacity", "at": 1}])
-    with chaos.installed(plan):
-        final, recoveries = run_until_recovering(
-            st0, end, model, tables, cfg, rounds_per_chunk=4,
-            policy=RecoveryPolicy(max_recoveries=2, snapshot_interval_chunks=2),
-        )
-    assert [r["kind"] for r in recoveries] == ["capacity"]
-    grown = final.queue.capacity
-    assert grown == 2 * cfg.queue_capacity
-
-    cfg2 = dataclasses.replace(cfg, queue_capacity=grown)
-    st2 = bootstrap(init_state(cfg2, model.init()), model, cfg2)
-    reference = run_until(st2, end, model, tables, cfg2, rounds_per_chunk=4)
-    fa = jax.tree_util.tree_leaves_with_path(reference)
-    for (path, la), lb in zip(fa, jax.tree.leaves(final)):
-        assert jnp.array_equal(la, lb), (
-            f"recovered mismatch at {jax.tree_util.keystr(path)}"
-        )
-    assert int(final.model.streams_done.sum()) > 0
+@pytest.mark.parametrize(
+    "build,left",
+    [
+        (lambda: _yaml_engine("megakernel"), ("auto", "plain", "pump")),
+        (lambda: EngineConfig(num_hosts=4, engine="megakernel"), ("auto", "plain", "pump")),
+        (lambda: EngineConfig(num_hosts=4, exchange="segment"), ("all_to_all", "all_gather")),
+        (lambda: EngineConfig(num_hosts=4, exchange="dense"), ("all_to_all", "all_gather")),
+    ],
+    ids=["yaml-engine-megakernel", "cfg-engine-megakernel", "cfg-exchange-segment",
+         "cfg-exchange-dense"],
+)
+def test_retired_choices_are_refused(build, left):
+    """The front door and EngineConfig refuse each retired value as they do
+    any unknown name, and the message names the values left; the retired
+    knobs are no longer fields."""
+    with pytest.raises(ValueError) as ei:
+        build()
+    for name in left:
+        assert repr(name) in str(ei.value)
+    assert _yaml_engine("pump").experimental.engine == "pump"
+    for retired in ({"pool_capacity": 1}, {"megakernel_tile": 8}):
+        with pytest.raises(TypeError):
+            EngineConfig(num_hosts=4, **retired)

@@ -46,39 +46,23 @@ hosts:
 def test_auto_on_a_tpu_is_an_engine_the_chip_compiles(monkeypatch):
     """On a backend that calls itself `tpu`, auto is pump (pump_k > 0) or
     plain — the two whose chunk programs the chip's compiler accepted
-    (tools/compile_for_chip.py) — and never the megakernel it refuses."""
+    (tools/compile_for_chip.py)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = EngineConfig(num_hosts=8)
     assert effective_engine(cfg) == "plain"
     assert effective_engine(dataclasses.replace(cfg, pump_k=4)) == "pump"
     assert effective_engine(dataclasses.replace(cfg, ensemble=True, pump_k=4)) == "pump"
-    # explicit names always win, the megakernel's included
-    assert effective_engine(dataclasses.replace(cfg, engine="megakernel")) == "megakernel"
+    # explicit names always win
+    assert effective_engine(dataclasses.replace(cfg, engine="plain", pump_k=4)) == "plain"
 
 
-def test_unknown_backend_never_interprets_a_kernel(monkeypatch):
-    """A platform with another name gets the same XLA engines from auto,
-    and the megakernel refuses to choose a mode from its name: interpreted
-    on cpu, compiled on tpu, an error anywhere else."""
-    from shadow_tpu.engine import megakernel
-
+def test_unknown_backend_gets_the_same_engines(monkeypatch):
+    """A platform with another name gets the same XLA engines from auto:
+    the rule reads the config, never the backend's name."""
     monkeypatch.setattr(jax, "default_backend", lambda: "quux")
     cfg = EngineConfig(num_hosts=8, pump_k=8)
     assert effective_engine(cfg) == "pump"
     assert effective_engine(dataclasses.replace(cfg, pump_k=0)) == "plain"
-    with pytest.raises(ValueError, match="'quux' is neither"):
-        megakernel.megakernel_stage(None, None, None, None, cfg)
-
-    seen = {}
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(megakernel, "pump_carry_init", lambda *a: "carry")
-    monkeypatch.setattr(megakernel, "pump_carry_finish", lambda st, c, *a: c)
-    monkeypatch.setattr(
-        megakernel, "_launch",
-        lambda c, we, model, tables, cfg, interpret: seen.setdefault("interpret", interpret),
-    )
-    megakernel.megakernel_stage(None, None, None, None, cfg)
-    assert seen == {"interpret": False}
 
 
 # --- one compile cache, placeable from outside --------------------------

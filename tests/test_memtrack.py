@@ -57,7 +57,7 @@ def _manual_nbytes(tree) -> int:
 
 def test_price_state_exact_single_plane():
     cfg, _model, _tables, st0 = _phold_world()
-    report = memtrack.price_state(st0, cfg)
+    report = memtrack.price_state(st0)
     assert report["total_bytes"] == _manual_nbytes(st0) == tree_nbytes(st0)
     assert report["num_hosts"] == cfg.num_hosts
     assert report["replicas"] == 1
@@ -76,7 +76,7 @@ def test_price_state_exact_ensemble_and_mesh_planes():
 
     cfg, model, _tables, _st0 = _phold_world(num_hosts=4)
     ens = init_ensemble_state(cfg, model, 3, 1)
-    rep = memtrack.price_state(ens, cfg)
+    rep = memtrack.price_state(ens)
     assert rep["total_bytes"] == _manual_nbytes(ens)
     assert rep["replicas"] == 3
     assert rep["num_hosts"] == 4
@@ -84,7 +84,7 @@ def test_price_state_exact_ensemble_and_mesh_planes():
     # the mesh plane is BY CONSTRUCTION the ensemble pytree (mesh.py
     # init_mesh_state), so its pricing is the same exactness claim
     msh = init_mesh_state(cfg, model, MeshPlan(replicas=2, shards=2, rows=1))
-    rep = memtrack.price_state(msh, cfg)
+    rep = memtrack.price_state(msh)
     assert rep["total_bytes"] == _manual_nbytes(msh)
     assert rep["replicas"] == 2
 
@@ -116,7 +116,7 @@ def test_price_regrow_matches_grow_state():
 
 def test_max_hosts_for_budget_monotone():
     cfg, _model, _tables, st0 = _phold_world()
-    report = memtrack.price_state(st0, cfg)
+    report = memtrack.price_state(st0)
     budgets = [2**20, 2**24, 2**28, 2**32]
     fits = [memtrack.max_hosts_for_budget(report, b) for b in budgets]
     assert fits == sorted(fits)
@@ -126,7 +126,7 @@ def test_max_hosts_for_budget_monotone():
 
 def test_render_report_table():
     cfg, _model, _tables, st0 = _phold_world()
-    report = memtrack.price_state(st0, cfg)
+    report = memtrack.price_state(st0)
     text = memtrack.render_report(report, hbm_gb=16)
     assert "dominant grid:" in text
     assert "queue" in text and "outbox" in text

@@ -54,7 +54,7 @@ from shadow_tpu.simtime import NS_PER_MS, TIME_MAX
 def _canon_queue(q, h):
     """Host h's live queue content in canonical (time, tie) pop order,
     every recorded field included (debug_sorted_events plus the aux
-    channel). Slot ASSIGNMENT inside the dense grid is the one queue
+    channel). Slot ASSIGNMENT inside the queue grid is the one queue
     fact the sharded exchange lays out differently (same-time deliveries
     can land in swapped slots; tombstone payloads differ) — pop order is
     key-driven, so content-in-pop-order is the semantic contract, the
@@ -271,13 +271,10 @@ def test_mesh_plan_and_spec_validation():
     cfg, model, tables, _ = _phold_world(num_hosts=6)
     with pytest.raises(ValueError, match="divide evenly"):
         init_mesh_state(cfg, model, MeshPlan(replicas=2, shards=4, rows=2))
-    # the exchange pin: dense mesh cfgs trace the all_gather exchange
-    # (all_to_all has no vmap batching rule), but the segment exchange's
-    # ppermute ring DOES batch under vmap and passes through unpinned
+    # the exchange pin: mesh cfgs trace the all_gather exchange
+    # (all_to_all has no vmap batching rule)
+    assert cfg.exchange == "all_to_all"
     assert mesh_engine_cfg(cfg).exchange == "all_gather"
-    assert mesh_engine_cfg(
-        dataclasses.replace(cfg, exchange="segment")
-    ).exchange == "segment"
     assert mesh_engine_cfg(cfg).ensemble
 
 

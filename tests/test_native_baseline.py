@@ -1,9 +1,8 @@
 """The native C baseline (tools/native_baseline/tgen_pdes.c) must compute
 the *same simulation* as the Python scalar oracle (cpu_ref/tgen_ref.py) —
 same threefry draws, same TCP/shaping integer arithmetic, same window
-loop — so the published baseline rate (BENCH vs_baseline denominator) is
-provably measuring identical semantics at native speed, not a lighter
-workload (round-3 verdict Missing #3)."""
+loop — so a native rate measured with it is provably of identical
+semantics at native speed, not of a lighter workload."""
 
 import json
 import pathlib
@@ -78,13 +77,18 @@ def test_native_baseline_matches_python_oracle(nb_bin, tmp_path):
 
 
 def test_native_baseline_bench_topology_smoke(nb_bin, tmp_path):
-    """The bench-shaped world (32-node lossy graph, 100 Mbit shaping)
+    """The benchmark's world (examples/tgen-10k: 32-node lossy graph,
+    100 Mbit shaping), built through the front door and cut to 64 hosts,
     completes and reports a plausible native rate."""
-    import bench
+    import sys
 
-    cfg, model, tables, _st = bench._build(64)
-    c = _run_c(nb_bin, tmp_path, tables, 64, int(0.1 * NS_PER_SEC), cfg.seed,
-               model.resp_bytes, model.pause_ns, cfg.runahead_ns,
+    sys.path.insert(0, str(REPO / "tools"))
+    from compile_for_chip import build_world
+
+    _config, world = build_world(str(REPO / "examples/tgen-10k/shadow.yaml"), 64)
+    cfg, model = world.ecfg, world.model
+    c = _run_c(nb_bin, tmp_path, world.tables, 64, int(0.1 * NS_PER_SEC),
+               cfg.seed, model.resp_bytes, model.pause_ns, cfg.runahead_ns,
                bw_bits_per_sec_to_refill(100_000_000))
     assert c["streams_done"] == 32  # one stream per client in 100 ms
     assert c["bytes_down"] == 32 * model.resp_bytes

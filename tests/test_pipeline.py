@@ -2,8 +2,8 @@
 pipelining, donated chunk states, device-side termination probes) is a
 pure DRIVER change: pipelined+donated runs must be leaf-exact vs the
 synchronous driver (pipeline=False, same executable, probe fetched before
-every launch) on phold and tgen — across the plain, pump, and megakernel
-(interpret-mode) engines — and the donation contract must fail loudly:
+every launch) on phold and tgen — across the plain and pump engines —
+and the donation contract must fail loudly:
 a donated state's buffers raise RuntimeError on any stale reuse while the
 caller's own SimState is never invalidated."""
 
@@ -86,10 +86,10 @@ def test_pipelined_matches_sync_phold():
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("engine", ["plain", "pump", "megakernel"])
+@pytest.mark.parametrize("engine", ["plain", "pump"])
 def test_pipelined_matches_sync_tgen(engine):
     """Leaf-exact pipelined-vs-sync on the flagship tgen TCP workload for
-    every round engine (megakernel runs in Pallas interpret mode here).
+    every round engine.
     Slow tier: each engine compiles its own chunk executable twice; the
     tier-1 pipeline coverage is the phold equivalence + smoke above."""
     cfg0, model, tables, st0 = _tgen_world(8, 0.02, 20_000_000, seed=3)

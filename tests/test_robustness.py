@@ -27,6 +27,7 @@ from shadow_tpu.runtime.checkpoint import (
     StateTap,
     load_checkpoint,
     peek_checkpoint_meta,
+    resume_engine_cfg,
     save_checkpoint,
 )
 from shadow_tpu.runtime.recovery import (
@@ -127,16 +128,41 @@ def test_interrupt_resume_bit_exact_tgen(tmp_path, engine):
     _assert_leaves_exact(straight, resumed)
 
 
-@pytest.mark.slow
-def test_interrupt_resume_bit_exact_tgen_megakernel(tmp_path):
-    cfg0, model, tables, st0 = _tgen_world(8, 0.02, 20_000_000, seed=3)
-    cfg = dataclasses.replace(cfg0, engine="megakernel", pump_k=3)
-    end = 30 * NS_PER_MS
-    straight = run_until(st0, end, model, tables, cfg, rounds_per_chunk=2)
-    resumed = _interrupt_then_resume(
-        cfg, model, tables, st0, end, tmp_path,
-        interval_ns=4 * NS_PER_MS, interrupt_at_ns=10 * NS_PER_MS, rpc=2,
-    )
+def test_resume_ignores_retired_pool_capacity_field(tmp_path):
+    """A checkpoint written before the segment exchange was retired carries
+    `pool_capacity: 0` in its meta (the front door could set no other
+    value). Resume rebuilds the engine config from the meta's width knobs
+    (Manager and the sweep, through resume_engine_cfg): it passes that
+    field by, and the resumed run is leaf-exact to the uninterrupted one."""
+    import json
+
+    import numpy as np
+
+    cfg, model, tables, st0 = _phold_world()
+    end = 60 * NS_PER_MS
+    straight = run_until(st0, end, model, tables, cfg, rounds_per_chunk=4)
+
+    ck = CheckpointManager(str(tmp_path), 10 * NS_PER_MS, "fp")
+    ck.engine_cfg = cfg  # as run_until_recovering sets it: meta records the knobs
+    tap = StateTap(checkpoints=ck, guard=InterruptGuard(test_interrupt_at_ns=25 * NS_PER_MS))
+    with pytest.raises(RunInterrupted):
+        run_until(st0, end, model, tables, cfg, rounds_per_chunk=4, on_state=tap)
+    path = CheckpointManager.latest_path(str(tmp_path))
+    with np.load(path, allow_pickle=False) as z:
+        arrays = {k: z[k] for k in z.files}
+    meta = json.loads(str(arrays["__meta__"][()]))
+    assert "a2a_capacity" in meta and "pool_capacity" not in meta
+    arrays["__meta__"] = np.asarray(json.dumps({**meta, "pool_capacity": 0}))
+    with open(path, "wb") as f:
+        np.savez(f, **arrays)
+    assert peek_checkpoint_meta(path)["pool_capacity"] == 0
+
+    assert resume_engine_cfg(path, cfg) == cfg
+    grown = dataclasses.replace(cfg, queue_capacity=2 * cfg.queue_capacity, a2a_capacity=7)
+    assert resume_engine_cfg(path, grown) == cfg  # the recorded widths win
+    restored, _ = load_checkpoint(path, st0, "fp")
+    resumed = run_until(restored, end, model, tables, cfg, rounds_per_chunk=4)
+    assert int(resumed.events_handled.sum()) > 0
     _assert_leaves_exact(straight, resumed)
 
 

@@ -1,6 +1,6 @@
 """Managed-tier benchmark: N-pair C HTTP client/server matrix under the
 hybrid scheduler (guests on sharded CPU kernel workers, packets on the
-device engine). The managed-scale counterpart of bench.py's scripted tgen
+device engine). The managed-scale counterpart of the scripted tgen
 metric (round-2 verdict item 1).
 
   python tools/bench_hybrid.py [pairs] [workers] [fetches] [nbytes]

@@ -17,9 +17,7 @@ carry the described device's sharding:
   handle           `handle_one_iteration` — the full event handler
   pump_microstep   one pump microstep on a PumpCarry
   pump_stage       pump_k cond-guarded microsteps + carry init/finish
-  megakernel       `megakernel._launch(interpret=False)` — Mosaic
   flush            the round-boundary exchange cfg.exchange selects
-  flush_segment    `_flush_segment` (exchange="segment")
   window           `_next_window_end`
 
 Run with JAX_PLATFORMS=cpu, every call under a `timeout`, in the background:
@@ -49,7 +47,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
 PIECES = (
     "chunk", "sharded", "run_round", "handle", "pump_microstep", "pump_stage",
-    "megakernel", "flush", "flush_segment", "window",
+    "flush", "window",
 )
 
 
@@ -157,7 +155,7 @@ def main(argv=None) -> int:
     ap.add_argument("pieces", nargs="+", choices=PIECES)
     ap.add_argument("--config", default="examples/tgen-10k/shadow.yaml")
     ap.add_argument("--hosts", type=int, default=0, help="cut the world to N hosts")
-    ap.add_argument("--engine", default="plain", choices=("plain", "pump", "megakernel"))
+    ap.add_argument("--engine", default="plain", choices=("plain", "pump"))
     ap.add_argument("--pump-k", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=0,
                     help="rounds per chunk (0 = the config's)")
@@ -230,17 +228,12 @@ def main(argv=None) -> int:
             return lambda: jax.jit(
                 lambda s: rnd._flush_outbox_traffic(s, None, ecfg)
             ).lower(st)
-        if name == "flush_segment":
-            seg = dataclasses.replace(ecfg, exchange="segment")
-            return lambda: jax.jit(
-                lambda s: rnd._flush_segment(s, None, seg)
-            ).lower(st)
         if name == "window":
             return lambda: jax.jit(
                 lambda s, e, tb: rnd._next_window_end(s, e, ecfg, None, tables=tb)
             ).lower(st, t64, tables)
-        if name in ("pump_microstep", "pump_stage", "megakernel"):
-            from shadow_tpu.engine import megakernel, pump
+        if name in ("pump_microstep", "pump_stage"):
+            from shadow_tpu.engine import pump
 
             pcfg = ecfg if ecfg.pump_k > 0 else dataclasses.replace(
                 ecfg, pump_k=args.pump_k
@@ -256,14 +249,8 @@ def main(argv=None) -> int:
                 ),
                 one_chip,
             )
-            if name == "pump_microstep":
-                return lambda: jax.jit(
-                    lambda c, we, tb: pump.pump_microstep(c, we, model, tb, pcfg)
-                ).lower(carry, t64, tables)
             return lambda: jax.jit(
-                lambda c, we, tb: megakernel._launch(
-                    c, we, model, tb, pcfg, interpret=False
-                )
+                lambda c, we, tb: pump.pump_microstep(c, we, model, tb, pcfg)
             ).lower(carry, t64, tables)
         if name == "sharded":
             from shadow_tpu.engine.sharded import AXIS, ShardedRunner, state_specs

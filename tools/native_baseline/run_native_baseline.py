@@ -1,45 +1,15 @@
-"""Build + run the native C baseline (tgen_pdes.c) on the bench topology.
-
-Dumps the exact routing tables bench.py:_build constructs (so the C PDES
-simulates the identical world), compiles the C once, runs it, and prints
-its one-line JSON result. Used by bench.py for the honest `vs_baseline`
-denominator and by tests/test_native_baseline.py for counter-identity
-against the device engine and the Python oracle.
-
-  python tools/native_baseline/run_native_baseline.py [hosts] [sim_sec]
-"""
+"""The one serializer of the native C baseline's (tgen_pdes.c) tables
+format, for tests/test_native_baseline.py: the C PDES simulates the world
+whose routing tables it is handed, counter-identical to the device engine
+and the Python oracle."""
 
 from __future__ import annotations
 
-import os
-import pathlib
 import struct
-import subprocess
-import sys
-
-HERE = pathlib.Path(__file__).resolve().parent
-REPO = HERE.parent.parent
-
-NS_PER_SEC = 1_000_000_000
-
-
-def ensure_built() -> pathlib.Path:
-    src = HERE / "tgen_pdes.c"
-    out = HERE / "build" / "tgen_pdes"
-    out.parent.mkdir(exist_ok=True)
-    if not out.exists() or out.stat().st_mtime < src.stat().st_mtime:
-        subprocess.run(
-            ["cc", "-O2", "-o", str(out), str(src), "-lm"],
-            check=True,
-            capture_output=True,
-            text=True,
-        )
-    return out
 
 
 def write_tables(path, tables) -> None:
-    """The one serializer of the C binary's tables format:
-    int32 n_nodes, int64 lat[n*n] ns, float32 rel[n*n]."""
+    """int32 n_nodes, int64 lat[n*n] ns, float32 rel[n*n]."""
     import numpy as np
 
     lat = np.asarray(tables.lat_ns, dtype=np.int64)
@@ -48,57 +18,3 @@ def write_tables(path, tables) -> None:
         f.write(struct.pack("<i", lat.shape[0]))
         f.write(lat.tobytes())
         f.write(rel.tobytes())
-
-
-def dump_tables(path: pathlib.Path, num_hosts: int, seed: int = 7):
-    """Writes the bench topology's lat/rel node tables; returns the engine
-    config pieces the C binary needs (runahead, bandwidth refill) — all
-    read from bench._build's world, never duplicated here."""
-    sys.path.insert(0, str(REPO))
-    from bench import HOST_BW_BITS, _build_world
-
-    # world only — never init_state/bootstrap (at 160k+ hosts the device
-    # state is multi-GB and the C binary needs none of it)
-    cfg, model, tables = _build_world(num_hosts, seed=seed)
-    write_tables(path, tables)
-    from shadow_tpu.netstack import bw_bits_per_sec_to_refill
-
-    return {
-        "runahead_ns": cfg.runahead_ns,
-        "refill": bw_bits_per_sec_to_refill(HOST_BW_BITS),
-        "resp_bytes": model.resp_bytes,
-        "pause_ns": model.pause_ns,
-        "seed": cfg.seed,
-    }
-
-
-def run(num_hosts: int, sim_sec: float, tables_path=None) -> str:
-    binary = ensure_built()
-    tp = pathlib.Path(tables_path or (HERE / "build" / f"tables_{num_hosts}.bin"))
-    meta = dump_tables(tp, num_hosts)
-    r = subprocess.run(
-        [
-            str(binary),
-            str(tp),
-            str(num_hosts),
-            str(int(sim_sec * NS_PER_SEC)),
-            str(meta["seed"]),
-            str(meta["resp_bytes"]),
-            str(meta["pause_ns"]),
-            str(meta["runahead_ns"]),
-            str(meta["refill"]),
-            str(meta["refill"]),
-        ],
-        check=True,
-        capture_output=True,
-        text=True,
-    )
-    return r.stdout.strip()
-
-
-if __name__ == "__main__":
-    hosts = int(sys.argv[1]) if len(sys.argv) > 1 else int(
-        os.environ.get("SHADOW_TPU_BENCH_HOSTS", 10240)
-    )
-    sim_sec = float(sys.argv[2]) if len(sys.argv) > 2 else 0.5
-    print(run(hosts, sim_sec))

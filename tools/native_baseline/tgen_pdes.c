@@ -16,8 +16,7 @@
  *
  * Input: a binary tables file (int32 n_nodes, int64 lat[n*n] ns,
  * float rel[n*n]) written by tools/native_baseline/run_native_baseline.py
- * from the bench topology; host->node mapping is i % n_nodes as in
- * bench.py:_build.
+ * write_tables; host->node mapping is i % n_nodes.
  *
  * Usage: tgen_pdes TABLES_FILE NUM_HOSTS SIM_NS [SEED] [RESP_BYTES]
  *        [PAUSE_NS] [RUNAHEAD_NS] [TX_REFILL] [RX_REFILL]
