@@ -77,7 +77,7 @@ import flax.struct
 from shadow_tpu import equeue, netstack, rng, scopes
 from shadow_tpu.engine.state import EngineConfig, SimState
 from shadow_tpu.events import KIND_PACKET, pack_tie, tie_src_host
-from shadow_tpu.graph.routing import RoutingTables
+from shadow_tpu.graph.routing import RoutingTables, node_of, route_lookup
 from shadow_tpu.netstack import AUX_SHAPED_BIT, AUX_SIZE_MASK
 from shadow_tpu.simtime import TIME_MAX
 from shadow_tpu.transport import tcp as T
@@ -238,7 +238,7 @@ def pump_carry_init(
         alive=jnp.ones((h,), bool),
         rejected=jnp.zeros((h,), bool),
         host_ids=st.host_id,
-        src_node=tables.host_node[st.host_id],
+        src_node=node_of(tables, st.host_id),
         key_data=jax.random.key_data(st.rng_key),
     )
 
@@ -705,9 +705,7 @@ def pump_microstep(
     # relay-charge and draw order — is preserved either way. The P2
     # loss draw index is remapped to the handler's control lane. ----
     dst = jnp.clip(v_rhost, 0, tables.num_global_hosts - 1)
-    dst_node = tables.host_node[dst]
-    lat = tables.lat_ns[src_node, dst_node]
-    rel = tables.rel[src_node, dst_node]
+    _, lat, rel = route_lookup(tables, src_node, dst)
     loopb = dst == host_ids
     in_btx = now < cfg.bootstrap_end_ns
 

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from shadow_tpu import equeue, netstack, rng, scopes
 from shadow_tpu.engine.state import EngineConfig, Outbox, SimState, trace_static_cfg
 from shadow_tpu.events import KIND_PACKET, pack_tie
-from shadow_tpu.graph.routing import RoutingTables
+from shadow_tpu.graph.routing import RoutingTables, node_of, route_lookup
 from shadow_tpu.netstack import AUX_SHAPED_BIT, AUX_SIZE_MASK
 from shadow_tpu.simtime import TIME_MAX
 
@@ -223,11 +223,10 @@ def handle_one_iteration(
     ep = pvalid.shape[1]
 
     # --- packet path: routing lookup, loss draw, delivery clamp ---
-    src_node = tables.host_node[host_ids]  # [H]
     dst_clamped = jnp.clip(pemits.dst, 0, tables.num_global_hosts - 1)
-    dst_node = tables.host_node[dst_clamped]  # [H, EP]
-    lat = tables.lat_ns[src_node[:, None], dst_node]  # [H, EP] i64
-    rel = tables.rel[src_node[:, None], dst_node]  # [H, EP] f32
+    _, lat, rel = route_lookup(  # [H, EP] i64, f32
+        tables, node_of(tables, host_ids), dst_clamped
+    )
 
     unroutable = pvalid & (lat >= TIME_MAX)
     loss_lane = getattr(model, "LOSS_COUNTER_LANE", None)

@@ -671,6 +671,8 @@ class Manager:
             )
         eng = getattr(sched, "engine", None)
         eng_note = f"engine={eng}, " if eng else ""
+        if getattr(sched, "route_path", None):
+            eng_note += f"route={sched.route_path}:{sched.route_runs}, "
         slog("info", 0, "manager", f"starting: {num_hosts} hosts, {rep_note}"
              f"scheduler={sched.name}, {eng_note}"
              f"runahead={runahead}ns, stop={fmt_time_ns(end)}")
@@ -805,6 +807,8 @@ class Manager:
                     fallbacks[-1]["to"] if fallbacks
                     else getattr(sched, "engine", None)
                 ),
+                "route": getattr(sched, "route_path", None),
+                "route_runs": getattr(sched, "route_runs", None),
             }
         if autotune_plan is not None:
             # what the autotuner decided and on what evidence — an

@@ -58,6 +58,10 @@ class TpuScheduler:
         self.engine = effective_engine(cfg)
         if not model_pump_capable(model):
             self.engine = "plain"
+        # how the handler finds a host's node in THIS world, a fact of
+        # set-up like the engine: "runs" (compares against the host groups'
+        # bounds, that many) or "gather" (graph/routing.py node_of)
+        self.route_path, self.route_runs = tables.route_path, tables.route_runs
         if n > 1:
             from jax.sharding import Mesh
 

@@ -30,6 +30,12 @@ Every name is a single path component; nested scopes give paths:
                             the up relay's token buckets at emit time
     drain/handle/tcp        transport/tcp.py: tcp_handle on the fused slot
                             view and commit_slot's one scatter
+    drain/handle/route      graph/routing.py route_lookup / node_of: the
+    drain/pump/route        packet's routing lookup, which is the source's
+                            and the destination's node (compares against
+                            the host groups' bounds, or a gather where
+                            hosts are listed singly) and ONE gather of the
+                            pair's packed latency and reliability words
     drain/handle/stage      the handler's staging of surviving packets
                             into the host's own outbox row: one select
                             chain over [H, outbox] per array, the
@@ -59,6 +65,7 @@ HANDLE = "handle"
 PUMP = "pump"
 NETSTACK = "netstack"
 TCP = "tcp"
+ROUTE = "route"
 STAGE = "stage"
 PUSH_SELF = "push_self"
 EXCHANGE = "exchange"
@@ -74,6 +81,7 @@ SCOPES = {
     PUMP: "drain",
     NETSTACK: "drain",
     TCP: "drain",
+    ROUTE: "drain",
     STAGE: "drain",
     PUSH_SELF: "kernels",
     EXCHANGE: "exchange",

@@ -134,6 +134,8 @@ def test_sim_stats_device_block(tmp_path):
     assert s["device"] == {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices()), "ids": [dev.id], "engine": "plain",
+        # one host group: the routing lookup reads a host's node from one run
+        "route": "runs", "route_runs": 1,
     }
     assert "degraded" not in s
     for k, per_host in s["per_host"].items():

@@ -21,7 +21,10 @@ from shadow_tpu.netstack import bw_bits_per_sec_to_refill
 from shadow_tpu.simtime import NS_PER_MS
 
 
-def _world(num_hosts, loss, bw_bits, seed=11):
+def _world(num_hosts, loss, bw_bits, seed=11, grouped=False):
+    """`grouped`: hosts in n_nodes blocks, as host groups give them (the
+    routing lookup's runs: one a node), in place of striped over the nodes
+    (one run a host)."""
     rng_py = random.Random(seed)
     n_nodes = 4
     lines = ["graph [", "  directed 0"]
@@ -39,7 +42,7 @@ def _world(num_hosts, loss, bw_bits, seed=11):
     lines.append("]")
     graph = NetworkGraph.from_gml("\n".join(lines))
     tables = compute_routing(graph).with_hosts(
-        [i % n_nodes for i in range(num_hosts)]
+        [i * n_nodes // num_hosts if grouped else i % n_nodes for i in range(num_hosts)]
     )
     cfg = EngineConfig(
         num_hosts=num_hosts,
@@ -109,9 +112,13 @@ def _assert_states_equal(a, b):
         assert jnp.array_equal(la, lb), f"mismatch at {jax.tree_util.keystr(path)}"
 
 
-@pytest.mark.parametrize("loss,bw", [(0.0, 20_000_000), (0.02, 20_000_000)])
-def test_pump_bit_identical_tgen(loss, bw):
-    cfg0, model, tables, st0 = _world(32, loss, bw)
+@pytest.mark.parametrize(
+    "loss,bw,grouped",
+    [(0.0, 20_000_000, False), (0.02, 20_000_000, False), (0.02, 20_000_000, True)],
+)
+def test_pump_bit_identical_tgen(loss, bw, grouped):
+    cfg0, model, tables, st0 = _world(32, loss, bw, grouped=grouped)
+    assert tables.route_runs == (4 if grouped else 32)  # both by the runs' bounds
     end = 120 * NS_PER_MS
     ref = _run(cfg0, model, tables, st0, end)
     cfgp = dataclasses.replace(cfg0, pump_k=6)

@@ -40,13 +40,13 @@ ROUNDS = 2
 CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
     "window", "drain", "drain/handle", "drain/handle/push_self",
-    "drain/handle/stage", "exchange", "exchange/land", "probe",
+    "drain/handle/stage", "drain/handle/route", "exchange", "exchange/land", "probe",
 }
 # the tgen world shapes its hosts and speaks TCP; phold's does neither
 TGEN = EVERYWHERE | {"drain/handle/netstack", "drain/handle/tcp"}
 EXPECTED = {
     "tgen-plain": TGEN,
-    "tgen-pump": TGEN | {"drain/pump", "drain/pump/push_self"},
+    "tgen-pump": TGEN | {"drain/pump", "drain/pump/push_self", "drain/pump/route"},
     # phold publishes no pump_spec: every engine value takes the handler
     "phold-plain": EVERYWHERE,
     "phold-pump": EVERYWHERE,
@@ -200,6 +200,21 @@ def test_a_table_without_a_scope_is_refused_loudly(chunks, monkeypatch):
     table = scopes.chunk_table(fresh)
     assert table and scopes.chunk_table(fresh) is table  # memoised
     assert len(said) == 2
+
+
+def test_the_routing_lookups_scope_is_in_the_list_and_in_the_digest():
+    """`route` names a drain scope, and the chunk functions' names moved
+    with it: a compile cache filled before it cannot answer."""
+    import hashlib
+
+    assert scopes.SCOPES[scopes.ROUTE] == "drain"
+    def digest(names):
+        return "s" + hashlib.sha1("/".join(names).encode()).hexdigest()[:6]
+
+    assert scopes.KEY == digest(scopes.SCOPES)
+    assert scopes.KEY != digest(n for n in scopes.SCOPES if n != scopes.ROUTE)
+    op = "jit(_run_chunk)/while/body/drain/while/body/handle/route/gather"
+    assert scopes.scope_path(op) == "drain/handle/route"
 
 
 def test_scope_path_keeps_only_the_lists_names():

@@ -14,12 +14,12 @@ from shadow_tpu import equeue
 from shadow_tpu.engine import EngineConfig, ShardedRunner, init_state
 from shadow_tpu.engine.round import bootstrap, run_until
 from shadow_tpu.engine.sharded import AXIS
-from shadow_tpu.graph import NetworkGraph, compute_routing
+from shadow_tpu.graph import NetworkGraph, compute_routing, routing
 from shadow_tpu.models import PholdModel
 from shadow_tpu.simtime import NS_PER_MS
 
 
-def _setup(num_hosts, n_nodes=4, loss=0.1, seed=31):
+def _setup(num_hosts, n_nodes=4, loss=0.1, seed=31, grouped=False):
     rng_py = random.Random(seed)
     lines = ["graph [", "  directed 0"]
     for i in range(n_nodes):
@@ -32,7 +32,10 @@ def _setup(num_hosts, n_nodes=4, loss=0.1, seed=31):
             )
     lines.append("]")
     graph = NetworkGraph.from_gml("\n".join(lines))
-    host_node = [i % n_nodes for i in range(num_hosts)]
+    # grouped: blocks of hosts a node, as host groups give them
+    host_node = [
+        i * n_nodes // num_hosts if grouped else i % n_nodes for i in range(num_hosts)
+    ]
     tables = compute_routing(graph, block=8).with_hosts(host_node)
     cfg = EngineConfig(
         num_hosts=num_hosts,
@@ -46,17 +49,7 @@ def _setup(num_hosts, n_nodes=4, loss=0.1, seed=31):
     return cfg, model, tables, st
 
 
-def test_sharded_matches_single_device():
-    assert jax.device_count() == 8
-    cfg, model, tables, st0 = _setup(num_hosts=16)
-    end = 50 * NS_PER_MS
-
-    st_single = run_until(st0, end, model, tables, cfg, rounds_per_chunk=16)
-
-    mesh = Mesh(np.array(jax.devices()), (AXIS,))
-    runner = ShardedRunner(mesh, model, tables, cfg, rounds_per_chunk=16)
-    st_sharded = runner.run_until(st0, end)
-
+def _assert_sharded_equals_single(st_single, st_sharded, cfg):
     for name in ["seq", "rng_counter", "packets_sent", "packets_dropped", "events_handled"]:
         np.testing.assert_array_equal(
             np.asarray(getattr(st_single, name)), np.asarray(getattr(st_sharded, name)), err_msg=name
@@ -72,8 +65,39 @@ def test_sharded_matches_single_device():
         assert equeue.debug_sorted_events(st_sharded.queue, h) == equeue.debug_sorted_events(
             st_single.queue, h
         ), f"host {h}"
+
+
+def test_sharded_matches_single_device():
+    assert jax.device_count() == 8
+    cfg, model, tables, st0 = _setup(num_hosts=16)
+    end = 50 * NS_PER_MS
+
+    st_single = run_until(st0, end, model, tables, cfg, rounds_per_chunk=16)
+
+    mesh = Mesh(np.array(jax.devices()), (AXIS,))
+    runner = ShardedRunner(mesh, model, tables, cfg, rounds_per_chunk=16)
+    st_sharded = runner.run_until(st0, end)
+
+    _assert_sharded_equals_single(st_single, st_sharded, cfg)
     assert int(st_sharded.queue.overflow.sum()) == 0
     assert int(st_sharded.outbox.overflow.sum()) == 0
+
+
+@pytest.mark.parametrize("path", ["runs", "gather"])
+def test_two_shards_match_one_on_either_lookup_path(path, monkeypatch):
+    """The routing lookup's tables are global and replicated: four runs of
+    four hosts, two on each chip, read by the runs' bounds; and the striped
+    map of 16 runs read by the gather (the limit lowered under it)."""
+    if path == "gather":
+        monkeypatch.setattr(routing, "ROUTE_RUNS_MAX", 8)
+    cfg, model, tables, st0 = _setup(num_hosts=16, grouped=path == "runs")
+    assert (tables.route_path, tables.route_runs) == ((path, 4) if path == "runs" else (path, 0))
+    end = 50 * NS_PER_MS
+    one = run_until(st0, end, model, tables, cfg, rounds_per_chunk=16)
+    mesh = Mesh(np.array(jax.devices()[:2]), (AXIS,))
+    two = ShardedRunner(mesh, model, tables, cfg, rounds_per_chunk=16).run_until(st0, end)
+    assert int(one.packets_sent.sum()) > 30 and int(one.packets_dropped.sum()) > 0
+    _assert_sharded_equals_single(one, two, cfg)
 
 
 def _setup_bulk(num_hosts, seed=17, exchange="all_to_all"):
