@@ -22,6 +22,7 @@ import flax.struct
 import jax
 import jax.numpy as jnp
 
+from shadow_tpu import scopes
 from shadow_tpu.events import KIND_INVALID, pack_tie, tie_src_host
 from shadow_tpu.simtime import TIME_MAX
 
@@ -277,6 +278,29 @@ def push_many(
     )
 
 
+def run_bounds(key: jax.Array, h: int) -> "tuple[jax.Array, jax.Array]":
+    """(cnt, begin), each [h] i32: cnt[x] the number of entries of `key`
+    ([M] i32, in any order) equal to x, begin its exclusive cumulative sum
+    (where x's run starts once the keys are sorted). A key outside [0, h)
+    is counted nowhere.
+
+    cnt[a * 128 + b] = sum_i [key_i // 128 == a][key_i % 128 == b], one
+    product of two one-hot matrices (exact in int32; on the chip the
+    MXU's, 0.1 ms where H+1 binary searches over the sorted keys took
+    1.2-1.4 ms a round: PERF.md, PR 27). Key h, the invalids', matches no
+    column or one past the last host's."""
+    with jax.named_scope(scopes.COUNT):
+        blocks = -(-h // 128)
+        hot_a = (key >> 7)[:, None] == jnp.arange(blocks, dtype=jnp.int32)
+        hot_b = (key & 127)[:, None] == jnp.arange(128, dtype=jnp.int32)
+        cnt = jnp.dot(
+            hot_a.T.astype(jnp.int8), hot_b.astype(jnp.int8),
+            preferred_element_type=jnp.int32,
+        ).reshape(-1)[:h]
+        begin = jnp.cumsum(cnt, dtype=jnp.int32) - cnt  # [H] start of h's run
+    return cnt, begin
+
+
 def push_many_sorted(
     q: EventQueue,
     dst: jax.Array,  # [M] i32 destination host ids
@@ -334,19 +358,7 @@ def push_many_sorted(
     key1 = jnp.where(valid, dst, h).astype(jnp.int32)
     pos = jnp.arange(m, dtype=jnp.int32)
     _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
-    # the runs: cnt[a * 128 + b] = sum_i [key_i // 128 == a][key_i % 128 == b],
-    # one product of two one-hot matrices (exact in int32; on the chip the
-    # MXU's, 0.1 ms where H+1 binary searches over the sorted keys took
-    # 1.2-1.4 ms a round: PERF.md, PR 27). Key h, the invalids', matches
-    # no column or one past the last host's.
-    blocks = -(-h // 128)
-    hot_a = (key1 >> 7)[:, None] == jnp.arange(blocks, dtype=jnp.int32)
-    hot_b = (key1 & 127)[:, None] == jnp.arange(128, dtype=jnp.int32)
-    cnt = jnp.dot(
-        hot_a.T.astype(jnp.int8), hot_b.astype(jnp.int8),
-        preferred_element_type=jnp.int32,
-    ).reshape(-1)[:h]
-    begin = jnp.cumsum(cnt, dtype=jnp.int32) - cnt  # [H] start of h's run
+    cnt, begin = run_bounds(key1, h)
 
     # G: the payload as 32-bit words, word-major [W, M]: time and tie as
     # (low, high), kind, aux, the data lanes. Word-major because the chip

@@ -60,6 +60,15 @@ _GROUP_BY_FIELD = {
 }
 _GROUP_ORDER = ("queue", "outbox", "net", "model", "tracker", "rng", "counters")
 
+# What a `shadow-tpu run` holds on the device, over the state by shapes, as
+# a TPU v5e measured it on the one world that fills a chip (524,288 PHOLD
+# hosts, state 2,491,940,904 B; PERF.md section 6, PR 32): two copies of
+# the state at a driver entry (the caller's and the donated one;
+# `memory_stats()` peaks at 5,086,441,984 B) plus what the chunk program
+# reserves when it is loaded, 7.35 GiB there, which `memory_stats()` does
+# not count and which held ballast showed to be real: 12.95 GB in all.
+DEVICE_OVER_STATE = 5.2
+
 
 def _leaf_name(path) -> str:
     """'queue.data' from a tree_flatten_with_path key path."""
@@ -203,7 +212,9 @@ def render_report(report: dict, hbm_gb: "float | None" = None) -> str:
         fits = max_hosts_for_budget(report, budget)
         lines.append(
             f"  projection: {fits} hosts fit in {hbm_gb:g} GiB HBM "
-            f"(state only; XLA temps/program come on top — see "
+            f"(state only; XLA temps/program come on top: a TPU v5e "
+            f"held {DEVICE_OVER_STATE:g}x the state in a run, so about "
+            f"{int(fits / DEVICE_OVER_STATE)} hosts at that ratio — see "
             f"compiled peak in sim-stats/autotune)"
         )
     return "\n".join(lines)

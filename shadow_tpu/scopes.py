@@ -49,6 +49,8 @@ Every name is a single path component; nested scopes give paths:
                             order, the [H, queue] pull gather and the
                             one where pass that merges it (no push_self
                             under land)
+    exchange/land/count     equeue.run_bounds: each destination's arrival
+                            count and the start of its run in sorted order
     probe                   state_probe and the tracker plane's per-round
                             high-water marks
 """
@@ -71,6 +73,7 @@ PUSH_SELF = "push_self"
 EXCHANGE = "exchange"
 COLLECTIVE = "collective"
 LAND = "land"
+COUNT = "count"
 PROBE = "probe"
 
 # scope name -> layer of PERF.md / BENCHMARK.json that owns its time
@@ -87,6 +90,7 @@ SCOPES = {
     EXCHANGE: "exchange",
     COLLECTIVE: "exchange",
     LAND: "kernels",
+    COUNT: "kernels",
     PROBE: "driver",
 }
 

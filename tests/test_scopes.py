@@ -40,7 +40,8 @@ ROUNDS = 2
 CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
     "window", "drain", "drain/handle", "drain/handle/push_self",
-    "drain/handle/stage", "drain/handle/route", "exchange", "exchange/land", "probe",
+    "drain/handle/stage", "drain/handle/route", "exchange", "exchange/land",
+    "exchange/land/count", "probe",
 }
 # the tgen world shapes its hosts and speaks TCP; phold's does neither
 TGEN = EVERYWHERE | {"drain/handle/netstack", "drain/handle/tcp"}
@@ -202,24 +203,33 @@ def test_a_table_without_a_scope_is_refused_loudly(chunks, monkeypatch):
     assert len(said) == 2
 
 
-def test_the_routing_lookups_scope_is_in_the_list_and_in_the_digest():
-    """`route` names a drain scope, and the chunk functions' names moved
+@pytest.mark.parametrize("name,layer,op,path", [
+    (scopes.ROUTE, "drain",
+     "jit(_run_chunk)/while/body/drain/while/body/handle/route/gather", "drain/handle/route"),
+    # equeue.run_bounds, under the landing (PR 32)
+    (scopes.COUNT, "kernels",
+     "jit(_run_chunk)/while/body/exchange/land/count/dot_general", "exchange/land/count"),
+])
+def test_a_later_scope_is_in_the_list_and_in_the_digest(name, layer, op, path):
+    """The scope names its layer, and the chunk functions' names moved
     with it: a compile cache filled before it cannot answer."""
     import hashlib
 
-    assert scopes.SCOPES[scopes.ROUTE] == "drain"
+    assert scopes.SCOPES[name] == layer
+
     def digest(names):
         return "s" + hashlib.sha1("/".join(names).encode()).hexdigest()[:6]
 
     assert scopes.KEY == digest(scopes.SCOPES)
-    assert scopes.KEY != digest(n for n in scopes.SCOPES if n != scopes.ROUTE)
-    op = "jit(_run_chunk)/while/body/drain/while/body/handle/route/gather"
-    assert scopes.scope_path(op) == "drain/handle/route"
+    assert scopes.KEY != digest(n for n in scopes.SCOPES if n != name)
+    assert scopes.scope_path(op) == path
 
 
 def test_scope_path_keeps_only_the_lists_names():
     op = "jit(_run_chunk)/while/body/closed_call/cond/branch_1_fun/drain/while/body/handle/push_self/select_n"
     assert scopes.scope_path(op) == "drain/handle/push_self"
     assert scopes.scope_path("jit(_run_chunk)/while/body/add") == ""
+    # a primitive whose name only holds a scope's word is no scope
+    assert scopes.scope_path("jit(_run_chunk)/while/body/drain/population_count") == "drain"
     # no scope is named like something JAX writes into an op_name itself
     assert not set(scopes.SCOPES) & {"cond", "body", "while", "closed_call", "jit"}

@@ -138,11 +138,8 @@ def main(argv=None) -> int:
                 key1 = jnp.where(valid, dst, h).astype(jnp.int32)
                 pos = jnp.arange(m, dtype=jnp.int32)
                 _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
-                hot_a = (key1 >> 7)[:, None] == jnp.arange(-(-h // 128), dtype=jnp.int32)
-                hot_b = (key1 & 127)[:, None] == jnp.arange(128, dtype=jnp.int32)
-                cnt = jnp.dot(hot_a.T.astype(jnp.int8), hot_b.astype(jnp.int8),
-                              preferred_element_type=jnp.int32).reshape(-1)[:h]
-                return order, jnp.cumsum(cnt, dtype=jnp.int32) - cnt, cnt
+                cnt, begin = equeue.run_bounds(key1, h)
+                return order, begin, cnt
 
             def pack(tm, tie, kind, data, aux):
                 lo = lambda x: x.astype(jnp.int32)  # noqa: E731
