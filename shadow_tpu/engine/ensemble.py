@@ -54,6 +54,7 @@ import numpy as np
 from shadow_tpu import equeue, rng
 from shadow_tpu.engine.round import (
     PROBE_EXCH_HWM,
+    PROBE_LAND_HWM,
     PROBE_LANES,
     PROBE_NEXT_TIME,
     PROBE_NOW,
@@ -99,6 +100,7 @@ _SUM_LANES = frozenset(range(PROBE_LANES)) - {
     PROBE_QUEUE_HWM,
     PROBE_OUTBOX_HWM,
     PROBE_EXCH_HWM,
+    PROBE_LAND_HWM,
     PROBE_ROUNDS_LIVE,
     PROBE_ROUNDS_IDLE,
     PROBE_WIN_NS,

@@ -562,7 +562,7 @@ def _flush_outbox_traffic(
         return x.reshape(m)
 
     valid, dst, time, tie = flat(ob.valid), flat(ob.dst), flat(ob.time), flat(ob.tie)
-    # the landing reads the payload word-major (push_many_sorted's data.T
+    # the landing reads the payload word-major (land_sorted's data.T
     # folds with this one); the sharded buckets read it as [M, 8] rows
     data, aux = _payload_words(ob).T, flat(ob.aux)
     overflow_extra = None
@@ -625,7 +625,7 @@ def _flush_outbox_traffic(
     mine = valid & (local_dst >= 0) & (local_dst < h_local)
     lanes = getattr(cfg, "deliver_lanes", 0) if cfg is not None else 0
     with jax.named_scope(scopes.LAND):
-        queue = equeue.push_many_sorted(
+        queue, max_land = equeue.land_sorted(
             deliver_lanes=lanes if lanes > 0 else st.queue.capacity,
             q=st.queue,
             dst=local_dst,
@@ -644,7 +644,19 @@ def _flush_outbox_traffic(
     )
     if overflow_extra is not None:
         fresh = fresh.replace(overflow=fresh.overflow.at[0].add(overflow_extra))
-    return st.replace(queue=queue, outbox=fresh)
+    st = st.replace(queue=queue, outbox=fresh)
+    if cfg is not None and cfg.tracker:
+        # how the landing's loop engaged (row 0, like exch_hwm): the most
+        # arrivals one destination landed in one round — what LAND_LANES
+        # is sized from — and the passes made over all landings
+        tr = st.tracker
+        st = st.replace(
+            tracker=tr.replace(
+                land_hwm=tr.land_hwm.at[0].max(max_land),
+                land_passes=tr.land_passes.at[0].add(equeue.land_passes(max_land)),
+            )
+        )
+    return st
 
 
 def run_round(
@@ -921,11 +933,11 @@ def _peek_next_time(st: SimState) -> jax.Array:
 
 @jax.jit
 def _peek_capacity(st: SimState) -> jax.Array:
-    """[5] i64: queue overflow, outbox overflow, queue hwm, outbox hwm,
-    exchange hwm — the split check_capacity reports so a blowup names
-    the saturated counter without a rerun. With state_probe's overflow
-    lanes, the only two places that define what counts as a dropped
-    slot."""
+    """[6] i64: queue overflow, outbox overflow, queue hwm, outbox hwm,
+    exchange hwm, landing hwm — the split check_capacity reports so a
+    blowup names the saturated counter without a rerun. With
+    state_probe's overflow lanes, the only two places that define what
+    counts as a dropped slot."""
     return jnp.stack(
         [
             jnp.sum(st.queue.overflow).astype(jnp.int64),
@@ -933,6 +945,7 @@ def _peek_capacity(st: SimState) -> jax.Array:
             jnp.max(st.tracker.queue_hwm).astype(jnp.int64),
             jnp.max(st.tracker.outbox_hwm).astype(jnp.int64),
             jnp.max(st.tracker.exch_hwm).astype(jnp.int64),
+            jnp.max(st.tracker.land_hwm).astype(jnp.int64),
         ]
     )
 
@@ -984,7 +997,13 @@ PROBE_WIN_NS = 21
 # sizing (sharded.auto_a2a_capacity) and the exchange-occupancy figure
 # in CapacityError
 PROBE_EXCH_HWM = 22
-PROBE_LANES = 23
+# how the landing's loop engaged (tracker plane; equeue.land_sorted): the
+# most arrivals one destination landed in one round (pmax'd sharded) and
+# the passes its loop made over all landings (psum'd: a shard's loop runs
+# to its own busiest destination)
+PROBE_LAND_HWM = 23
+PROBE_LAND_PASSES = 24
+PROBE_LANES = 25
 
 
 def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
@@ -1012,12 +1031,14 @@ def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
         jnp.sum(tr.retrans_segs),
         jnp.sum(st.iters_done).astype(jnp.int64),
         jnp.sum(st.lanes_live),
+        jnp.sum(tr.land_passes).astype(jnp.int64),
     ]
     maxes = [
         st.now,
         jnp.max(tr.queue_hwm).astype(jnp.int64),
         jnp.max(tr.outbox_hwm).astype(jnp.int64),
         jnp.max(tr.exch_hwm).astype(jnp.int64),
+        jnp.max(tr.land_hwm).astype(jnp.int64),
     ]
     # replicated scalars (win_ns_sum is mesh-uniform: pmin'd window math)
     rounds = [tr.rounds_live, tr.rounds_idle, st.win_ns_sum]
@@ -1026,12 +1047,12 @@ def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
         sums = [jax.lax.psum(x, axis_name) for x in sums]
         maxes = [_pmax(x, axis_name) for x in maxes]
         rounds = [_pmax(x, axis_name) for x in rounds]
-    now, qh, oh, xh = maxes
-    (ov, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx, it, ll) = sums
+    now, qh, oh, xh, lh = maxes
+    (ov, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx, it, ll, lp) = sums
     rl, ri, wn = rounds
     return jnp.stack(
         [nt, ov, now, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx,
-         qh, oh, rl, ri, it, ll, wn, xh]
+         qh, oh, rl, ri, it, ll, wn, xh, lh, lp]
     ).astype(jnp.int64)
 
 
@@ -1067,6 +1088,12 @@ class ChunkProbe:
     # most events any shard flushed in one round (tracker plane; 0 when
     # cfg.tracker is off) — the measured per-round exchange traffic
     exch_hwm: int
+    # the landing's loop (tracker plane; 0 when cfg.tracker is off): the
+    # most arrivals one destination landed in one round, and the passes
+    # made over all landings (equeue.land_passes of each round's mark;
+    # like iters it depends on how the hosts are split over chips)
+    land_hwm: int
+    land_passes: int
 
     @property
     def ev_packet(self) -> int:
@@ -1264,11 +1291,11 @@ def check_capacity(st: SimState) -> None:
     simulation has silently dropped events and no longer matches the
     determinism contract (the tensor-shaped analogue of the reference's
     unbounded queues never dropping)."""
-    qov, oov, qh, oh, xh = (int(x) for x in _peek_capacity(st))
+    qov, oov, qh, oh, xh, lh = (int(x) for x in _peek_capacity(st))
     if qov or oov:
         err = _capacity_error(
             qov + oov, queue_ov=qov, outbox_ov=oov, queue_hwm=qh,
-            outbox_hwm=oh, exch_hwm=xh,
+            outbox_hwm=oh, exch_hwm=xh, land_hwm=lh,
         )
         attach_capacity_bytes(err, st)
         raise err
@@ -1300,6 +1327,8 @@ def host_stats(st: SimState) -> dict:
             "rounds_live": st.tracker.rounds_live,
             "rounds_idle": st.tracker.rounds_idle,
             "exch_hwm": st.tracker.exch_hwm,
+            "land_hwm": st.tracker.land_hwm,
+            "land_passes": st.tracker.land_passes,
             "iters_done": st.iters_done,
             "lanes_live": st.lanes_live,
             "win_ns_sum": st.win_ns_sum,
@@ -1329,13 +1358,15 @@ def _capacity_error(
     queue_hwm: "int | None" = None,
     outbox_hwm: "int | None" = None,
     exch_hwm: "int | None" = None,
+    land_hwm: "int | None" = None,
 ) -> CapacityError:
     """The split (when known — it rides the probe's dedicated lanes, so
     every driver has it) names WHICH fixed-slot counter saturated; the
     high-water marks (tracker plane, nonzero only with cfg.tracker) say
-    how close to the rim the other one ran, and the exchange high-water
+    how close to the rim the other one ran, the exchange high-water
     (PROBE_EXCH_HWM) reports the pool occupancy an exchange-side drop
-    was up against."""
+    was up against, and the landing's (PROBE_LAND_HWM) the fan-in of the
+    busiest destination of one round."""
     if queue_ov is None:
         which = "queue.overflow/outbox.overflow"
     else:
@@ -1352,6 +1383,8 @@ def _capacity_error(
             which += f"; high-water queue={queue_hwm}, outbox={outbox_hwm}"
         if exch_hwm:
             which += f"; exchange pool occupancy hwm={exch_hwm} events/round"
+        if land_hwm:
+            which += f"; busiest destination landed {land_hwm} arrivals in one round"
         which += "]"
     err = CapacityError(
         f"event capacity exhausted: {dropped} events/packets dropped "
@@ -1605,6 +1638,7 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
                     queue_hwm=probe.queue_hwm,
                     outbox_hwm=probe.outbox_hwm,
                     exch_hwm=probe.exch_hwm,
+                    land_hwm=probe.land_hwm,
                 )
                 # price the saturated buffers from the live state (the
                 # pipelined in-flight chunk's output when pend_st was

@@ -67,10 +67,10 @@ class EngineConfig:
     # semantics-bearing there and stays fixed.
     adaptive_window: bool = True
     # Round-boundary exchange mode (the cross-chip seam, the analogue of
-    # worker.rs:619-629). Both modes land by pull
-    # (equeue.push_many_sorted): one index sort by destination, the
-    # payload following as packed rows, then every free queue slot
-    # gathers its arrival by rank and one where pass merges it.
+    # worker.rs:619-629). Both modes land by pull (equeue.land_sorted):
+    # one index sort by destination, then every destination pulls its
+    # arrivals through the sort's permutation, a few arrival lanes a
+    # pass, for as many passes as the round's busiest destination needs.
     # "all_to_all" (default) buckets outbox entries by destination shard
     # and exchanges only each peer's bucket over ICI; "all_gather"
     # replicates every shard's whole outbox (more traffic, never
@@ -90,13 +90,14 @@ class EngineConfig:
     #  >0  = explicit bucket size.
     a2a_capacity: int = -1
     # Per-destination bound of the round-boundary landing
-    # (equeue.push_many_sorted): a host takes at most its first
+    # (equeue.land_sorted): a host takes at most its first
     # deliver_lanes arrivals of a ROUND; beyond it overflows loudly via
     # check_capacity. 0 (default) = queue_capacity: exact — a delivery
     # wave the queue could hold is never bounded by this. The landing is
-    # a pull (every free queue slot gathers its arrival by rank from the
-    # destination-sorted payload), so this width sizes no buffer and
-    # costs nothing at run or compile time (CHANGES.md PR 27).
+    # a pull by arrival lane whose passes follow the arrivals the busiest
+    # destination actually lands, so this width sizes no buffer and no
+    # loop, and costs nothing at run or compile time (CHANGES.md PR 27,
+    # PR 33).
     deliver_lanes: int = 0
     # Active-set compaction (engine/round.py handle_one_iteration_compact):
     # per pop-iteration, gather only the <= active_lanes hosts that actually
@@ -250,6 +251,15 @@ class TrackerState:
     # all_to_all buckets (sharded.auto_a2a_capacity) and the exchange
     # occupancy figure CapacityError reports.
     exch_hwm: jax.Array  # [H] i32
+    # How the landing's loop engaged (equeue.land_sorted), on row 0 like
+    # exch_hwm: the most arrivals ONE destination of this shard landed in
+    # one round (the figure equeue.LAND_LANES is sized from), and the
+    # passes the loop made, summed over the landings: ceil(that round's
+    # mark / LAND_LANES) each. Exact for a seed on one plane; like
+    # iters_done, land_passes depends on how the hosts are split over
+    # chips, so comparisons across planes leave it out.
+    land_hwm: jax.Array  # [H] i32
+    land_passes: jax.Array  # [H] i32
 
 
 def _empty_tracker(h: int) -> TrackerState:
@@ -264,6 +274,8 @@ def _empty_tracker(h: int) -> TrackerState:
         rounds_live=jnp.asarray(0, jnp.int64),
         rounds_idle=jnp.asarray(0, jnp.int64),
         exch_hwm=jnp.zeros((h,), jnp.int32),
+        land_hwm=jnp.zeros((h,), jnp.int32),
+        land_passes=jnp.zeros((h,), jnp.int32),
     )
 
 

@@ -256,6 +256,19 @@ def push_self_lanes(
     )
 
 
+# Arrival lanes of every destination that one pass of the landing pulls
+# (land_sorted). One constant for every caller: what adapts is the number
+# of passes, to the busiest destination of the batch. Chosen by a sweep on
+# the chip (PERF.md section 7.7).
+LAND_LANES = 4
+
+
+def land_passes(max_land):
+    """Passes land_sorted's loop makes when the busiest destination lands
+    `max_land` arrivals: ceil(max_land / LAND_LANES)."""
+    return (max_land + (LAND_LANES - 1)) // LAND_LANES
+
+
 def push_many(
     q: EventQueue,
     dst: jax.Array,  # [M] i32 destination host ids
@@ -271,7 +284,8 @@ def push_many(
     This is the round-boundary exchange step (the analogue of
     Worker::push_packet_to_host, reference src/main/core/worker.rs:619-629,
     minus the mutex). push_many_sorted with no per-destination bound but
-    the queue's own capacity (exact: only a full row rejects)."""
+    the queue's own capacity (exact: only a full row rejects); it costs by
+    the batch and by the busiest destination's arrivals (land_sorted)."""
     return push_many_sorted(
         q, dst, valid, time, tie, kind, data, aux,
         deliver_lanes=q.capacity,
@@ -312,28 +326,55 @@ def push_many_sorted(
     aux: "jax.Array | None" = None,  # [M] i32
     deliver_lanes: int = 48,
 ) -> EventQueue:
-    """push_many as a PULL: every free queue slot works out which arrival,
-    if any, it receives, and gathers it.
+    """land_sorted's queue alone, for callers with no use for the count of
+    arrivals the busiest destination landed."""
+    return land_sorted(q, dst, valid, time, tie, kind, data, aux, deliver_lanes)[0]
+
+
+def land_sorted(
+    q: EventQueue,
+    dst: jax.Array,  # [M] i32 destination host ids
+    valid: jax.Array,  # [M] bool
+    time: jax.Array,  # [M] i64
+    tie: jax.Array,  # [M] i64
+    kind: jax.Array,  # [M] i32
+    data: jax.Array,  # [M, PAYLOAD_LANES] i32
+    aux: "jax.Array | None" = None,  # [M] i32
+    deliver_lanes: int = 48,
+) -> "tuple[EventQueue, jax.Array]":
+    """push_many as a PULL by arrival lane: every destination pulls its own
+    arrivals, LAND_LANES of them a pass, for as many passes as the busiest
+    destination needs. Returns (queue, max_land): max_land (scalar i32) is
+    the most arrivals one destination landed, the loop's bound.
 
       S   one stable sort of (destination, position) — two words per
           entry, invalids last — puts each destination's arrivals in one
           run, in arrival order; cnt[h] of the runs is a histogram of the
           keys (one product of two one-hot matrices), begin[h] its
           exclusive cumulative sum;
-      G   the payload follows as 32-bit words (14 an entry): one gather
-          into sorted order;
-      P   slot c of row h is that row's fr[h, c]-th free (tombstoned)
-          slot; it takes arrival begin[h] + fr[h, c] while fr[h, c] <
-          land[h] = min(cnt[h], D, room[h]) — ONE [H, Q] row gather and
-          one where pass over the five queue arrays.
+      G   the payload is packed as 32-bit words (14 an entry) where it
+          lies; nothing is copied into sorted order: a pass reads entry
+          order[begin[h] + r] for arrival rank r, through the sort's
+          permutation;
+      P   row h lands land[h] = min(cnt[h], D, room[h]) arrivals; pass p
+          handles ranks [p K, (p + 1) K), K = LAND_LANES: two gathers of
+          [K, H] indices (the permutation, then the 14 words) and one
+          select chain over the five queue arrays that puts rank r into
+          the row's r-th free (tombstoned) slot (`free & (fr == r)`, as
+          push_self_lanes spells it). A lax.while_loop makes
+          ceil(max_h land[h] / K) passes: none where nothing landed.
 
-    Cost follows the queue and the batch, not D = deliver_lanes: there is
-    no [H, D] delivery grid, no scatter and no lane-by-lane merge. D keeps
+    Cost follows the batch (S) and the busiest destination (P), not the
+    queue's H x Q slots and not D = deliver_lanes: there is no [H, D]
+    delivery grid, no scatter, no gather of H x Q or of M indices. D keeps
     its meaning: a destination takes at most its first D arrivals of a
     call (rank < D fits); those beyond D are counted on overflow row 0,
     those beyond the row's room on the row's own overflow — both loud via
     check_capacity. The r-th arrival lands in the row's r-th free slot
     (arrival order of the stable sort); pop order is key-driven anyway.
+    Under vmap the loop runs to the largest replica's pass count and a
+    replica that is done keeps its carry: each replica's leaves are its
+    own.
 
     Why the payload does not ride the sort: XLA:TPU's sort costs the
     chip's compiler ~14 s per 32-bit operand word once the array no
@@ -347,7 +388,7 @@ def push_many_sorted(
     """
     m = dst.shape[0]
     if m == 0:
-        return q
+        return q, jnp.zeros((), jnp.int32)
     if aux is None:
         aux = jnp.zeros_like(kind)
     h, cap = q.num_hosts, q.capacity
@@ -377,34 +418,51 @@ def push_many_sorted(
     words = jnp.concatenate(
         [jnp.stack([lo(time), hi(time), lo(tie), hi(tie), kind, aux]), data.T]
     )
-    words_s = words[:, order]
 
-    # P: each free slot pulls its arrival by rank
+    # P: each row's free slots by rank, and how many arrivals it lands
     free = q.time == TIME_MAX  # [H, Q]
     fr = (jnp.cumsum(free, axis=1) - free).astype(jnp.int32)  # rank among free slots
     fit = jnp.minimum(cnt, deliver_lanes)
     land = jnp.minimum(fit, cap - q.count)  # [H]
     take = free & (fr < land[:, None])
-    src = jnp.minimum(begin[:, None] + fr, m - 1)  # only read where take
-    g = words_s[:, src]  # [W, H, Q]
+    max_land = jnp.max(land)
+    passes = land_passes(max_land)
+    lane = jnp.arange(LAND_LANES, dtype=jnp.int32)
 
-    g_time = long(g[0], g[1])
-    return q.replace(
-        time=jnp.where(take, g_time, q.time),
-        tie=jnp.where(take, long(g[2], g[3]), q.tie),
-        kind=jnp.where(take, g[4], q.kind),
-        data=jnp.where(take[:, :, None], jnp.moveaxis(g[6:], 0, -1), q.data),
-        aux=jnp.where(take, g[5], q.aux),
+    def one_pass(carry):
+        p, q = carry
+        rank = p * LAND_LANES + lane  # [K] arrival ranks of this pass
+        # lanes major, hosts minor: a minor axis of K would pad to 128 lanes
+        src = jnp.minimum(begin + rank[:, None], m - 1)  # [K, H]; used where rank < land
+        g = words[:, order[src]]  # [W, K, H]
+        g_time, g_tie = long(g[0], g[1]), long(g[2], g[3])
+        q_time, q_tie, q_kind, q_data, q_aux = q.time, q.tie, q.kind, q.data, q.aux
+        for k in range(LAND_LANES):
+            at = take & (fr == rank[k])  # the row's rank-th free slot
+            q_time = jnp.where(at, g_time[k, :, None], q_time)
+            q_tie = jnp.where(at, g_tie[k, :, None], q_tie)
+            q_kind = jnp.where(at, g[4, k, :, None], q_kind)
+            q_aux = jnp.where(at, g[5, k, :, None], q_aux)
+            q_data = jnp.where(at[:, :, None], g[6:, k].T[:, None, :], q_data)
+        landed = jnp.where(rank[:, None] < land, g_time, TIME_MAX)
+        return p + 1, q.replace(
+            time=q_time, tie=q_tie, kind=q_kind, data=q_data, aux=q_aux,
+            head_time=jnp.minimum(q.head_time, jnp.min(landed, axis=0)),
+        )
+
+    with jax.named_scope(scopes.PULL):
+        _, q = jax.lax.while_loop(
+            lambda carry: carry[0] < passes, one_pass, (jnp.zeros((), jnp.int32), q)
+        )
+    q = q.replace(
         count=q.count + land,
         # beyond the row's room: on the row; beyond deliver_lanes, at
         # TIME_MAX or to no host of this queue: globally on row 0
         overflow=(q.overflow + (fit - land))
         .at[0]
         .add(n_pushed - jnp.sum(fit, dtype=jnp.int32)),
-        head_time=jnp.minimum(
-            q.head_time, jnp.min(jnp.where(take, g_time, TIME_MAX), axis=1)
-        ),
     )
+    return q, max_land
 
 
 def debug_sorted_events(q: EventQueue, host: int):

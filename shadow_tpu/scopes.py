@@ -44,13 +44,17 @@ Every name is a single path component; nested scopes give paths:
     drain/pump/push_self    merges of the handler and the pump
     exchange                flush_outbox: flatten, bucket, clear
     exchange/collective     all_to_all / all_gather (sharded only)
-    exchange/land           equeue.push_many_sorted: destination sort,
-                            the runs' bounds, row gather into sorted
-                            order, the [H, queue] pull gather and the
-                            one where pass that merges it (no push_self
+    exchange/land           equeue.land_sorted: destination sort, the
+                            runs' bounds, the payload packed as words,
+                            each row's free-slot ranks (no push_self
                             under land)
     exchange/land/count     equeue.run_bounds: each destination's arrival
                             count and the start of its run in sorted order
+    exchange/land/pull      the landing's while loop: a pass pulls
+                            LAND_LANES arrival lanes of every destination
+                            through the sort's permutation and selects
+                            them into the rows' free slots; as many
+                            passes as the busiest destination needs
     probe                   state_probe and the tracker plane's per-round
                             high-water marks
 """
@@ -74,6 +78,7 @@ EXCHANGE = "exchange"
 COLLECTIVE = "collective"
 LAND = "land"
 COUNT = "count"
+PULL = "pull"
 PROBE = "probe"
 
 # scope name -> layer of PERF.md / BENCHMARK.json that owns its time
@@ -91,6 +96,7 @@ SCOPES = {
     COLLECTIVE: "exchange",
     LAND: "kernels",
     COUNT: "kernels",
+    PULL: "kernels",
     PROBE: "driver",
 }
 

@@ -287,7 +287,8 @@ class Tracker:
                 f"queue_hwm={int(stats['queue_hwm'][i])} "
                 f"outbox_hwm={int(stats['outbox_hwm'][i])} "
                 f"lanes_live={int(stats['lanes_live'][i])} "
-                f"win_mean_ns={win_mean:.0f} occupancy={occ:.4f}",
+                f"win_mean_ns={win_mean:.0f} occupancy={occ:.4f} "
+                f"land_hwm={probe.land_hwm} land_passes={probe.land_passes}",
             )
 
     def record_probe(self, probe) -> None:
@@ -373,6 +374,8 @@ class Tracker:
             out["high_water"] = {
                 "queue": int(max(hs["queue_hwm"])),
                 "outbox": int(max(hs["outbox_hwm"])),
+                # most arrivals one destination landed in one round
+                "landing": int(max(hs["land_hwm"])),
             }
             out["rounds"] = {
                 "live": int(hs["rounds_live"]),
@@ -399,6 +402,9 @@ class Tracker:
                 "iters": iters,
                 "lanes_live": lanes,
                 "occupancy": round(lanes / (iters * h), 4) if iters and h else 0,
+                # passes of the landing's loop (equeue.land_sorted); like
+                # iters, summed over the iteration planes
+                "land_passes": int(np.asarray(hs["land_passes"]).sum()),
             }
         elif self.last_probe is not None:
             p = self.last_probe
@@ -417,7 +423,9 @@ class Tracker:
                 "data": p.bytes_data,
                 "retrans_segments": p.retrans_segs,
             }
-            out["high_water"] = {"queue": p.queue_hwm, "outbox": p.outbox_hwm}
+            out["high_water"] = {
+                "queue": p.queue_hwm, "outbox": p.outbox_hwm, "landing": p.land_hwm,
+            }
             out["rounds"] = {"live": p.rounds_live, "idle": p.rounds_idle}
         return out
 

@@ -105,6 +105,8 @@ def _canon(st):
             queue_hwm=st.tracker.queue_hwm * 0,
             outbox_hwm=st.tracker.outbox_hwm * 0,
             exch_hwm=st.tracker.exch_hwm * 0,
+            land_hwm=st.tracker.land_hwm * 0,
+            land_passes=st.tracker.land_passes * 0,
         ),
     )
 

@@ -196,9 +196,10 @@ def test_delivery_grid_landing_compiles(world, one_chip, queue, deliver_lanes):
     """equeue.push_many_sorted — the front door's exchange landing — with
     a whole 10,240-host outbox (655,360 entries) in flight: onto a
     narrowed queue at deliver_lanes=4, and as the front door runs it
-    (deliver_lanes 0 = the queue's capacity, 384). The landing is a pull,
-    so neither width sizes anything: the index sort, the one-hot product
-    that counts the runs and the two row gathers are what is asked here."""
+    (deliver_lanes 0 = the queue's capacity, 384). The landing pulls by
+    arrival lane in a loop, so neither width sizes anything: the index
+    sort, the one-hot product that counts the runs, and a while whose
+    pass gathers [H, LAND_LANES] indices twice are what is asked here."""
     m = HOSTS * OUTBOX
     q = _on(jax.eval_shape(lambda: equeue.create(HOSTS, queue)), one_chip)
 
@@ -216,8 +217,21 @@ def test_delivery_grid_landing_compiles(world, one_chip, queue, deliver_lanes):
     # and nothing else is sorted (searchsorted's method="sort" would be)
     sorts = [ln.split(" sort(")[0] for ln in text.splitlines() if " sort(" in ln]
     assert len(sorts) == 1 and sorts[0].count(f"[{m}]") == 2 and "s64[" not in sorts[0], sorts
-    # no delivery grid: nothing of [H * D] rows is scattered; the landing gathers
-    assert " scatter(" not in text and " gather(" in text
+    # no delivery grid: nothing of [H * D] rows is scattered
+    assert " scatter(" not in text
+    # the pull is a loop over arrival lanes: every gather issues H x LAND_LANES
+    # indices (the permutation's word, then the 14 payload words), none the
+    # queue's H x Q slots or the outbox's M entries, and no [14, H, Q] pull
+    # result or [14, M] copy in sorted order is made
+    assert " while(" in text
+    made = [ln.split(" = ")[1].split("{")[0] for ln in text.splitlines() if " = " in ln]
+    gathers = sorted(
+        ln.split(" = ")[1].split("{")[0] for ln in text.splitlines() if " gather(" in ln
+    )
+    lanes = equeue.LAND_LANES
+    assert gathers == sorted([f"s32[{lanes},{HOSTS}]", f"s32[14,{lanes},{HOSTS}]"]), gathers
+    flat = (f"[14,{HOSTS},{queue}]", f"[{HOSTS * queue},14]", f"[14,{HOSTS * queue}]", f"[{m},14]")
+    assert not [b for b in made if b.endswith(flat)]
 
 
 def test_sharded_window_and_exchange_collective_compile(topo):

@@ -266,6 +266,8 @@ def _pop_some(rng, q, rounds):
     return q
 
 
+K = equeue.LAND_LANES
+
 # name -> (H, Q, D, [batches as (M, kwargs of _batch)], pops between batches)
 _LANDING_CASES = {
     "empty_batch": (4, 8, 8, [(0, {})], 0),
@@ -279,6 +281,22 @@ _LANDING_CASES = {
     "one_destination_takes_everything": (
         4, 8, 8, [(6, {"dst": [2] * 6}), (6, {"dst": [2] * 6, "p_valid": 1.0})], 1),
     "d_one": (4, 8, 1, [(10, {}), (10, {})], 1),
+    # the loop's edges (K = LAND_LANES arrival lanes a pass): a fan-in that
+    # ends on, one past and well past a pass, onto rows that interleaved
+    # pops left with tombstones
+    "fan_in_exactly_k": (4, 4 * K, 4 * K, [(K + 6, {"dst": [1] * K + [0, 2, 3] * 2})] * 3, 2),
+    "fan_in_k_plus_one": (
+        4, 4 * K, 4 * K, [(K + 4, {"dst": [3] * (K + 1) + [0, 1, 2], "p_valid": 1.0})] * 3, 2),
+    "fan_in_three_k_plus_one": (
+        5, 8 * K, 8 * K,
+        [(3 * K + 5, {"dst": [2] + [0, 1, 3, 4] + [2] * (3 * K), "p_valid": 1.0})] * 2, 3),
+    "room_ends_inside_a_pass": (
+        3, K + K // 2 + 1, 4 * K,
+        [(2 * K + 3, {"dst": [1] * (2 * K) + [0, 2, 2], "p_valid": 1.0})] * 2, 1),
+    "deliver_lanes_end_inside_a_pass": (
+        3, 4 * K, K + 3, [(2 * K + 3, {"dst": [0] * (2 * K) + [1, 2, 2], "p_valid": 1.0})] * 2, 1),
+    "one_destination_takes_the_whole_queue": (
+        4, 3 * K, 3 * K, [(3 * K + 2, {"dst": [2] * (3 * K + 2), "p_valid": 1.0})], 0),
 }
 
 
@@ -300,6 +318,64 @@ def test_push_many_sorted_equals_grid_reference(case):
                 np.asarray(getattr(q_new, name)), np.asarray(getattr(q_ref, name)),
                 err_msg=f"{case}: leaf {name} after batch {i}")
         q_new = q_ref = _pop_some(rng, q_new, pops)
+
+
+@pytest.mark.parametrize("case", sorted(_LANDING_CASES))
+def test_landing_makes_as_many_passes_as_the_busiest_destination_needs(case, monkeypatch):
+    """The landing's loop body runs ceil(max_h land[h] / K) times a push,
+    land[h] the arrivals row h took (its count's growth): none for a batch
+    that is empty or all invalid. max_land is what land_sorted hands back,
+    land_passes what the tracker plane books of it."""
+    hosts, cap, d, batches, pops = _LANDING_CASES[case]
+    rng = np.random.default_rng(sorted(_LANDING_CASES).index(case))
+    ran = []
+
+    def counted_loop(cond, body, carry):
+        ran.append(0)
+        while bool(cond(carry)):
+            carry = body(carry)
+            ran[-1] += 1
+        return carry
+
+    monkeypatch.setattr(jax.lax, "while_loop", counted_loop)
+    q = equeue.create(hosts, cap)
+    for i, (m, kw) in enumerate(batches):
+        ent = _batch(rng, m, hosts, t0=40 * i, **kw)
+        before = np.asarray(q.count)
+        q, max_land = equeue.land_sorted(q, *ent, deliver_lanes=d)
+        most = int((np.asarray(q.count) - before).max())
+        assert int(max_land) == most
+        assert int(equeue.land_passes(max_land)) == -(-most // K)
+        assert (ran.pop() if m else 0) == -(-most // K) and not ran
+        q = _pop_some(rng, q, pops)
+    if case in ("empty_batch", "all_invalid"):
+        assert most == 0
+
+
+def test_vmapped_landing_gives_each_replica_its_own_leaves():
+    """Two replicas under jax.vmap with different fan-in (one arrival a
+    destination against 3 K + 1 on one): the batched loop runs to the
+    larger pass count, and each replica's queue and max_land are what it
+    gets alone."""
+    hosts, cap = 4, 8 * K
+    rng = np.random.default_rng(77)
+    m = 3 * K + 4
+    wide = _batch(rng, m, hosts, p_valid=1.0, dst=[0, 1, 3] + [2] * (3 * K + 1))
+    thin = _batch(rng, m, hosts, dst=(list(range(hosts)) * m)[:m])
+    thin = (thin[0], thin[1].at[:hosts].set(True).at[hosts:].set(False)) + thin[2:]
+    q0 = _pop_some(rng, equeue.push_many(equeue.create(hosts, cap), *_batch(rng, 40, hosts)), 3)
+    q1 = _pop_some(rng, equeue.push_many(equeue.create(hosts, cap), *_batch(rng, 40, hosts)), 2)
+    alone = [equeue.land_sorted(q, *ent) for q, ent in ((q0, thin), (q1, wide))]
+    assert [int(n) for _, n in alone] == [1, 3 * K + 1]
+
+    def stack(a, b):
+        return jax.tree.map(lambda x, y: jnp.stack([x, y]), a, b)
+
+    got_q, got_n = jax.vmap(equeue.land_sorted)(stack(q0, q1), *stack(thin, wide))
+    assert got_n.tolist() == [1, 3 * K + 1]
+    for r, (want, _) in enumerate(alone):
+        for a, b in zip(jax.tree.leaves(got_q), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a[r]), np.asarray(b), err_msg=f"replica {r}")
 
 
 def test_push_many_at_time_max_counted_on_row_zero():

@@ -1,5 +1,5 @@
 """The round-boundary exchange (engine/round.py flush_outbox): all_to_all
-or all_gather across shards, landed by pull (equeue.push_many_sorted).
+or all_gather across shards, landed by pull (equeue.land_sorted).
 
 Contracts pinned here:
 

@@ -8,10 +8,11 @@ Contracts pinned here, on the virtual 8-device CPU mesh:
     run seeded seed + r*stride — phold and tgen, plain and pump
     engines, tracker leaves included — modulo ONLY the established
     sharded-execution deviations: the per-shard iteration diagnostics
-    (iters_done / lanes_live / exch_hwm, excluded by every
-    engine-equivalence test — engine/state.py; exch_hwm accumulates on
-    each shard's local row 0, so its placement depends on the grid
-    layout) and residual garbage in DEAD queue slots (live
+    (iters_done / lanes_live / exch_hwm / land_hwm / land_passes,
+    excluded by every engine-equivalence test — engine/state.py; the
+    last three accumulate on each shard's local row 0, so their
+    placement depends on the grid layout, and a shard's landing loop
+    runs to its own busiest destination) and residual garbage in DEAD queue slots (live
     slots are compared bit-exact IN PLACE; the sharded exchange lays
     tombstone payloads differently, the same deviation
     tests/test_sharded.py accepts by comparing canonical pop order);
@@ -88,6 +89,7 @@ def _assert_mesh_slice_exact(sl, single, what=""):
     for (path, la), (_, lb) in zip(fa, fb):
         ks = jax.tree_util.keystr(path)
         if ("iters_done" in ks or "lanes_live" in ks or "exch_hwm" in ks
+                or "land_hwm" in ks or "land_passes" in ks
                 or ks in grid_leaves):
             continue
         assert jnp.array_equal(la, lb), f"mismatch{what} at {ks}"
@@ -352,7 +354,7 @@ sweep:
         s.pop("device", None)  # likewise: where it ran, not what it computed
         if "tracker" in s:
             s["tracker"].pop("phases", None)
-            for k in ("iters", "lanes_live", "occupancy"):
+            for k in ("iters", "lanes_live", "occupancy", "land_passes"):
                 s["tracker"].get("window", {}).pop(k, None)
         return s
 

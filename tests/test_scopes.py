@@ -41,7 +41,7 @@ CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
     "window", "drain", "drain/handle", "drain/handle/push_self",
     "drain/handle/stage", "drain/handle/route", "exchange", "exchange/land",
-    "exchange/land/count", "probe",
+    "exchange/land/count", "exchange/land/pull", "probe",
 }
 # the tgen world shapes its hosts and speaks TCP; phold's does neither
 TGEN = EVERYWHERE | {"drain/handle/netstack", "drain/handle/tcp"}
@@ -97,8 +97,11 @@ def test_every_scope_names_operations_of_the_compiled_chunk(chunks, case):
     table = scopes.parse_hlo_text(chunks[case][2])
     found = {v[1] for v in table.values() if v[1]}
     assert EXPECTED[case] <= found, EXPECTED[case] - found
-    # the landing is a pull (one gather, one where pass): no lane merge under it
+    # the landing pulls by arrival lane in a loop of its own: no lane merge
+    # under it, and the loop's body books under the loop's scope
     assert "exchange/land/push_self" not in found
+    pulled = [n for n, v in table.items() if v[1] == "exchange/land/pull"]
+    assert any(n.startswith("while") for n in pulled) and len(pulled) > 1, pulled
     # nothing outside the one list, and outermost is the path's head
     for shape, inner, outer in table.values():
         if inner:
@@ -209,6 +212,9 @@ def test_a_table_without_a_scope_is_refused_loudly(chunks, monkeypatch):
     # equeue.run_bounds, under the landing (PR 32)
     (scopes.COUNT, "kernels",
      "jit(_run_chunk)/while/body/exchange/land/count/dot_general", "exchange/land/count"),
+    # equeue.land_sorted's while loop, under the landing (PR 33)
+    (scopes.PULL, "kernels",
+     "jit(_run_chunk)/while/body/exchange/land/pull/while/body/gather", "exchange/land/pull"),
 ])
 def test_a_later_scope_is_in_the_list_and_in_the_digest(name, layer, op, path):
     """The scope names its layer, and the chunk functions' names moved

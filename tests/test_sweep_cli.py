@@ -67,7 +67,7 @@ def _stats(path) -> dict:
     s.pop("device", None)  # likewise: where it ran, not what it computed
     if "tracker" in s:
         s["tracker"].pop("phases", None)
-        for k in ("iters", "lanes_live", "occupancy"):
+        for k in ("iters", "lanes_live", "occupancy", "land_passes"):
             s["tracker"].get("window", {}).pop(k, None)
     return s
 

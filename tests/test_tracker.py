@@ -178,6 +178,50 @@ def test_probe_tracker_lanes_consistent():
     assert p.queue_overflow == 0 and p.outbox_overflow == 0
 
 
+def _staged(st, dst_by_row):
+    """`st` with one staged packet per listed outbox row: row r sends to
+    dst_by_row[r]."""
+    ob, n = st.outbox, len(dst_by_row)
+    return st.replace(
+        outbox=ob.replace(
+            valid=ob.valid.at[:n, 0].set(True),
+            dst=ob.dst.at[:n, 0].set(jnp.asarray(dst_by_row, jnp.int32)),
+            time=ob.time.at[:n, 0].set(7 * NS_PER_MS),
+            tie=ob.tie.at[:n, 0].set(jnp.arange(n) + 1),
+            fill=ob.fill.at[:n].set(1),
+        )
+    )
+
+
+@pytest.mark.parametrize("tracker", [True, False], ids=["on", "off"])
+def test_land_counters_book_the_landings_loop(tracker):
+    """land_hwm is the most arrivals one destination landed in one round,
+    land_passes the sum over the flushes of ceil(that round's mark / K);
+    both on row 0, and both untouched with the tracker off."""
+    from shadow_tpu import equeue
+    from shadow_tpu.engine.round import ChunkProbe, flush_outbox, state_probe
+    from shadow_tpu.engine.state import EngineConfig, init_state
+
+    k = equeue.LAND_LANES
+    hosts = 3 * k + 4
+    cfg = EngineConfig(
+        num_hosts=hosts, queue_capacity=4 * k, outbox_capacity=2,
+        runahead_ns=NS_PER_MS, tracker=tracker,
+    )
+    st = init_state(cfg, model_state=())
+    fan_ins = [1, k, k + 1, 3 * k + 1, 2]
+    for n in fan_ins:  # n rows send to host 5, one more to host 0
+        st = flush_outbox(_staged(st, [5] * n + [0]), None, cfg)
+        st = st.replace(queue=equeue.create(hosts, 4 * k))
+    want_hwm = max(fan_ins) if tracker else 0
+    want_passes = sum(-(-n // k) for n in fan_ins) if tracker else 0
+    assert st.tracker.land_hwm.tolist() == [want_hwm] + [0] * (hosts - 1)
+    assert st.tracker.land_passes.tolist() == [want_passes] + [0] * (hosts - 1)
+    probe = ChunkProbe.from_array(state_probe(st))
+    assert (probe.land_hwm, probe.land_passes) == (want_hwm, want_passes)
+    assert probe.exch_hwm == 0  # run_round's own sample, not the flush's
+
+
 def test_ensemble_flatten_pairs_window_numerator_and_denominator():
     """mean_ns = win_ns_sum / live must take BOTH terms from the same
     population: the ensemble flatten sums win_ns_sum across replicas and
@@ -273,7 +317,7 @@ def test_heartbeat_lines_and_stats_fold_phold():
     assert set(stats["events_by_kind"]) == {"local", "tcp", "packet"}
     assert set(stats["drops"]) == {"loss", "codel", "unroutable"}
     assert set(stats["bytes"]) == {"ctrl", "data", "retrans_segments"}
-    assert set(stats["high_water"]) == {"queue", "outbox"}
+    assert set(stats["high_water"]) == {"queue", "outbox", "landing"}
     assert set(stats["rounds"]) == {"live", "idle"}
     total = sum(stats["events_by_kind"].values())
     assert total == int(st.events_handled.sum())
