@@ -8,7 +8,9 @@ own document and checks what comes out by the repo's own means.
 clients, 500 ms) or `fattree-10k` (benchmarks/configs/fattree-10k.json:
 10,240 hosts on a k=16 fat-tree, 5 us lookahead, 5,120 saturating TCP
 flows at 1 Gbit, 5 ms) or `phold-512k` (benchmarks/configs/phold-512k.json:
-524,288 PHOLD hosts, the world that fills a chip, 50 ms = 25 rounds). The
+524,288 PHOLD hosts, the world that fills a chip, 50 ms = 25 rounds) or
+`fattree-10k-cabled` (benchmarks/configs/fattree-10k-cabled.json: the
+fat-tree with cables of unequal length, flows out of step, 5 ms). The
 full-size phases run the document's own host count and stop time. This
 process never imports JAX: one process at a time owns the chip, so every
 run is a child that exits before the next starts, and the device facts
@@ -17,7 +19,7 @@ come back through the `device` block the run writes into sim-stats.json.
 Phases, each failing the script on its own:
 
   parity  the same document cut to 256 hosts and a shorter stop time
-          (100 ms; the fat-tree's 3 ms), once on the device
+          (100 ms; the fat-trees' 3 ms), once on the device
           and once on the independent scalar oracle
           (experimental.scheduler: cpu-ref, that child alone is given
           JAX_PLATFORMS=cpu): every per-host counter equal.
@@ -53,6 +55,8 @@ DEPLOYMENTS = {
     "tgen-10k": (os.path.join("examples", "tgen-10k", "shadow.yaml"), "100 ms", 64, "50 ms"),
     "fattree-10k": (os.path.join("benchmarks", "configs", "fattree-10k.json"), "3 ms", 128, "3 ms"),
     "phold-512k": (os.path.join("benchmarks", "configs", "phold-512k.json"), "100 ms", 64, "50 ms"),
+    "fattree-10k-cabled": (os.path.join("benchmarks", "configs", "fattree-10k-cabled.json"),
+                           "3 ms", 128, "3 ms"),
 }
 # what `engine: auto` means for this config (pump_k unset) on every
 # backend — engine/round.py effective_engine

@@ -14,7 +14,9 @@ lookahead). Nothing is drawn: the output is a function of the arguments,
 byte for byte.
 
   gen_fattree.py [k] > fattree.gml          # k even, default 8: the GML alone
-  gen_fattree.py --config [options] > fattree-10k.json
+  gen_fattree.py --config > benchmarks/configs/fattree-10k.json
+  gen_fattree.py --config --pod-step-us 8 --edge-step-us 3 \
+      > benchmarks/configs/fattree-10k-cabled.json
 
 `--config` writes the document as JSON (which is YAML): `general`,
 `network.graph.inline`, `experimental`, one host group per edge switch in
@@ -41,6 +43,24 @@ from its `source`. Options, with the values
                           covers 4-8 ms of this world: the adaptive window
                           skips simulated time in which no host has an
                           event, and the flows move in step
+  --pod-step-us 0 --edge-step-us 0
+                          cables of unequal length (PR 34). With steps P / E
+                          the aggregation-core links of pod p take
+                          70 + P p us and the edge-aggregation links of edge
+                          switch e of every pod 30 + E e us (pods at unequal
+                          distance from the core row, racks from their
+                          pod's aggregation row); the self-loop, and so the
+                          lookahead, stays 5 us. `fattree-10k-cabled` is
+                          8 / 3: client pod p, edge e reaches its server
+                          over 264 + 16 p + 6 e us, 64 distinct paths of
+                          264-418 us, so the 5,120 flows fall out of step
+                          and the adaptive window finds an event every few
+                          microseconds (~120 live rounds a millisecond where
+                          the lock-step world has 5). With a step the
+                          defaults of two options change, to what that world
+                          needs: --outbox-capacity 512 (marks 162 / 160 by
+                          5 ms: two refills' bursts meet at one host) and
+                          --rounds-per-chunk 128 (one chunk a millisecond)
 
 The GML alone takes the host bandwidth of the older examples (10 Gbit)
 and no loss, as `examples/fattree/shadow.yaml` expects.
@@ -65,6 +85,10 @@ RESP_BYTES = 125_000_000  # 1 s at line rate: no response ends inside a run
 PAUSE = "500 ms"
 STOP_TIME = "5 ms"
 MAX_ITERS_PER_ROUND = 256
+# queue / outbox high-water marks of the k=16 x 80 world with steps 8 / 3 to
+# 5 ms, the same on three seeds (PERF.md section 4): what its capacities
+# are twice of, as powers of two
+CABLED_MARKS = (162, 160)
 
 
 def fattree_ids(k: int) -> dict:
@@ -79,7 +103,11 @@ def fattree_ids(k: int) -> dict:
 
 
 def fattree_gml(k: int, core_latency_us=50, agg_latency_us=20, edge_latency_us=10,
-                host_bw_bits=10_000_000_000, core_loss=0.0) -> str:
+                host_bw_bits=10_000_000_000, core_loss=0.0,
+                pod_step_us=0, edge_step_us=0) -> str:
+    """The GML. With steps, cables of unequal length: every
+    edge-aggregation link of edge switch e of a pod is `edge_step_us * e`
+    longer, every aggregation-core link of pod p `pod_step_us * p`."""
     half = k // 2
     ids = fattree_ids(k)
     lines = ["graph [", "  directed 0"]
@@ -106,21 +134,72 @@ def fattree_gml(k: int, core_latency_us=50, agg_latency_us=20, edge_latency_us=1
     for p in range(k):
         for e in range(half):
             for a in range(half):
-                edge(f"edge{p}.{e}", f"agg{p}.{a}", edge_latency_us + agg_latency_us)
+                edge(f"edge{p}.{e}", f"agg{p}.{a}",
+                     edge_latency_us + agg_latency_us + edge_step_us * e)
     # agg <-> core: agg a connects to cores [a*half, (a+1)*half)
     for p in range(k):
         for a in range(half):
             for c in range(a * half, (a + 1) * half):
-                edge(f"agg{p}.{a}", f"core{c}", agg_latency_us + core_latency_us, core_loss)
+                edge(f"agg{p}.{a}", f"core{c}",
+                     agg_latency_us + core_latency_us + pod_step_us * p, core_loss)
     lines.append("]")
     return "\n".join(lines)
 
 
-def fattree_config(k=16, hosts_per_edge=80, queue_capacity=512, outbox_capacity=256,
-                   rounds_per_chunk=32) -> dict:
-    """The front door's document for tgen saturation on a k-ary fat-tree."""
+def _path_us(k: int, pod_step_us: int, edge_step_us: int) -> "list[int]":
+    """One-way latency of every client's path to its server, by tgen's rule
+    (pod p, edge e fetches from pod p + k/2, edge e), one entry an edge
+    switch of the client pods."""
+    half = k // 2
+    return [2 * (30 + edge_step_us * e) + (70 + pod_step_us * p) + (70 + pod_step_us * (p + half))
+            for p in range(half) for e in range(half)]
+
+
+def fattree_config(k=16, hosts_per_edge=80, queue_capacity=512, outbox_capacity=None,
+                   rounds_per_chunk=None, pod_step_us=0, edge_step_us=0) -> dict:
+    """The front door's document for tgen saturation on a k-ary fat-tree.
+    Without steps every cable of a tier has one length (`fattree-10k`:
+    outbox 256, 32 rounds a chunk unless given); with steps they are
+    unequal (`fattree-10k-cabled`: outbox 512, 128 rounds a chunk)."""
     half = k // 2
     ids = fattree_ids(k)
+    cabled = bool(pod_step_us or edge_step_us)
+    if outbox_capacity is None:
+        outbox_capacity = 512 if cabled else 256
+    if rounds_per_chunk is None:
+        rounds_per_chunk = 128 if cabled else 32
+    if cabled:
+        paths = _path_us(k, pod_step_us, edge_step_us)
+        options = f" --pod-step-us {pod_step_us} --edge-step-us {edge_step_us}"
+        notes = {
+            "link_latency_us": f"edge-aggregation 30 + {edge_step_us} e for edge switch e = 0..{half - 1} "
+                               f"of every pod (racks at unequal distance from their pod's aggregation "
+                               f"row), aggregation-core 70 + {pod_step_us} p for pod p = 0..{k - 1} (pods "
+                               f"at unequal distance from the core row), edge self-loop 5 (the "
+                               f"lookahead, as fattree-10k's); one-way client-to-server paths "
+                               f"{min(paths)}-{max(paths)} us, {len(set(paths))} distinct "
+                               f"({paths[0]} + {2 * pod_step_us} p + {2 * edge_step_us} e for client pod p, "
+                               f"edge switch e), so the flows do not move in step",
+            "queue_capacity": f"{queue_capacity} (the default is twice the high-water mark of the "
+                              f"k=16 x 80 world with steps 8 / 3 to 5 ms, {CABLED_MARKS[0]} on three "
+                              f"seeds, as a power of two)",
+            "outbox_capacity": f"{outbox_capacity} (likewise: mark {CABLED_MARKS[1]}; two refills' "
+                               f"bursts meet at one host now that round-trip times differ, so 256 no "
+                               f"longer holds twice the mark)",
+            "rounds_per_chunk": f"{rounds_per_chunk} (chunking is trajectory-neutral; ~120 live rounds "
+                                f"a simulated millisecond in slow start, so one chunk a millisecond)",
+        }
+    else:
+        options = ""
+        notes = {
+            "link_latency_us": "edge-aggregation 30, aggregation-core 70, edge self-loop 5 "
+                               "(gen_fattree.py's defaults); cross-pod RTT 400 us",
+            "queue_capacity": f"{queue_capacity} (the default is twice the high-water mark of the "
+                              "k=16 x 80 world to 5 ms, 129 on three seeds, as a power of two)",
+            "outbox_capacity": f"{outbox_capacity} (likewise: mark 127, a refilled bucket's 83 "
+                               "packets and what was staged before them, in one round)",
+            "rounds_per_chunk": f"{rounds_per_chunk} (chunking is trajectory-neutral)",
+        }
     groups = {
         f"p{p:02d}e{e}": {
             "network_node_id": ids[f"edge{p}.{e}"],
@@ -132,10 +211,9 @@ def fattree_config(k=16, hosts_per_edge=80, queue_capacity=512, outbox_capacity=
     return {
         "x-benchmark": {
             "source": "BASELINE.json config 4 'iperf-2 TCP saturation, 10k-host fat-tree topology'; "
-                      "world as examples/fattree/gen_fattree.py --config emits it",
+                      f"world as examples/fattree/gen_fattree.py --config{options} emits it",
             "assumed": {
-                "link_latency_us": "edge-aggregation 30, aggregation-core 70, edge self-loop 5 "
-                                   "(gen_fattree.py's defaults); cross-pod RTT 400 us",
+                "link_latency_us": notes["link_latency_us"],
                 "hosts_per_edge_switch": hosts_per_edge,
                 "host_bandwidth": f"{HOST_BW_BITS} bit up and down (upstream Shadow's 1_gbit_switch)",
                 "packet_loss": f"{CORE_LOSS} on each aggregation-core link, 0 elsewhere "
@@ -145,11 +223,9 @@ def fattree_config(k=16, hosts_per_edge=80, queue_capacity=512, outbox_capacity=
                 "pause": PAUSE,
                 "pairs": "tgen's rule: the first half of the hosts (the lower pods) clients, the "
                          "second half servers, client i to server i: every flow crosses the core",
-                "queue_capacity": f"{queue_capacity} (the default is twice the high-water mark of the "
-                                  "k=16 x 80 world to 5 ms, 129 on three seeds, as a power of two)",
-                "outbox_capacity": f"{outbox_capacity} (likewise: mark 127, a refilled bucket's 83 "
-                                   "packets and what was staged before them, in one round)",
-                "rounds_per_chunk": f"{rounds_per_chunk} (chunking is trajectory-neutral)",
+                "queue_capacity": notes["queue_capacity"],
+                "outbox_capacity": notes["outbox_capacity"],
+                "rounds_per_chunk": notes["rounds_per_chunk"],
             },
             "reduced": [] if k == 34 else ["network.graph"],
             "reduced_why": f"a k-ary fat-tree of 10k hosts is k=34 (1,445 switches, 17 hosts a "
@@ -161,7 +237,8 @@ def fattree_config(k=16, hosts_per_edge=80, queue_capacity=512, outbox_capacity=
         },
         "general": {"stop_time": STOP_TIME, "seed": 7},
         "network": {"graph": {"type": "gml", "inline": fattree_gml(
-            k, host_bw_bits=HOST_BW_BITS, core_loss=CORE_LOSS)}},
+            k, host_bw_bits=HOST_BW_BITS, core_loss=CORE_LOSS,
+            pod_step_us=pod_step_us, edge_step_us=edge_step_us)}},
         "experimental": {
             "scheduler": "tpu",
             "engine": "auto",
@@ -182,8 +259,10 @@ def main(argv=None) -> int:
     ap.add_argument("--k", type=int, default=16)
     ap.add_argument("--hosts-per-edge", type=int, default=80)
     ap.add_argument("--queue-capacity", type=int, default=512)
-    ap.add_argument("--outbox-capacity", type=int, default=256)
-    ap.add_argument("--rounds-per-chunk", type=int, default=32)
+    ap.add_argument("--outbox-capacity", type=int, default=None)
+    ap.add_argument("--rounds-per-chunk", type=int, default=None)
+    ap.add_argument("--pod-step-us", type=int, default=0)
+    ap.add_argument("--edge-step-us", type=int, default=0)
     args = ap.parse_args(argv)
     if not args.config:
         print(fattree_gml(args.k_gml or 8))

@@ -29,7 +29,7 @@ What makes the batch correct under vmap:
     [R, PROBE_LANES]; quiescence and capacity lanes reduce per replica:
     the driver stops only when EVERY replica is quiescent, records each
     replica's probe row at ITS OWN quiescence chunk (restoring now and
-    the round counters exactly as the single-replica driver would have
+    the idle-round count exactly as the single-replica driver would have
     left them), and a nonzero overflow lane raises a CapacityError that
     names the replica — rollback-and-regrow (runtime/recovery.py) then
     rolls back and regrows the WHOLE batch, keeping every replica on
@@ -256,7 +256,7 @@ def _replica_capacity_error(rows: np.ndarray) -> "Exception":
 
 
 def _patch_snapshot(host: SimState, final_rows: "dict[int, np.ndarray]") -> SimState:
-    """Rewrite a host (state_to_host) snapshot's `now` and round counters
+    """Rewrite a host (state_to_host) snapshot's `now` and idle-round count
     for every replica already recorded quiescent, to the values of its
     OWN quiescence chunk's probe row — the values _finish will restore at
     the end of the run. A replica that quiesces early keeps taking idle
@@ -270,41 +270,31 @@ def _patch_snapshot(host: SimState, final_rows: "dict[int, np.ndarray]") -> SimS
     if not final_rows:
         return host
     now = np.array(host.now, copy=True)
-    rl = np.array(host.tracker.rounds_live, copy=True)
     ri = np.array(host.tracker.rounds_idle, copy=True)
     for r, row in final_rows.items():
         now[r] = row[PROBE_NOW]
-        rl[r] = row[PROBE_ROUNDS_LIVE]
         ri[r] = row[PROBE_ROUNDS_IDLE]
-    return host.replace(
-        now=now, tracker=host.tracker.replace(rounds_live=rl, rounds_idle=ri)
-    )
+    return host.replace(now=now, tracker=host.tracker.replace(rounds_idle=ri))
 
 
 def _finish(out: SimState, final_rows: "dict[int, np.ndarray]") -> SimState:
-    """Restore each replica's `now` and round counters to the values its
+    """Restore each replica's `now` and idle-round count to the values its
     probe carried at ITS OWN quiescence chunk. A replica that quiesced
     early keeps taking idle rounds while slower replicas drain (and under
     pipelining one extra in-flight chunk runs after the last replica
-    quiesces); those idle rounds touch ONLY now and the round counters —
-    exactly the leaves the probe carries — so writing the recorded rows
+    quiesces); those idle rounds touch ONLY now and tracker.rounds_idle —
+    leaves the probe carries — so writing the recorded rows
     back makes every slice leaf-exact to the single-replica driver, which
     stops at that replica's own quiescence chunk."""
     r = num_replicas(out)
     now = jnp.asarray(
         [int(final_rows[i][PROBE_NOW]) for i in range(r)], out.now.dtype
     )
-    rl = jnp.asarray(
-        [int(final_rows[i][PROBE_ROUNDS_LIVE]) for i in range(r)],
-        out.tracker.rounds_live.dtype,
-    )
     ri = jnp.asarray(
         [int(final_rows[i][PROBE_ROUNDS_IDLE]) for i in range(r)],
         out.tracker.rounds_idle.dtype,
     )
-    return out.replace(
-        now=now, tracker=out.tracker.replace(rounds_live=rl, rounds_idle=ri)
-    )
+    return out.replace(now=now, tracker=out.tracker.replace(rounds_idle=ri))
 
 
 def _drive_ensemble(
@@ -364,6 +354,7 @@ def _drive_ensemble(
     # probe, which would re-accumulate idle rounds — carries the exact
     # leaves _finish must restore.
     flightrec.begin_segment()  # mirrors engine/round.py _drive
+    scopes.last_probes = None  # this plane keeps none; never another entry's
     with _tspan(tracker, "entry_probe"):
         entry_rows = np.asarray(jax.device_get(_peek_probe_ensemble(st)))
     final_rows: "dict[int, np.ndarray]" = {

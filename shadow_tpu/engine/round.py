@@ -880,25 +880,21 @@ def run_rounds_scan(
         def live(s):
             with jax.named_scope(scopes.WINDOW):
                 width = window_end - jnp.minimum(start, window_end)
-                s = s.replace(win_ns_sum=s.win_ns_sum + width)
-            s = run_round(s, window_end, model, tables, cfg, axis_name)
-            if cfg.tracker:
                 # replicated scalars: every shard runs the same round
-                # sequence, so no mesh reduction is needed (and the
-                # pipelined driver restores both from the probe on the
-                # quiescent-extra-chunk path, like `now`)
-                with jax.named_scope(scopes.PROBE):
-                    s = s.replace(
-                        tracker=s.tracker.replace(
-                            rounds_live=s.tracker.rounds_live + 1
-                        )
-                    )
-            return s
+                # sequence, so no mesh reduction is needed
+                s = s.replace(
+                    win_ns_sum=s.win_ns_sum + width,
+                    rounds_live=s.rounds_live + 1,
+                )
+            return run_round(s, window_end, model, tables, cfg, axis_name)
 
         def idle(s):
             with jax.named_scope(scopes.WINDOW):
                 s = s.replace(now=jnp.maximum(s.now, window_end))
             if cfg.tracker:
+                # a replicated scalar like rounds_live; the pipelined driver
+                # restores it from the probe on the quiescent-extra-chunk
+                # path, like `now`
                 with jax.named_scope(scopes.PROBE):
                     s = s.replace(
                         tracker=s.tracker.replace(
@@ -924,11 +920,6 @@ def validate_runahead(cfg: EngineConfig, tables: RoutingTables) -> None:
             f"runahead_ns={cfg.runahead_ns} exceeds the minimum path latency "
             f"{min_lat}ns; use runahead_ns <= graph.min_latency_ns()"
         )
-
-
-@jax.jit
-def _peek_next_time(st: SimState) -> jax.Array:
-    return jnp.min(equeue.next_time(st.queue))
 
 
 @jax.jit
@@ -985,10 +976,9 @@ PROBE_ROUNDS_LIVE = 17
 PROBE_ROUNDS_IDLE = 18
 # adaptivity lanes (always live, like the drop reasons): total drain
 # iterations, total eligible-host lanes across iterations (occupancy
-# numerator), and the summed simulated width of all live windows. NB the
-# derived window_ns_mean needs the tracker's rounds_live as denominator,
-# so it reads 0.0 on tracker-off runs even though win_ns_sum accrues —
-# consumers of the mean (--tracker stats) run tracker-on
+# numerator), and the summed simulated width of all live windows; their
+# denominator, PROBE_ROUNDS_LIVE above, is always live too (the idle
+# rounds beside it are the tracker plane's)
 PROBE_ITERS = 19
 PROBE_LANES_LIVE = 20
 PROBE_WIN_NS = 21
@@ -1008,9 +998,10 @@ PROBE_LANES = 25
 
 def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
     """[PROBE_LANES] i64 summary of a chunk's outcome, computed on device
-    as part of the chunk itself (no separate peek dispatch). Sharded, the
-    lanes are reduced over the mesh axis (psum for sums, pmin/pmax for
-    extrema) so the probe comes out replicated."""
+    as part of the chunk itself (no separate peek dispatch; a driver's
+    entry computes it once on the state it is handed: entry_probe).
+    Sharded, the lanes are reduced over the mesh axis (psum for sums,
+    pmin/pmax for extrema) so the probe comes out replicated."""
     tr = st.tracker
     nt = jnp.min(equeue.next_time(st.queue))
     qov = jnp.sum(st.queue.overflow).astype(jnp.int64)
@@ -1041,7 +1032,7 @@ def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
         jnp.max(tr.land_hwm).astype(jnp.int64),
     ]
     # replicated scalars (win_ns_sum is mesh-uniform: pmin'd window math)
-    rounds = [tr.rounds_live, tr.rounds_idle, st.win_ns_sum]
+    rounds = [st.rounds_live, tr.rounds_idle, st.win_ns_sum]
     if axis_name is not None:
         nt = _pmin(nt, axis_name)
         sums = [jax.lax.psum(x, axis_name) for x in sums]
@@ -1102,9 +1093,8 @@ class ChunkProbe:
 
     @property
     def window_ns_mean(self) -> float:
-        """Mean simulated width of the live windows drained so far.
-        Requires cfg.tracker (rounds_live is a tracker counter): a
-        tracker-off run accrues win_ns_sum but reads 0.0 here."""
+        """Mean simulated width of the live windows drained so far
+        (tracker on or off: both terms are always counted)."""
         return self.win_ns_sum / self.rounds_live if self.rounds_live else 0.0
 
     def occupancy(self, num_hosts: int, num_shards: int = 1) -> float:
@@ -1120,6 +1110,24 @@ class ChunkProbe:
     @classmethod
     def from_array(cls, arr) -> "ChunkProbe":
         return cls(*(int(x) for x in arr))
+
+
+# the probe of a state at rest, as its own tiny program: one launch and one
+# fetch of PROBE_LANES words at a driver's entry (a sharded state's lanes
+# come out reduced over the whole host axis, as the chunk's psum'd probe
+# gives them)
+_state_probe_jit = jax.jit(state_probe)
+
+
+def entry_probe(st: SimState) -> ChunkProbe:
+    """The probe of the state a driver entry starts from. Its `next_time`
+    lane answers "is there anything before end_time?"; the rest is what
+    the entry's chunks' probes are read against (a caller's warm state
+    does not start its counters at zero). Kept as `scopes.last_probes`,
+    where `_drive` puts the newest chunk's probe beside it."""
+    probe = ChunkProbe.from_array(jax.device_get(_state_probe_jit(st)))
+    scopes.last_probes = scopes.EntryProbes(st.num_hosts, probe)
+    return probe
 
 
 class CapacityError(RuntimeError):
@@ -1324,7 +1332,7 @@ def host_stats(st: SimState) -> dict:
             "retrans_segs": st.tracker.retrans_segs,
             "queue_hwm": st.tracker.queue_hwm,
             "outbox_hwm": st.tracker.outbox_hwm,
-            "rounds_live": st.tracker.rounds_live,
+            "rounds_live": st.rounds_live,
             "rounds_idle": st.tracker.rounds_idle,
             "exch_hwm": st.tracker.exch_hwm,
             "land_hwm": st.tracker.land_hwm,
@@ -1621,6 +1629,7 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
                 _fetch_probe(pend_probe, watchdog_s, fetched)
             )
         fetched += 1
+        scopes.last_probes.chunk = probe  # beside the entry's (entry_probe)
         with _tspan(tracker, "probe_decide", chunk=fetched - 1):
             # flight recorder (runtime/flightrec.py): fold this probe into
             # the installed recorder's ring BEFORE the capacity checks, so a
@@ -1704,7 +1713,7 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
             # the idle rounds clamp `now` to end_time where the
             # synchronous driver stopped at the last productive window —
             # and, under cfg.tracker, count themselves as idle rounds.
-            # Restore chunk N's `now` and round counters (they ride the
+            # Restore chunk N's `now` and idle-round count (they ride the
             # probe) so pipelined and synchronous results are leaf-exact
             # in every case.
             out = nxt[0]
@@ -1712,9 +1721,6 @@ def _drive(launch, st, end_time, max_chunks, on_chunk, pipeline, desc,
                 return out.replace(
                     now=jnp.asarray(probe.now, out.now.dtype),
                     tracker=out.tracker.replace(
-                        rounds_live=jnp.asarray(
-                            probe.rounds_live, out.tracker.rounds_live.dtype
-                        ),
                         rounds_idle=jnp.asarray(
                             probe.rounds_idle, out.tracker.rounds_idle.dtype
                         ),
@@ -1761,15 +1767,16 @@ def run_until(
     `on_chunk(probe: ChunkProbe)` is invoked once per completed chunk
     (heartbeats/progress); it receives the fetched probe, not the state.
     `tracker` (utils/tracker.py) records the entry as one `run` span
-    with the entry's own steps (`validate_runahead`, `peek_next_time`,
-    `put_end_time`, `donate_copy`) and the dispatch loop's spans (see
-    _drive) below it, and per-host heartbeats.
+    with the entry's own steps (`validate_runahead`, `peek_next_time`:
+    the fetch of the entry's probe, `put_end_time`, `donate_copy`) and
+    the dispatch loop's spans (see _drive) below it, and per-host
+    heartbeats.
     """
     with _tspan(tracker, "run"):
         with _tspan(tracker, "validate_runahead"):
             validate_runahead(cfg, tables)
         with _tspan(tracker, "peek_next_time"):
-            quiescent = int(_peek_next_time(st)) >= end_time
+            quiescent = entry_probe(st).next_time >= end_time
         if quiescent:
             # already quiescent: the zero-work fast path of the old driver
             # — no copy, no chunk dispatch, caller's state returned untouched

@@ -29,10 +29,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from shadow_tpu import scopes
 from shadow_tpu.engine.round import (
     _drive,
-    _peek_next_time,
     _tspan,
     check_capacity,
     effective_engine,
+    entry_probe,
     run_rounds_scan,
     state_probe,
     validate_runahead,
@@ -236,7 +236,7 @@ class ShardedRunner:
             with _tspan(tracker, "shard_state"):
                 st = shard_state(st, self.mesh)
             with _tspan(tracker, "peek_next_time"):
-                quiescent = int(_peek_next_time(st)) >= end_time
+                quiescent = entry_probe(st).next_time >= end_time
             if quiescent:
                 # already quiescent: zero-work fast path, state untouched
                 check_capacity(st)

@@ -219,8 +219,9 @@ class TrackerState:
     worker-local counters). Accumulated inside the round engines when
     EngineConfig.tracker is set, zero otherwise; never read back by the
     simulation, so the trajectory is identical either way. Leaves lead
-    with the host axis except the round counters, which are replicated
-    scalars (each shard executes the same round sequence in lockstep).
+    with the host axis except the idle-round counter, a replicated scalar
+    (each shard executes the same round sequence in lockstep; the live
+    rounds are counted on SimState, tracker on or off).
 
     Event-kind split: kind == KIND_PACKET is a packet event, kinds in
     the model's declared TCP_KIND_RANGE (TCP timer/flush, model-owned
@@ -242,7 +243,6 @@ class TrackerState:
     retrans_segs: jax.Array  # [H] i64 retransmitted segments
     queue_hwm: jax.Array  # [H] i32 event-queue occupancy high-water mark
     outbox_hwm: jax.Array  # [H] i32 outbox fill high-water mark
-    rounds_live: jax.Array  # scalar i64 rounds that ran a drain loop
     rounds_idle: jax.Array  # scalar i64 rounds skipped by the idle branch
     # Exchange high-water: the most events this shard flushed in any
     # single round (sum of outbox.fill at flush time), accumulated on
@@ -271,7 +271,6 @@ def _empty_tracker(h: int) -> TrackerState:
         retrans_segs=jnp.zeros((h,), jnp.int64),
         queue_hwm=jnp.zeros((h,), jnp.int32),
         outbox_hwm=jnp.zeros((h,), jnp.int32),
-        rounds_live=jnp.asarray(0, jnp.int64),
         rounds_idle=jnp.asarray(0, jnp.int64),
         exch_hwm=jnp.zeros((h,), jnp.int32),
         land_hwm=jnp.zeros((h,), jnp.int32),
@@ -311,6 +310,10 @@ class SimState:
     # construction (the window agreement is pmin'd), so the scalar stays
     # replicated sharded; mean window width = win_ns_sum / rounds_live.
     win_ns_sum: jax.Array  # scalar i64
+    # diagnostic: rounds that ran a drain loop (run_rounds_scan's live
+    # branch), counted beside win_ns_sum whether the tracker is on or off;
+    # every shard runs the same round sequence, so it stays replicated too
+    rounds_live: jax.Array  # scalar i64
     # the tracker plane (zeros unless EngineConfig.tracker is set)
     tracker: TrackerState
 
@@ -557,5 +560,6 @@ def init_state(
         iters_done=jnp.zeros((h,), jnp.int32),
         lanes_live=jnp.zeros((h,), jnp.int64),
         win_ns_sum=jnp.asarray(0, jnp.int64),
+        rounds_live=jnp.asarray(0, jnp.int64),
         tracker=_empty_tracker(h),
     )

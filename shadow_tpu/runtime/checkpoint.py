@@ -59,7 +59,10 @@ from shadow_tpu.utils.shadow_log import slog
 # 2: Outbox.data is [H, PAYLOAD_LANES, O]. A version-1 file holds it as
 # [H, O, PAYLOAD_LANES]: refused by version, not by the leaf-shape check
 # (which an outbox of 8 slots would pass, transposed)
-CHECKPOINT_VERSION = 2
+# 3: rounds_live is a leaf of SimState beside win_ns_sum, no longer of its
+# tracker: the leaf ORDER changed, and the two i64 scalars would pass the
+# shape check swapped
+CHECKPOINT_VERSION = 3
 
 
 class CheckpointError(ValueError):

@@ -14,7 +14,9 @@ a trace to them: the driver keeps the chunk executable of its newest
 entry here (`last_chunk`, one assignment per entry), and `chunk_table()`
 parses that executable's text into instruction -> scope when somebody asks
 (the benchmark's per-layer readers, an operator reading a `--xprof-dir`
-capture). Nothing is parsed in a run that does not ask.
+capture). Nothing is parsed in a run that does not ask. Beside it the
+driver keeps the probes of that entry's two ends (`last_probes`), for
+readers of what a replayed unit did.
 
 Every name is a single path component; nested scopes give paths:
 
@@ -61,6 +63,7 @@ Every name is a single path component; nested scopes give paths:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import hashlib
 import re
@@ -134,6 +137,24 @@ def scoped(name: str):
 # _launch_chunk0 assigns it); None until a driver has compiled one, and
 # where a driver was handed an executable compiled elsewhere.
 last_chunk = None
+
+@dataclasses.dataclass
+class EntryProbes:
+    """What a driver entry saw at its two ends: the ChunkProbe of the
+    state it started from and of its newest chunk (None until one is
+    fetched), and the rows of that state. A caller's warm state does not
+    start its counters at zero, so what the entry did is the difference."""
+
+    hosts: int
+    entry: object
+    chunk: object = None
+
+
+# The newest one-chip or sharded driver entry's probes (engine/round.py:
+# entry_probe assigns it at entry, _drive sets `chunk` at every probe
+# fetch). None until such a driver has run; nobody reads it in a run that
+# does not ask (the benchmark's readers of rounds and occupancy per unit).
+last_probes = None
 
 _memo = (None, None)  # (executable, its table)
 

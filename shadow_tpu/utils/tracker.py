@@ -30,6 +30,7 @@ tracker.c:407-430, and the worker-local SimStats fold, sim_stats.rs):
           validate_runahead
           shard_state          sharded / mesh: device_put onto the mesh
           peek_next_time       the blocking "anything to do?" round trip
+                               (one chip / sharded: the entry's probe)
           put_end_time         the end time made a device scalar
           donate_copy          the caller's state copied, leaf by leaf
           entry_probe          ensemble / mesh: replicas done at entry
@@ -41,7 +42,7 @@ tracker.c:407-430, and the worker-local SimStats fold, sim_stats.rs):
                                flight recorder, capacity, on_chunk
             host_stats_fetch   heartbeat cadence only
             state_snapshot     checkpoint cadence only
-          quiescent_restore    the last chunk's `now` / round counters
+          quiescent_restore    the last chunk's `now` / idle rounds
 
     plus the hybrid pass/upload/drain phases and worker round-trips.
     Each span also enters `jax.profiler.TraceAnnotation("shadow:<name>")`,

@@ -99,8 +99,8 @@ def _canon(st):
     return st.replace(
         outbox=ob,
         win_ns_sum=st.win_ns_sum * 0,
+        rounds_live=st.rounds_live * 0,
         tracker=st.tracker.replace(
-            rounds_live=st.tracker.rounds_live * 0,
             rounds_idle=st.tracker.rounds_idle * 0,
             queue_hwm=st.tracker.queue_hwm * 0,
             outbox_hwm=st.tracker.outbox_hwm * 0,

@@ -5,10 +5,20 @@ benchmark's configuration `fattree-10k` is its output at k=16. Here, at
 k=4 (8 edge switches, 64 hosts, the same rates, loss and `resp_bytes`):
 the program through the front door against the plain reference
 (`benchmarks/reference/pdes_ref.c`) to 3 ms of simulated time, all six
-per-host counters exact, on one device and on four virtual ones."""
+per-host counters exact, on one device and on four virtual ones.
 
+The same with cables of unequal length (`--pod-step-us 8 --edge-step-us
+3`, the benchmark's configuration `fattree-10k-cabled`, PR 34): the flows
+fall out of step, so the same span holds many more live rounds, which
+the state counts with the tracker on or off, whatever the chunking or
+the device count."""
+
+import dataclasses
+import functools
 import importlib.util
 import json
+import os
+import re
 import subprocess
 import sys
 import pathlib
@@ -19,8 +29,10 @@ import pytest
 
 ROOT = pathlib.Path(__file__).parent.parent
 GEN = ROOT / "examples" / "fattree" / "gen_fattree.py"
+CONFIGS = ROOT / "benchmarks" / "configs"
 END_NS = 3_000_000
 SEED = 2**31 + 4242
+STEPS = {"pod_step_us": 8, "edge_step_us": 3}  # fattree-10k-cabled's
 
 
 def _gen():
@@ -72,18 +84,23 @@ def test_benchmark_configuration_is_the_generators_output():
     assert doc["experimental"]["outbox_capacity"] == 256
 
 
-def _run(devices: int, **capacities):
+@functools.lru_cache(maxsize=None)
+def _run(devices: int, cabled: bool = False, rounds_per_chunk: int = 8, tracker: bool = True,
+         queue_capacity: int = 512, outbox_capacity: int = 256):
     """The k=4 world through the front door to END_NS; returns the per-host
-    counters, the last chunk's probe and the reference's counters."""
+    counters, the last chunk's probe and the reference's document."""
     from shadow_tpu.config.options import ConfigOptions
     from shadow_tpu.engine.round import host_stats
     from shadow_tpu.runtime.manager import Manager
     from shadow_tpu.runtime.scheduler import make_scheduler
 
     refworld = _refworld()
-    raw = _gen().fattree_config(k=4, hosts_per_edge=8, rounds_per_chunk=8, **capacities)
+    raw = _gen().fattree_config(
+        k=4, hosts_per_edge=8, rounds_per_chunk=rounds_per_chunk,
+        queue_capacity=queue_capacity, outbox_capacity=outbox_capacity,
+        **(STEPS if cabled else {}))
     raw["general"]["seed"] = SEED
-    raw["general"]["tracker"] = True  # high-water marks; trajectory-neutral
+    raw["general"]["tracker"] = tracker  # high-water marks; trajectory-neutral
     ref_config = json.loads(json.dumps(raw))
     config = ConfigOptions.from_dict(raw)
     world = Manager(config).build_world()
@@ -115,9 +132,10 @@ def reference(tmp_path_factory):
     return run
 
 
+@pytest.mark.parametrize("cabled", (False, True), ids=("lockstep", "cabled"))
 @pytest.mark.parametrize("devices", (1, 4))
-def test_program_matches_the_plain_reference(reference, devices):
-    got, probe, ref_config = _run(devices, queue_capacity=512, outbox_capacity=256)
+def test_program_matches_the_plain_reference(reference, devices, cabled):
+    got, probe, ref_config = _run(devices, cabled)
     want = reference(ref_config)
     assert probe.overflow == 0 and probe.drop_loss > 0
     assert int(want["packets_sent"].sum()) > 5_000  # saturating: ~110 packets a host
@@ -134,6 +152,202 @@ def test_a_refill_burst_above_half_the_outbox_neither_overflows_nor_differs(refe
     assert probe.overflow == 0 and probe.queue_overflow == 0 and probe.outbox_overflow == 0
     numbers = _refworld().compare(got, reference(ref_config))
     assert all(v == 0 for v in numbers.values()), numbers
+
+
+# --- cables of unequal length (PR 34) -------------------------------------
+
+
+def _cli(*options):
+    argv = [sys.executable, str(GEN), "--config", *options]
+    return subprocess.run(argv, capture_output=True, text=True, check=True).stdout
+
+
+def test_both_benchmark_configurations_are_the_generators_output_byte_for_byte():
+    """The defaults still write `fattree-10k.json`, to the byte; the two
+    steps write `fattree-10k-cabled.json`, and nothing is drawn."""
+    assert _cli() == (CONFIGS / "fattree-10k.json").read_text()
+    cabled = _cli("--pod-step-us", "8", "--edge-step-us", "3")
+    assert cabled == _cli("--pod-step-us", "8", "--edge-step-us", "3")
+    assert cabled == (CONFIGS / "fattree-10k-cabled.json").read_text()
+    assert json.loads(cabled) == _gen().fattree_config(**STEPS)
+
+
+def _one_way_us(doc):
+    """Shortest one-way latency (us) between every pair of the document's
+    graph nodes: the test's own Floyd-Warshall over the GML's edges."""
+    import numpy as np
+
+    gml = doc["network"]["graph"]["inline"]
+    n = gml.count("node [")
+    d = np.full((n, n), 10**9, np.int64)
+    for a, b, us in re.findall(r'edge \[ source (\d+) target (\d+) latency "(\d+) us"', gml):
+        d[int(a), int(b)] = d[int(b), int(a)] = min(d[int(a), int(b)], int(us))
+    for m in range(n):
+        d = np.minimum(d, d[:, m:m + 1] + d[m:m + 1, :])
+    return d
+
+
+def test_the_cabled_configuration_is_fattree_10k_with_unequal_paths():
+    """10,240 hosts, the same groups, processes and source; 64 distinct
+    client-to-server paths of 264-418 us where `fattree-10k` has one of
+    200; the lookahead stays the 5 us self-loop; outbox 512, 128 rounds a
+    chunk; nothing else differs."""
+    cabled = json.loads((CONFIGS / "fattree-10k-cabled.json").read_text())
+    plain = json.loads((CONFIGS / "fattree-10k.json").read_text())
+    assert sum(g["quantity"] for g in cabled["hosts"].values()) == 10_240
+    groups = list(cabled["hosts"].values())
+    clients, servers = groups[:64], groups[64:]  # tgen's rule: client i fetches from server i
+    for doc, want in ((cabled, sorted(264 + 16 * p + 6 * e for p in range(8) for e in range(8))),
+                      (plain, [200] * 64)):
+        d = _one_way_us(doc)
+        assert int(d.min()) == 5  # the self-loop: the world's lookahead, 5,000 ns
+        paths = sorted(int(d[c["network_node_id"], s["network_node_id"]])
+                       for c, s in zip(clients, servers))
+        assert paths == want
+    assert cabled["experimental"] == dict(plain["experimental"], outbox_capacity=512,
+                                          rounds_per_chunk=128)
+    assert cabled["hosts"] == plain["hosts"] and cabled["general"] == plain["general"]
+    head, head10 = cabled["x-benchmark"], plain["x-benchmark"]
+    assert "--pod-step-us 8 --edge-step-us 3" in head["source"]
+    assert "264-418 us, 64 distinct" in head["assumed"]["link_latency_us"]
+    for key in ("reduced", "reduced_why", "guarantees"):
+        assert head[key] == head10[key]
+    assert head["reduced"] == ["network.graph"]
+    differing = {k for k in head["assumed"] if head["assumed"][k] != head10["assumed"][k]}
+    assert differing == {"link_latency_us", "queue_capacity", "outbox_capacity", "rounds_per_chunk"}
+
+
+@pytest.mark.parametrize("other", [
+    dict(tracker=False), dict(rounds_per_chunk=32, tracker=False),
+    dict(rounds_per_chunk=128, tracker=False), dict(devices=4),
+], ids=("tracker-off", "chunk-32", "chunk-128", "four-devices"))
+def test_live_rounds_are_counted_whatever_the_tracker_the_chunking_or_the_devices(other):
+    """`rounds_live` rides the probe from the state itself: the same count,
+    and the same per-host counters, with the tracker off, at 8, 32 and 128
+    rounds a chunk, and over four devices."""
+    import numpy as np
+
+    other = dict(other)
+    want, want_probe, _ = _run(1, True)
+    got, probe, _ = _run(other.pop("devices", 1), True, **other)
+    assert probe.rounds_live == want_probe.rounds_live > 0
+    assert probe.win_ns_sum == want_probe.win_ns_sum
+    assert probe.window_ns_mean == want_probe.window_ns_mean > 0
+    for k in want:
+        assert np.array_equal(got[k], want[k]), k
+
+
+def test_unequal_cables_make_many_more_live_rounds_for_the_same_span():
+    """Four path lengths where there was one: the flows fall out of step
+    and the adaptive window finds an event far more often."""
+    _, lockstep, _ = _run(1, False)
+    _, cabled, _ = _run(1, True)
+    assert cabled.rounds_live > 3 * lockstep.rounds_live > 0, (cabled, lockstep)
+    assert cabled.iters / cabled.rounds_live < lockstep.iters / lockstep.rounds_live
+
+
+def test_the_cabled_cell_rehearses_end_to_end():
+    """`benchmarks/run.py --workload fattree-10k-cabled.ramp --rehearse
+    --trace 1`: 128 hosts on the CPU equal the plain reference, and the
+    unit 2-3 ms has the counts the full size has (rounds and iterations
+    depend on the paths, not on the hosts a switch): 123 live rounds,
+    1,486 drain iterations, 1.1 % of the rows live."""
+    r = subprocess.run(
+        [sys.executable, str(ROOT / "benchmarks" / "run.py"), "--workload",
+         "fattree-10k-cabled.ramp", "--seed", "7", "--seconds", "1", "--trace", "1", "--rehearse"],
+        cwd=ROOT, capture_output=True, text=True, timeout=900,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="0",
+                 XLA_FLAGS="--xla_force_host_platform_device_count=1"),
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["correct"] is True and out["rehearsal"] is True and out["failed"] == 0
+    assert all(v["value"] == 0 == v["limit"] for v in out["check"].values())
+    assert "128 hosts, 1 chip(s)" in r.stdout
+    counted = {k: m["value"] for k, m in out["metrics"].items() if m["value"] is not None}
+    assert set(counted) == {"drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct"}
+    assert counted["drain.rounds_per_unit"] == 123
+    assert counted["drain.iters_per_unit"] == 1486
+    assert 1.0 < counted["drain.occupancy_pct"] < 1.25
+
+
+def _reader(name):
+    path = ROOT / "benchmarks" / "layer_metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_the_two_readers_read_the_kept_probes_and_none_without_them(monkeypatch):
+    """`drain.rounds_per_unit` and `drain.occupancy_pct` take the
+    difference of the program's kept probes; against a program that keeps
+    none (the parent: no `scopes.last_probes`), or whose newest entry was
+    not the unit, they return None and raise nothing."""
+    import types
+
+    from shadow_tpu import scopes
+    from shadow_tpu.engine.round import ChunkProbe, PROBE_LANES
+
+    monkeypatch.syspath_prepend(str(ROOT / "benchmarks"))
+    rounds, occupancy = _reader("drain.rounds_per_unit"), _reader("drain.occupancy_pct")
+    ctx = types.SimpleNamespace(iters_per_unit=40, chips=1)
+
+    def probe(rounds_live, iters, lanes_live):
+        return dataclasses.replace(ChunkProbe.from_array([0] * PROBE_LANES),
+                                   rounds_live=rounds_live, iters=iters, lanes_live=lanes_live)
+
+    kept = scopes.EntryProbes(hosts=100, entry=probe(7, 60, 900), chunk=probe(12, 100, 980))
+    monkeypatch.setattr(scopes, "last_probes", kept)
+    assert rounds(ctx) == 5 and occupancy(ctx) == pytest.approx(100.0 * 80 / (40 * 100))
+    ctx.chips = 4  # a shard scans a quarter of the rows
+    assert occupancy(ctx) == pytest.approx(100.0 * 80 / (40 * 25))
+    ctx.iters_per_unit = 41  # the newest entry was not the unit
+    assert rounds(ctx) is None and occupancy(ctx) is None
+    ctx.iters_per_unit = 40
+    kept.chunk = None  # an entry that launched no chunk
+    assert rounds(ctx) is None and occupancy(ctx) is None
+    monkeypatch.setattr(scopes, "last_probes", None)
+    assert rounds(ctx) is None and occupancy(ctx) is None
+    monkeypatch.delattr(scopes, "last_probes")  # the parent's program
+    assert rounds(ctx) is None and occupancy(ctx) is None
+
+
+def test_benchmark_json_names_the_cabled_configuration_and_its_cell():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    config = {c["name"]: c for c in bench["configs"]}["fattree-10k-cabled"]
+    assert config["file"] == "benchmarks/configs/fattree-10k-cabled.json"
+    assert config["reduced"] == ["network.graph"] and len(config["source"]) <= 200
+    cell = {w["name"]: w for w in bench["workloads"]}["fattree-10k-cabled.ramp"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("fattree-10k-cabled", "ramp", 1)
+    params = json.loads((ROOT / "benchmarks" / "cells" / "fattree-10k-cabled.ramp.json").read_text())
+    assert (params["warm_sim_ms"], params["unit_sim_ms"], params["rehearse"]["hosts"]) == (2, 1, 128)
+    by = {m["name"]: m for m in bench["per_layer"]}
+    every = [w["name"] for w in bench["workloads"]]
+    for name, better in (("drain.rounds_per_unit", "lower"), ("drain.occupancy_pct", "higher")):
+        m = by[name]
+        assert m["workloads"][:len(every)] == every  # every cell; later cells are appended
+        assert (m["source"], m["layer"], m["moves"], m["better"]) == (
+            "program_counter", "drain", "sim_s_per_wall_s", better)
+    listed = {n for n, m in by.items() if "fattree-10k-cabled.ramp" in m["workloads"]}
+    assert len(listed) == 21
+    assert not listed & {"drain.iter_ms", "exchange.flush_ms", "exchange.flush_roofline",
+                         "driver.unit_p95_ms", "exchange.land_roofline",
+                         "exchange.collective_ms_per_unit"}
+    for name in listed:  # a cell is only ever appended: after the five that were there
+        cells = by[name]["workloads"]
+        assert set(cells[:cells.index("fattree-10k-cabled.ramp")]) <= set(every[:5])
+
+
+def test_chip_smoke_knows_the_cabled_deployment():
+    sys.path.insert(0, str(ROOT))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(str(ROOT))
+    doc, parity_stop, rehearse_hosts, _stop = chip_smoke.DEPLOYMENTS["fattree-10k-cabled"]
+    assert (ROOT / doc) == CONFIGS / "fattree-10k-cabled.json"
+    assert (parity_stop, rehearse_hosts) == ("3 ms", 128)
 
 
 def test_fattree_bulk_tcp_smoke():

@@ -40,9 +40,10 @@ def test_the_document_is_phold_10k_at_16384_hosts_a_group():
 
 
 def test_benchmark_json_names_the_configuration_and_its_cell_last():
+    """Last when PR 32 added them: the fourth configuration, the fifth cell."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert bench["configs"][-1]["name"] == "phold-512k" and bench["configs"][-1]["reduced"] == []
-    cell = bench["workloads"][-1]
+    assert bench["configs"][3]["name"] == "phold-512k" and bench["configs"][3]["reduced"] == []
+    cell = bench["workloads"][4]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         "phold-512k.steady", "phold-512k", "steady", 1)
     params = json.loads((ROOT / "benchmarks" / "cells" / "phold-512k.steady.json").read_text())
@@ -52,14 +53,16 @@ def test_benchmark_json_names_the_configuration_and_its_cell_last():
     at = names.index("exchange.count_ms_per_unit")  # later PRs append after the pair
     assert names[at:at + 2] == ["exchange.count_ms_per_unit", "exchange.land_roofline"]
     assert by["exchange.land_roofline"] == ["phold-512k.steady"]
-    assert len(by["exchange.count_ms_per_unit"]) == 5
+    assert by["exchange.count_ms_per_unit"][:5] == [w["name"] for w in bench["workloads"][:5]]
     listed = sorted(n for n, cells in by.items() if "phold-512k.steady" in cells)
-    # 16 with the cell, and the landing loop's own since PR 33
-    assert len(listed) == 17 and "exchange.pull_ms_per_unit" in listed
+    # 16 with the cell, the landing loop's own since PR 33, rounds and occupancy since PR 34
+    assert len(listed) == 19 and "exchange.pull_ms_per_unit" in listed
     assert not {"drain.iter_ms", "exchange.flush_ms",
                 "exchange.flush_roofline", "driver.unit_p95_ms"} & set(listed)
-    for cells in by.values():  # a cell is only ever appended
-        assert "phold-512k.steady" not in cells[:-1]
+    for cells in by.values():  # a cell is only ever appended: after the four that were there
+        if "phold-512k.steady" in cells:
+            assert set(cells[:cells.index("phold-512k.steady")]) <= {
+                w["name"] for w in bench["workloads"][:4]}
 
 
 def test_the_front_door_sizes_the_world_without_building_it(tmp_path):
@@ -101,9 +104,11 @@ def test_the_cell_rehearses_end_to_end():
     assert all(v["value"] == 0 == v["limit"] for v in out["check"].values())
     assert "64 hosts, 1 chip(s)" in r.stdout
     assert out["metrics"]["drain.iters_per_unit"]["value"] > 0
-    # no time, rate or share from a CPU run: the two new readers among them
+    # no time, rate or share from a CPU run: the two new readers among them;
+    # the counts keep their values (rounds and occupancy since PR 34)
     timed = {k for k, m in out["metrics"].items() if m["value"] is not None}
-    assert timed == {"drain.iters_per_unit"}
+    assert timed == {"drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct"}
+    assert out["metrics"]["drain.rounds_per_unit"]["value"] == 5  # 10 ms of a 2 ms lookahead
 
 
 def test_chip_smoke_knows_the_deployment():
@@ -114,4 +119,4 @@ def test_chip_smoke_knows_the_deployment():
         sys.path.remove(str(ROOT))
     doc, _parity_stop, rehearse_hosts, _stop = chip_smoke.DEPLOYMENTS["phold-512k"]
     assert (ROOT / doc) == CONFIGS / "phold-512k.json" and rehearse_hosts == 64
-    assert len(chip_smoke.DEPLOYMENTS) == 3
+    assert len(chip_smoke.DEPLOYMENTS) == 4

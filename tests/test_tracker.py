@@ -55,7 +55,7 @@ def _assert_leaves_exact(a, b, skip=None):
 
 TRACKER_LEAVES = (
     "ev_local", "ev_tcp", "bytes_ctrl", "bytes_data", "retrans_segs",
-    "queue_hwm", "outbox_hwm", "rounds_live", "rounds_idle",
+    "queue_hwm", "outbox_hwm", "rounds_idle",
 )
 
 
@@ -104,7 +104,8 @@ def test_tracker_on_off_trajectory_unchanged_phold():
     _assert_leaves_exact(off, on, skip=".tracker")
     for name in TRACKER_LEAVES:
         assert int(jnp.sum(getattr(off.tracker, name))) == 0, name
-    assert int(on.tracker.rounds_live) > 0
+    # the live rounds are counted on the state itself, tracker on or off
+    assert int(on.rounds_live) == int(off.rounds_live) > 0
     assert int(jnp.sum(on.tracker.ev_local)) > 0
 
 
@@ -173,7 +174,7 @@ def test_probe_tracker_lanes_consistent():
     assert p.drop_loss == int(st.packets_dropped.sum())
     assert p.queue_hwm == int(st.tracker.queue_hwm.max())
     assert p.outbox_hwm == int(st.tracker.outbox_hwm.max())
-    assert p.rounds_live == int(st.tracker.rounds_live)
+    assert p.rounds_live == int(st.rounds_live)
     assert p.rounds_live > 0
     assert p.queue_overflow == 0 and p.outbox_overflow == 0
 
@@ -397,6 +398,55 @@ def test_chrome_trace_valid_and_well_nested(tmp_path):
                 disjoint = b0 >= a1 - eps
                 contained = b1 <= a1 + eps
                 assert disjoint or contained, (a, b)
+
+
+# --- the entry's probe and its newest chunk's, kept for readers ------------
+
+
+@pytest.mark.parametrize("driver", ("single", "sharded"))
+def test_an_entry_keeps_its_probe_and_its_newest_chunks(driver):
+    """A driver entry fetches the probe of the state it starts from (its
+    `next_time` lane is the quiescence test) and keeps it, with the newest
+    chunk's, as `scopes.last_probes`: a warm state's counters are not
+    zero, so what the entry did is the difference. Tracker off: the live
+    rounds and the mean window width are counted all the same."""
+    from shadow_tpu import scopes
+
+    cfg, model, tables, st0 = _phold_world(64)
+    if driver == "sharded":
+        import numpy as np
+        from jax.sharding import Mesh
+
+        from shadow_tpu.engine.sharded import AXIS, ShardedRunner
+
+        mesh = Mesh(np.array(jax.devices()[:4]), (AXIS,))
+        runner = ShardedRunner(mesh, model, tables, cfg, rounds_per_chunk=4)
+
+        def run(st, end, **kw):
+            return runner.run_until(st, end, **kw)
+
+    else:
+
+        def run(st, end, **kw):
+            return run_until(st, end, model, tables, cfg, rounds_per_chunk=4, **kw)
+
+    warm = run(st0, 20 * NS_PER_MS)
+    probes = []
+    out = run(warm, 40 * NS_PER_MS, on_chunk=probes.append)
+    kept = scopes.last_probes
+    assert kept.hosts == 64 and kept.chunk == probes[-1]
+    assert kept.entry.rounds_live == int(warm.rounds_live) > 0
+    assert kept.entry.now == int(warm.now)
+    assert kept.entry.events_handled == int(warm.events_handled.sum())
+    assert kept.entry.iters == int(warm.iters_done.sum())
+    did = kept.chunk.rounds_live - kept.entry.rounds_live
+    assert did == int(out.rounds_live) - int(warm.rounds_live) > 0
+    assert int(out.tracker.rounds_idle) == 0  # the tracker is off
+    assert probes[-1].window_ns_mean == int(out.win_ns_sum) / int(out.rounds_live) > 0
+    # an entry that finds nothing to do keeps its probe and no chunk's
+    assert run(out, 40 * NS_PER_MS) is not None
+    assert scopes.last_probes.chunk is None
+    assert scopes.last_probes.entry.rounds_live == int(out.rounds_live)
 
 
 # --- the span tree: ids, parents, one `run` root per driver entry ---------
