@@ -2,10 +2,29 @@
 
 Replaces the reference's per-host `BinaryHeap<Reverse<Event>>`
 (reference: src/main/core/work/event_queue.rs:10-49) with a
-struct-of-arrays layout: H hosts x Q slots. Slots [0, count[h]) of row h
-hold that host's pending events in *arbitrary* order; "pop" is a two-stage
-masked argmin over the total-order key (time, tie) from events.py, and the
-freed slot is back-filled with the last valid slot so rows stay compact.
+struct-of-arrays layout: H hosts x Q slots. A row's pending events sit in
+*arbitrary* slots; a free slot is a tombstone (time == TIME_MAX, tie ==
+_I64_MAX) that keeps its stale kind / aux / data, and pushes fill free
+slots by rank.
+
+"Pop" is ONE reduction over the row and ONE gather (peek_min): the
+reduction is a masked argmin over the total-order key (time, tie) from
+events.py that also returns what it found — the tie is its minimum, and
+the slot's kind and aux ride beside its index — and the gather reads the
+slot's eight payload words, one index a host. On the chip a gather costs
+by the index (10-22 ns each), not by the byte, so the pop issues H
+indices once where picking tie, kind, aux and data each by the slot
+issued them five times (the i64 tie is two 32-bit gathers there). The
+consume half (clear_slot) rewrites the two key arrays and nothing else.
+
+Hosts are axis 0 of every leaf: the sharded runner splits each leaf there
+(engine/sharded.py), and the ensemble and mesh planes vmap over a replica
+axis in front of it. kind and aux are [H, Q] leaves of their own and the
+payload is [H, Q, 8]: eight words are a sublane tile of the chip, which
+lays the payload out with the words on the sublanes whatever Q is; kind,
+aux and the payload as ONE array of ten words a slot pad to sixteen there
+and double the chunk program's temporaries at 524,288 hosts (PERF.md
+section 6, PR 35).
 
 All operations are branch-free, fixed-shape, and vectorized over hosts so
 they trace into a single XLA computation (no per-host Python loops).
@@ -38,7 +57,10 @@ _I64_MAX = jnp.iinfo(jnp.int64).max
 
 @flax.struct.dataclass
 class EventQueue:
-    """H x Q event slots + per-host fill counts."""
+    """H x Q event slots + per-host fill counts. `time` and `tie` are the
+    key, which every pop scans and rewrites; `kind`, `aux` and `data` are
+    what a slot carries, which a pop reads (kind and aux in the key's own
+    reduction, data by one gather) and only pushes write."""
 
     time: jax.Array  # [H, Q] i64 ns; TIME_MAX in empty slots
     tie: jax.Array  # [H, Q] i64 packed (variant, src_host, seq); _I64_MAX when empty
@@ -97,6 +119,26 @@ class Popped:
         return tie_src_host(self.tie).astype(jnp.int32)
 
 
+def _first_min(key: jax.Array, *rides: jax.Array) -> "tuple[jax.Array, ...]":
+    """(the minimum of each row of `key` [H, Q], the first slot that holds
+    it as [H] i32, and every one of `rides` [H, Q] read at that slot).
+    jnp.argmin's own reduction over (value, index), with the value kept and
+    the rides carried beside the index: one pass over the row where jnp.min
+    beside jnp.argmin makes two, and no gather of the slot afterwards."""
+    index = jax.lax.broadcasted_iota(jnp.int32, key.shape, 1)
+
+    def first(a, b):
+        take_a = (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+        return tuple(jnp.where(take_a, x, y) for x, y in zip(a, b))
+
+    operands = (key, index, *rides)
+    # what loses to every slot: the largest key at an index past the row
+    top = (jnp.iinfo(key.dtype).max, jnp.iinfo(jnp.int32).max) + (0,) * len(rides)
+    init = tuple(jnp.array(t, x.dtype) for t, x in zip(top, operands))
+    return jax.lax.reduce(operands, init, first, (1,))
+
+
+@scopes.scoped(scopes.POP)
 def peek_min(q: EventQueue, want: jax.Array) -> tuple[Popped, jax.Array]:
     """Read each host's minimum event where `want[h]` and the host is
     non-empty, WITHOUT removing it. Returns (event, slot); pass the slot
@@ -106,33 +148,33 @@ def peek_min(q: EventQueue, want: jax.Array) -> tuple[Popped, jax.Array]:
     tmin = q.head_time  # [H]
     at_min = q.time == tmin[:, None]
     tie_masked = jnp.where(at_min, q.tie, _I64_MAX)
-    slot = jnp.argmin(tie_masked, axis=1)  # [H]
+    # ONE reduction over the row gives the slot, and with it what the slot
+    # holds in the [H, Q] arrays: the tie is the reduction's own minimum
+    # (on an empty row _I64_MAX, which create and clear_slot keep in every
+    # free slot, so what a gather of slot 0 would read), kind and aux ride
+    # beside the index. A gather costs per INDEX on the chip, 10-22 ns each
+    # (PERF.md), where the two more planes the pass reads cost their bytes.
+    tie, slot, kind, aux = _first_min(tie_masked, q.kind, q.aux)
     valid = want & (q.count > 0)
 
-    # Payload reads are per-row GATHERS (one index per host): ~10k-index
-    # gathers cost well under a millisecond on TPU, while the previous
-    # one-hot masked reductions re-read every [H, Q(, 8)] payload array in
-    # full — the single biggest per-iteration traffic term at bench scale
-    # (per-index cost is what matters, and it only bites at exchange
-    # scale, not at H).
-    sl1 = slot[:, None]
-
-    def pick(arr):
-        if arr.ndim == 3:
-            return jnp.take_along_axis(arr, sl1[:, :, None], axis=1)[:, 0]
-        return jnp.take_along_axis(arr, sl1, axis=1)[:, 0]
+    # The payload is the pop's ONE gather of H indices (eight words each:
+    # a sublane tile; ten words in one array pad to sixteen, PERF.md).
+    data = jnp.take_along_axis(
+        q.data, slot[:, None, None], axis=1, mode="promise_in_bounds"
+    )[:, 0]
 
     ev = Popped(
         valid=valid,
         time=tmin,  # the selected slot's time IS the cached row minimum
-        tie=pick(q.tie),
-        kind=pick(q.kind),
-        data=pick(q.data),
-        aux=pick(q.aux),
+        tie=tie,
+        kind=kind,
+        data=data,
+        aux=aux,
     )
     return ev, slot
 
 
+@scopes.scoped(scopes.POP)
 def clear_slot(q: EventQueue, slot: jax.Array, mask: jax.Array) -> EventQueue:
     """Tombstone q[h, slot[h]] where mask[h] (the consume half of a
     peek_min/clear_slot pop; see pop_min). Only the two key arrays are

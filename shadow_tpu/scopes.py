@@ -44,6 +44,11 @@ Every name is a single path component; nested scopes give paths:
                             payload's over [H, 8, outbox] (slots minor)
     drain/handle/push_self  equeue.push_self_lanes: the [H, queue] lane
     drain/pump/push_self    merges of the handler and the pump
+    drain/handle/pop        equeue.peek_min + clear_slot, the pop: one
+    drain/pump/pop          reduction over the row's keys gives the slot,
+                            its tie, its kind and its aux, ONE gather of H
+                            indices reads the slot's eight payload words,
+                            one select pass tombstones the two key arrays
     exchange                flush_outbox: flatten, bucket, clear
     exchange/collective     all_to_all / all_gather (sharded only)
     exchange/land           equeue.land_sorted: destination sort, the
@@ -77,6 +82,7 @@ TCP = "tcp"
 ROUTE = "route"
 STAGE = "stage"
 PUSH_SELF = "push_self"
+POP = "pop"
 EXCHANGE = "exchange"
 COLLECTIVE = "collective"
 LAND = "land"
@@ -95,6 +101,7 @@ SCOPES = {
     ROUTE: "drain",
     STAGE: "drain",
     PUSH_SELF: "kernels",
+    POP: "kernels",
     EXCHANGE: "exchange",
     COLLECTIVE: "exchange",
     LAND: "kernels",

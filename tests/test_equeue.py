@@ -3,8 +3,10 @@
 validated property-style against a plain Python sorted list."""
 
 import random
+import re
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -416,3 +418,197 @@ def test_push_at_time_max_rejected_loudly():
     # occupancy stays consistent: free slots == capacity - count
     free = np.asarray(q.time) == TIME_MAX
     assert free.sum(axis=1).tolist() == [Q - 1, Q]
+
+
+# --- the pop: one reduction for the slot and its tie, ONE gather for the rest --
+
+_I64_MAX = np.iinfo(np.int64).max
+_POP_SCENARIOS = ("empty_rows", "shared_minimum", "tombstones", "full_rows", "want_false")
+
+
+def _pop_world(cap, scenario, hosts=6):
+    """(queue, want): a queue written leaf by leaf, every payload word of
+    every slot distinct (free slots too: a tombstone keeps its stale
+    words), shaped by `scenario`."""
+    rng = np.random.default_rng([cap, _POP_SCENARIOS.index(scenario)])
+    live = rng.random((hosts, cap)) < {"tombstones": 0.3, "full_rows": 1.0}.get(scenario, 0.6)
+    live[:, -1] = True  # no row empty unless the scenario empties it
+    time = rng.integers(0, 40, (hosts, cap))
+    tie = rng.permutation(hosts * cap).reshape(hosts, cap) + 1000  # all distinct
+    if scenario == "empty_rows":
+        live[[0, 2]] = False
+    if scenario == "shared_minimum":
+        time[:] = rng.integers(5, 8, (hosts, cap))  # many slots share the minimum time
+        time[:, -1] = 5
+    if scenario == "tombstones":
+        live[:, 0] = False  # the first slot is stale, the minimum sits late in the row
+        time[:, -1] = 0
+    # word w of slot s of host h; w = 0, 1 are kind and aux, the rest the data lanes
+    words = (np.arange(hosts)[:, None, None] * 1_000_000
+             + np.arange(cap)[None, :, None] * 16
+             + np.arange(2 + PAYLOAD_LANES)[None, None, :]).astype(np.int32)
+    time = np.where(live, time, TIME_MAX)
+    q = equeue.EventQueue(
+        time=jnp.asarray(time, jnp.int64),
+        tie=jnp.asarray(np.where(live, tie, _I64_MAX), jnp.int64),
+        kind=jnp.asarray(words[:, :, 0]),
+        data=jnp.asarray(words[:, :, 2:]),
+        aux=jnp.asarray(words[:, :, 1]),
+        count=jnp.asarray(live.sum(1), jnp.int32),
+        overflow=jnp.zeros((hosts,), jnp.int32),
+        head_time=jnp.asarray(time.min(1), jnp.int64),
+    )
+    want = rng.random(hosts) < 0.5 if scenario == "want_false" else np.ones(hosts, bool)
+    return q, jnp.asarray(want)
+
+
+def _plain_pop(q, want):
+    """The pop, host by host in plain Python over NumPy copies of the
+    leaves: (per host the head event with its slot, or None; the leaves after)."""
+    time, tie, count = np.array(q.time), np.array(q.tie), np.array(q.count)
+    kind, aux, data = np.asarray(q.kind), np.asarray(q.aux), np.asarray(q.data)
+    events = []
+    for h in range(time.shape[0]):
+        live = [s for s in range(time.shape[1]) if time[h, s] != TIME_MAX]
+        if not live:
+            events.append(None)
+            continue
+        s = min(live, key=lambda s: (time[h, s], tie[h, s], s))
+        events.append(dict(
+            slot=s, time=int(time[h, s]), tie=int(tie[h, s]), kind=int(kind[h, s]),
+            aux=int(aux[h, s]), data=[int(x) for x in data[h, s]],
+        ))
+        if want[h]:
+            time[h, s], tie[h, s] = TIME_MAX, _I64_MAX
+            count[h] -= 1
+    return events, dict(time=time, tie=tie, kind=kind, aux=aux, data=data, count=count,
+                        head_time=time.min(1))
+
+
+@pytest.mark.parametrize("scenario", _POP_SCENARIOS)
+@pytest.mark.parametrize("cap", [4, 64, 384])
+def test_pop_equals_a_plain_numpy_pop(cap, scenario):
+    q, want = _pop_world(cap, scenario)
+    pop = jax.jit(equeue.pop_min)
+    for _ in range(3):  # three pops in a row: tombstones of this very pop included
+        events, after = _plain_pop(q, np.asarray(want))
+        ev, q = pop(q, want)
+        for h, e in enumerate(events):
+            assert bool(ev.valid[h]) == (e is not None and bool(want[h])), h
+            if e is None:  # an empty row peeks the free slots' keys
+                assert int(ev.time[h]) == TIME_MAX and int(ev.tie[h]) == _I64_MAX
+                continue
+            got = dict(time=int(ev.time[h]), tie=int(ev.tie[h]), kind=int(ev.kind[h]),
+                       aux=int(ev.aux[h]), data=[int(x) for x in ev.data[h]])
+            assert got == {k: v for k, v in e.items() if k != "slot"}, (h, e["slot"])
+        for name, leaf in after.items():
+            np.testing.assert_array_equal(np.asarray(getattr(q, name)), leaf, err_msg=name)
+        assert int(q.overflow.sum()) == 0
+
+
+def _drain_row(q, host):
+    """Every event of `host` in pop order as (time, tie, kind, aux, data)."""
+    out = []
+    while int(q.count[host]):
+        ev, q = equeue.pop_min(q, jnp.ones((q.num_hosts,), bool))
+        out.append((int(ev.time[host]), int(ev.tie[host]), int(ev.kind[host]),
+                    int(ev.aux[host]), tuple(int(x) for x in ev.data[host])))
+    return out
+
+
+@pytest.mark.parametrize("cap", [8, 64])
+@pytest.mark.parametrize("writer", ["push_self_lanes", "land_sorted"])
+def test_popped_words_are_the_pushed_ones(writer, cap):
+    """kind, aux and the eight data lanes come back word for word: after a
+    lane push, and after a landing of LAND_LANES + 1 arrivals to one row
+    (two passes of its loop), onto a row that holds a tombstone."""
+    hosts, n = 3, equeue.LAND_LANES + 1
+    rng = np.random.default_rng(cap)
+    q = equeue.create(hosts, cap)
+    q = equeue.push_self(  # one event a row, popped again: slot 0 is stale, not empty
+        q, jnp.ones((hosts,), bool), jnp.zeros((hosts,), jnp.int64),
+        jnp.arange(hosts, dtype=jnp.int64), jnp.full((hosts,), 9, jnp.int32),
+        jnp.full((hosts, PAYLOAD_LANES), -7, jnp.int32), jnp.full((hosts,), 77, jnp.int32),
+    )
+    _, q = equeue.pop_min(q, jnp.ones((hosts,), bool))
+    time = rng.integers(1, 30, (hosts, n))
+    tie = rng.permutation(hosts * n).reshape(hosts, n)
+    kind = rng.integers(0, 5, (hosts, n)).astype(np.int32)
+    aux = rng.integers(0, 1 << 24, (hosts, n)).astype(np.int32)
+    data = rng.integers(-(1 << 31), 1 << 31, (hosts, n, PAYLOAD_LANES)).astype(np.int32)
+    if writer == "push_self_lanes":
+        q = equeue.push_self_lanes(
+            q, jnp.ones((hosts, n), bool), jnp.asarray(time, jnp.int64),
+            jnp.asarray(tie, jnp.int64), jnp.asarray(kind), jnp.asarray(data), jnp.asarray(aux),
+        )
+        rows = range(hosts)
+    else:  # all n arrivals to row 1, shuffled among invalid entries
+        m = 3 * n
+        at = rng.permutation(m)[:n]
+
+        def spread(x, dtype=jnp.int32):  # x's n rows at `at` among m, zeros between
+            out = np.zeros((m,) + x.shape[1:], x.dtype)
+            out[at] = x
+            return jnp.asarray(out, dtype)
+
+        q, max_land = equeue.land_sorted(
+            q, jnp.ones((m,), jnp.int32), spread(np.ones(n, bool), bool),
+            spread(time[1], jnp.int64), spread(tie[1], jnp.int64),
+            spread(kind[1]), spread(data[1]), spread(aux[1]),
+        )
+        assert int(max_land) == n and int(equeue.land_passes(max_land)) == 2
+        rows = [1]
+    assert int(q.overflow.sum()) == 0
+    for h in rows:
+        pushed = sorted(
+            (int(time[h, l]), int(tie[h, l]), int(kind[h, l]), int(aux[h, l]),
+             tuple(int(x) for x in data[h, l])) for l in range(n)
+        )
+        assert _drain_row(q, h) == pushed, h
+
+
+def _gathers(jaxpr):
+    """gather equations of a jaxpr, those of the functions it calls included."""
+    return sum(
+        (eqn.primitive.name == "gather")
+        + sum(_gathers(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+        for eqn in jaxpr.eqns
+    )
+
+
+def _reads_a_gather(jaxpr, outvar):
+    """Whether `outvar` of `jaxpr` is computed from any gather's result (an
+    equation that calls a function with a gather inside counts whole)."""
+    needed = {outvar}
+    for eqn in reversed(jaxpr.eqns):
+        if not needed & set(eqn.outvars):
+            continue
+        if eqn.primitive.name == "gather" or any(
+            _gathers(sub) for sub in jax.core.jaxprs_in_params(eqn.params)
+        ):
+            return True
+        needed |= {v for v in eqn.invars if isinstance(v, jax.extend.core.Var)}
+    return False
+
+
+def test_lowered_pop_holds_one_gather_and_only_the_payload_reads_it():
+    q, want = _pop_world(64, "tombstones")
+    text = jax.jit(equeue.pop_min).lower(q, want).as_text()
+    # the module's functions by name; a count follows the calls a body makes
+    bodies = {
+        m.group(1): m.group(0) for m in
+        re.finditer(r"func\.func (?:public |private )?@(\w+)\((?s:.*?)\n  }\n", text)
+    }
+
+    def count(fn):
+        return bodies[fn].count('"stablehlo.gather"(') + sum(
+            count(callee) for callee in re.findall(r"call @(\w+)\(", bodies[fn]))
+
+    assert count("main") == 1, text
+    closed = jax.make_jaxpr(equeue.pop_min)(q, want)
+    out = jax.eval_shape(equeue.pop_min, q, want)
+    paths = [jax.tree_util.keystr(p) for p, _ in jax.tree_util.tree_leaves_with_path(out)]
+    by_path = dict(zip(paths, closed.jaxpr.outvars))
+    for field in ("time", "tie", "kind", "aux"):  # out of the row's one reduction
+        assert not _reads_a_gather(closed.jaxpr, by_path[f"[0].{field}"]), field
+    assert _reads_a_gather(closed.jaxpr, by_path["[0].data"])  # the check sees the one

@@ -330,7 +330,7 @@ def test_benchmark_json_names_the_cabled_configuration_and_its_cell():
         assert (m["source"], m["layer"], m["moves"], m["better"]) == (
             "program_counter", "drain", "sim_s_per_wall_s", better)
     listed = {n for n, m in by.items() if "fattree-10k-cabled.ramp" in m["workloads"]}
-    assert len(listed) == 21
+    assert len(listed) >= 21  # the 21 of PR 34; later PRs append theirs (PR 35: the pop's)
     assert not listed & {"drain.iter_ms", "exchange.flush_ms", "exchange.flush_roofline",
                          "driver.unit_p95_ms", "exchange.land_roofline",
                          "exchange.collective_ms_per_unit"}

@@ -55,8 +55,9 @@ def test_benchmark_json_names_the_configuration_and_its_cell_last():
     assert by["exchange.land_roofline"] == ["phold-512k.steady"]
     assert by["exchange.count_ms_per_unit"][:5] == [w["name"] for w in bench["workloads"][:5]]
     listed = sorted(n for n, cells in by.items() if "phold-512k.steady" in cells)
-    # 16 with the cell, the landing loop's own since PR 33, rounds and occupancy since PR 34
-    assert len(listed) == 19 and "exchange.pull_ms_per_unit" in listed
+    # 16 with the cell, the landing loop's own since PR 33, rounds and occupancy since
+    # PR 34, the pop's since PR 35: a floor, later PRs append theirs
+    assert len(listed) >= 20 and {"exchange.pull_ms_per_unit", "drain.pop_ms_per_unit"} <= set(listed)
     assert not {"drain.iter_ms", "exchange.flush_ms",
                 "exchange.flush_roofline", "driver.unit_p95_ms"} & set(listed)
     for cells in by.values():  # a cell is only ever appended: after the four that were there

@@ -71,6 +71,21 @@ def test_checkpoint_template_shape_mismatch(tmp_path):
         load_checkpoint(path, other, "fp")
 
 
+def test_checkpoint_of_an_older_format_is_refused(tmp_path, monkeypatch):
+    """A file of the version before this build's is refused by its version,
+    in words that name both, before any leaf is read."""
+    from shadow_tpu.runtime import checkpoint
+
+    now = checkpoint.CHECKPOINT_VERSION
+    _cfg, _model, _tables, st0 = _phold_world()
+    path = str(tmp_path / "ckpt.npz")
+    monkeypatch.setattr(checkpoint, "CHECKPOINT_VERSION", now - 1)
+    save_checkpoint(path, state_to_host(st0), {"fingerprint": "fp"})
+    monkeypatch.undo()
+    with pytest.raises(CheckpointError, match=f"format version {now - 1}.*reads version {now}"):
+        load_checkpoint(path, st0, "fp")
+
+
 def _interrupt_then_resume(cfg, model, tables, st0, end, ckpt_dir,
                            interval_ns, interrupt_at_ns, rpc=4):
     """Drive run_until with a checkpoint tap until the (deterministic)

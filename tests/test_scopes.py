@@ -39,7 +39,7 @@ END = jnp.asarray(30_000_000, jnp.int64)
 ROUNDS = 2
 CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
-    "window", "drain", "drain/handle", "drain/handle/push_self",
+    "window", "drain", "drain/handle", "drain/handle/push_self", "drain/handle/pop",
     "drain/handle/stage", "drain/handle/route", "exchange", "exchange/land",
     "exchange/land/count", "exchange/land/pull", "probe",
 }
@@ -47,7 +47,8 @@ EVERYWHERE = {
 TGEN = EVERYWHERE | {"drain/handle/netstack", "drain/handle/tcp"}
 EXPECTED = {
     "tgen-plain": TGEN,
-    "tgen-pump": TGEN | {"drain/pump", "drain/pump/push_self", "drain/pump/route"},
+    "tgen-pump": TGEN | {"drain/pump", "drain/pump/push_self", "drain/pump/route",
+                         "drain/pump/pop"},
     # phold publishes no pump_spec: every engine value takes the handler
     "phold-plain": EVERYWHERE,
     "phold-pump": EVERYWHERE,
@@ -215,6 +216,9 @@ def test_a_table_without_a_scope_is_refused_loudly(chunks, monkeypatch):
     # equeue.land_sorted's while loop, under the landing (PR 33)
     (scopes.PULL, "kernels",
      "jit(_run_chunk)/while/body/exchange/land/pull/while/body/gather", "exchange/land/pull"),
+    # equeue.peek_min + clear_slot, as the handler calls them (PR 35)
+    (scopes.POP, "kernels",
+     "jit(_run_chunk)/while/body/drain/while/body/handle/pop/gather", "drain/handle/pop"),
 ])
 def test_a_later_scope_is_in_the_list_and_in_the_digest(name, layer, op, path):
     """The scope names its layer, and the chunk functions' names moved
