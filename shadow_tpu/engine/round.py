@@ -522,7 +522,8 @@ def flush_outbox(
 
     In both modes the destination pops by the (time, tie) key, so
     delivery slot order — which differs between the modes — cannot
-    affect results.
+    affect results. Every flush books how the landing's loop engaged
+    (TrackerState.land_hwm / land_passes), cfg.tracker or not.
     """
     # Empty rounds skip the exchange sorts entirely (lax.cond on a scalar
     # any-reduce). Sharded: the predicate is made mesh-uniform with a
@@ -532,7 +533,7 @@ def flush_outbox(
         has_traffic = _has_traffic(st, axis_name)
 
         def _skip(st):
-            return st
+            return st, jnp.zeros((), jnp.int32)
 
         def _do_flush(st):
             return _flush_outbox_traffic(st, axis_name, cfg)
@@ -540,8 +541,21 @@ def flush_outbox(
         if not isinstance(has_traffic, jax.core.Tracer):
             # eager path (round_body_debug/tests): concrete predicate — an
             # eager lax.cond over this state is pathological for the tracer
-            return _do_flush(st) if bool(has_traffic) else st
-        return jax.lax.cond(has_traffic, _do_flush, _skip, st)
+            st, max_land = _do_flush(st) if bool(has_traffic) else _skip(st)
+        else:
+            st, max_land = jax.lax.cond(has_traffic, _do_flush, _skip, st)
+    # how the landing's loop engaged (row 0, like exch_hwm): the most
+    # arrivals one destination landed in one round — what LAND_LANES is
+    # sized from — and the passes made over all landings. Counted in every
+    # program (cfg.tracker or not): two one-element updates a flush.
+    with jax.named_scope(scopes.PROBE):
+        tr = st.tracker
+        return st.replace(
+            tracker=tr.replace(
+                land_hwm=tr.land_hwm.at[0].max(max_land),
+                land_passes=tr.land_passes.at[0].add(equeue.land_passes(max_land)),
+            )
+        )
 
 
 def _payload_words(ob: Outbox) -> jax.Array:
@@ -553,7 +567,9 @@ def _payload_words(ob: Outbox) -> jax.Array:
 
 def _flush_outbox_traffic(
     st: SimState, axis_name: Optional[str], cfg: "EngineConfig | None" = None
-) -> SimState:
+) -> "tuple[SimState, jax.Array]":
+    """The flush of a round that staged something: (state, the most
+    arrivals one destination landed: land_sorted's max_land)."""
     ob = st.outbox
     h_local, o_cap = ob.valid.shape
     m = h_local * o_cap
@@ -583,25 +599,26 @@ def _flush_outbox_traffic(
                 cap = m
             # bucket by destination shard; stable sort keeps emission order
             # within each bucket (determinism is key-driven anyway)
-            pos = jnp.arange(m)
-            shard_of = jnp.where(valid, dst // h_local, d).astype(jnp.int32)
-            order = jnp.argsort(shard_of, stable=True)
-            sh_s = shard_of[order]
-            valid_s = valid[order]
-            seg_start = jnp.concatenate([jnp.ones((1,), bool), sh_s[1:] != sh_s[:-1]])
-            start_pos = jax.lax.cummax(jnp.where(seg_start, pos, -1))
-            rank = (pos - start_pos).astype(jnp.int32)
-            fits = valid_s & (rank < cap)
-            sdst = jnp.where(fits, sh_s, d)
-            sslot = jnp.where(fits, rank, cap)
-            a2a_over = jnp.sum(valid_s & ~fits).astype(jnp.int32)
-            overflow_extra = (
-                a2a_over if overflow_extra is None else overflow_extra + a2a_over
-            )
+            with jax.named_scope(scopes.BUCKET):
+                pos = jnp.arange(m)
+                shard_of = jnp.where(valid, dst // h_local, d).astype(jnp.int32)
+                order = jnp.argsort(shard_of, stable=True)
+                sh_s = shard_of[order]
+                valid_s = valid[order]
+                seg_start = jnp.concatenate(
+                    [jnp.ones((1,), bool), sh_s[1:] != sh_s[:-1]]
+                )
+                start_pos = jax.lax.cummax(jnp.where(seg_start, pos, -1))
+                rank = (pos - start_pos).astype(jnp.int32)
+                fits = valid_s & (rank < cap)
+                sdst = jnp.where(fits, sh_s, d)
+                sslot = jnp.where(fits, rank, cap)
+                overflow_extra = jnp.sum(valid_s & ~fits).astype(jnp.int32)
 
             def to_peers(x, fill):
-                buf = jnp.full((d, cap) + x.shape[1:], fill, x.dtype)
-                buf = buf.at[sdst, sslot].set(x[order], mode="drop")
+                with jax.named_scope(scopes.BUCKET):
+                    buf = jnp.full((d, cap) + x.shape[1:], fill, x.dtype)
+                    buf = buf.at[sdst, sslot].set(x[order], mode="drop")
                 with jax.named_scope(scopes.COLLECTIVE):
                     got = jax.lax.all_to_all(buf, axis_name, 0, 0, tiled=False)
                 return got.reshape((d * cap,) + x.shape[1:])
@@ -644,19 +661,7 @@ def _flush_outbox_traffic(
     )
     if overflow_extra is not None:
         fresh = fresh.replace(overflow=fresh.overflow.at[0].add(overflow_extra))
-    st = st.replace(queue=queue, outbox=fresh)
-    if cfg is not None and cfg.tracker:
-        # how the landing's loop engaged (row 0, like exch_hwm): the most
-        # arrivals one destination landed in one round — what LAND_LANES
-        # is sized from — and the passes made over all landings
-        tr = st.tracker
-        st = st.replace(
-            tracker=tr.replace(
-                land_hwm=tr.land_hwm.at[0].max(max_land),
-                land_passes=tr.land_passes.at[0].add(equeue.land_passes(max_land)),
-            )
-        )
-    return st
+    return st.replace(queue=queue, outbox=fresh), max_land
 
 
 def run_round(
@@ -745,30 +750,28 @@ def run_round(
         st, iters = jax.lax.while_loop(
             cond, body, (st, jnp.asarray(0, jnp.int32))
         )
-    if cfg.tracker:
-        # Sample occupancy high-water marks at the two per-round peaks:
-        # the outbox right before the flush empties it, and the queue
-        # right after the flush delivers the exchanged packets. Sampled
-        # per round (not per iteration), identically in every engine.
-        with jax.named_scope(scopes.PROBE):
-            st = st.replace(
-                tracker=st.tracker.replace(
-                    outbox_hwm=jnp.maximum(
-                        st.tracker.outbox_hwm, st.outbox.fill
-                    ),
-                    queue_hwm=jnp.maximum(
-                        st.tracker.queue_hwm, st.queue.count
-                    ),
-                    # per-round exchange traffic high-water (row 0, like
-                    # iters_done): sum of staged events right before the
-                    # flush — the measured figure that sizes a2a buckets
-                    # (sharded.auto_a2a_capacity) and the exchange
-                    # occupancy CapacityError reports
-                    exch_hwm=st.tracker.exch_hwm.at[0].max(
-                        jnp.sum(st.outbox.fill).astype(jnp.int32)
-                    ),
-                )
+    with jax.named_scope(scopes.PROBE):
+        # per-round exchange traffic high-water (row 0, like iters_done):
+        # sum of staged events right before the flush — the measured
+        # figure that sizes a2a buckets (sharded.auto_a2a_capacity) and
+        # the exchange occupancy CapacityError reports. Counted in every
+        # program (cfg.tracker or not): one [H] sum a live round.
+        tr = st.tracker.replace(
+            exch_hwm=st.tracker.exch_hwm.at[0].max(
+                jnp.sum(st.outbox.fill).astype(jnp.int32)
             )
+        )
+        if cfg.tracker:
+            # Sample occupancy high-water marks at the two per-round
+            # peaks: the outbox right before the flush empties it, and the
+            # queue right after the flush delivers the exchanged packets.
+            # Sampled per round (not per iteration), identically in every
+            # engine. A pass over [H] each: the tracker plane's.
+            tr = tr.replace(
+                outbox_hwm=jnp.maximum(tr.outbox_hwm, st.outbox.fill),
+                queue_hwm=jnp.maximum(tr.queue_hwm, st.queue.count),
+            )
+        st = st.replace(tracker=tr)
     st = flush_outbox(st, axis_name, cfg)
     if cfg.tracker:
         with jax.named_scope(scopes.PROBE):
@@ -952,7 +955,8 @@ def _peek_capacity(st: SimState) -> jax.Array:
 # The remaining lanes are the tracker plane's sync-free aggregates
 # (docs/observability.md): the queue/outbox overflow split (capacity
 # diagnostics — always live), drop reasons (always live), and the
-# TrackerState sums/maxima (zero unless cfg.tracker). Heartbeats read
+# TrackerState sums/maxima (zero unless cfg.tracker, but the exchange's
+# three marks, lanes 22-24, which every program counts). Heartbeats read
 # these instead of ever fetching [H]-shaped state mid-run.
 
 PROBE_NEXT_TIME = 0
@@ -983,11 +987,11 @@ PROBE_ITERS = 19
 PROBE_LANES_LIVE = 20
 PROBE_WIN_NS = 21
 # exchange traffic high-water: most events any shard flushed in one
-# round (tracker plane, pmax'd sharded) — feeds measured a2a bucket
+# round (always live, pmax'd sharded) — feeds measured a2a bucket
 # sizing (sharded.auto_a2a_capacity) and the exchange-occupancy figure
 # in CapacityError
 PROBE_EXCH_HWM = 22
-# how the landing's loop engaged (tracker plane; equeue.land_sorted): the
+# how the landing's loop engaged (always live; equeue.land_sorted): the
 # most arrivals one destination landed in one round (pmax'd sharded) and
 # the passes its loop made over all landings (psum'd: a shard's loop runs
 # to its own busiest destination)
@@ -1076,13 +1080,13 @@ class ChunkProbe:
     iters: int
     lanes_live: int
     win_ns_sum: int
-    # most events any shard flushed in one round (tracker plane; 0 when
-    # cfg.tracker is off) — the measured per-round exchange traffic
+    # most events any shard flushed in one round (cfg.tracker on or off)
+    # — the measured per-round exchange traffic
     exch_hwm: int
-    # the landing's loop (tracker plane; 0 when cfg.tracker is off): the
-    # most arrivals one destination landed in one round, and the passes
-    # made over all landings (equeue.land_passes of each round's mark;
-    # like iters it depends on how the hosts are split over chips)
+    # the landing's loop (cfg.tracker on or off): the most arrivals one
+    # destination landed in one round, and the passes made over all
+    # landings (equeue.land_passes of each round's mark; like iters it
+    # depends on how the hosts are split over chips)
     land_hwm: int
     land_passes: int
 
@@ -1126,7 +1130,9 @@ def entry_probe(st: SimState) -> ChunkProbe:
     does not start its counters at zero). Kept as `scopes.last_probes`,
     where `_drive` puts the newest chunk's probe beside it."""
     probe = ChunkProbe.from_array(jax.device_get(_state_probe_jit(st)))
-    scopes.last_probes = scopes.EntryProbes(st.num_hosts, probe)
+    scopes.last_probes = scopes.EntryProbes(
+        st.num_hosts, int(st.outbox.valid.size), probe
+    )
     return probe
 
 
@@ -1149,8 +1155,8 @@ class CapacityError(RuntimeError):
     bytes_current: int = 0
     bytes_regrown: int = 0
     # exchange-pool occupancy high-water (most events flushed in one
-    # round, PROBE_EXCH_HWM; 0 without cfg.tracker) — the figure that
-    # says whether an a2a bucket was sized too small
+    # round, PROBE_EXCH_HWM) — the figure that says whether an a2a
+    # bucket was sized too small
     exchange_hwm: int = 0
     shard_detail: "str | None" = None
     # ensemble runs (engine/ensemble.py): index of the replica whose
@@ -1370,11 +1376,12 @@ def _capacity_error(
 ) -> CapacityError:
     """The split (when known — it rides the probe's dedicated lanes, so
     every driver has it) names WHICH fixed-slot counter saturated; the
-    high-water marks (tracker plane, nonzero only with cfg.tracker) say
-    how close to the rim the other one ran, the exchange high-water
-    (PROBE_EXCH_HWM) reports the pool occupancy an exchange-side drop
-    was up against, and the landing's (PROBE_LAND_HWM) the fan-in of the
-    busiest destination of one round."""
+    per-host high-water marks (tracker plane, nonzero only with
+    cfg.tracker) say how close to the rim the other one ran, the exchange
+    high-water (PROBE_EXCH_HWM, counted in every program) reports the
+    pool occupancy an exchange-side drop was up against, and the
+    landing's (PROBE_LAND_HWM, likewise) the fan-in of the busiest
+    destination of one round."""
     if queue_ov is None:
         which = "queue.overflow/outbox.overflow"
     else:

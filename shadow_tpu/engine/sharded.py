@@ -56,8 +56,9 @@ def auto_a2a_capacity(
     mutex for, worker.rs:619-629).
 
     With `measured_hwm` — the per-round per-shard exchange high-water
-    from a prior run's probe (ChunkProbe.exch_hwm, accumulated under
-    cfg.tracker) — the bucket derives from traffic actually observed:
+    from a prior run's probe (ChunkProbe.exch_hwm, which every program
+    counts, cfg.tracker or not) — the bucket derives from traffic
+    actually observed:
     any peer receives at most what one source shard flushed in a round,
     so hwm-sized buckets provably never overflow on the measured
     trajectory; a 25% margin covers workload drift between the

@@ -249,15 +249,18 @@ class TrackerState:
     # row 0 like SimState.iters_done so the leaf stays host-led under
     # sharding. This is the measured per-round traffic that sizes
     # all_to_all buckets (sharded.auto_a2a_capacity) and the exchange
-    # occupancy figure CapacityError reports.
+    # occupancy figure CapacityError reports. Counted in every program,
+    # cfg.tracker or not (one [H] sum a live round), like the two below.
     exch_hwm: jax.Array  # [H] i32
     # How the landing's loop engaged (equeue.land_sorted), on row 0 like
     # exch_hwm: the most arrivals ONE destination of this shard landed in
     # one round (the figure equeue.LAND_LANES is sized from), and the
     # passes the loop made, summed over the landings: ceil(that round's
-    # mark / LAND_LANES) each. Exact for a seed on one plane; like
-    # iters_done, land_passes depends on how the hosts are split over
-    # chips, so comparisons across planes leave it out.
+    # mark / LAND_LANES) each. Exact for a seed on one plane; the three
+    # are kept per shard on the shard's row 0 and, like iters_done,
+    # land_passes depends on how the hosts are split over chips, so
+    # comparisons across planes leave the three out
+    # (tests/test_mesh.py per_shard_leaf).
     land_hwm: jax.Array  # [H] i32
     land_passes: jax.Array  # [H] i32
 

@@ -438,9 +438,10 @@ def land_sorted(
     valid = valid & (time < TIME_MAX) & (dst >= 0) & (dst < h)
 
     # S: group by destination (stable; invalids sort last)
-    key1 = jnp.where(valid, dst, h).astype(jnp.int32)
-    pos = jnp.arange(m, dtype=jnp.int32)
-    _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
+    with jax.named_scope(scopes.SORT):
+        key1 = jnp.where(valid, dst, h).astype(jnp.int32)
+        pos = jnp.arange(m, dtype=jnp.int32)
+        _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
     cnt, begin = run_bounds(key1, h)
 
     # G: the payload as 32-bit words, word-major [W, M]: time and tie as
@@ -457,9 +458,10 @@ def land_sorted(
         low = jax.lax.bitcast_convert_type(low, jnp.uint32)
         return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
 
-    words = jnp.concatenate(
-        [jnp.stack([lo(time), hi(time), lo(tie), hi(tie), kind, aux]), data.T]
-    )
+    with jax.named_scope(scopes.PACK):
+        words = jnp.concatenate(
+            [jnp.stack([lo(time), hi(time), lo(tie), hi(tie), kind, aux]), data.T]
+        )
 
     # P: each row's free slots by rank, and how many arrivals it lands
     free = q.time == TIME_MAX  # [H, Q]

@@ -70,6 +70,9 @@ SUMMARY_FIELDS = (
     "drops",
     "queue_hwm",
     "outbox_hwm",
+    "land_passes",
+    "exch_hwm",
+    "land_hwm",
     "device_bytes_in_use",
     "device_peak_bytes",
 )
@@ -250,6 +253,12 @@ class FlightRecorder:
             "drops": d("drop_loss") + d("drop_codel") + d("drop_unroutable"),
             "queue_hwm": p.queue_hwm,
             "outbox_hwm": p.outbox_hwm,
+            # the exchange's series, counted with or without --tracker:
+            # the chunk's landing passes, and the running marks of staged
+            # entries a shard a round and arrivals a destination a round
+            "land_passes": d("land_passes"),
+            "exch_hwm": p.exch_hwm,
+            "land_hwm": p.land_hwm,
             "events_total": p.events_handled,
             "packets_total": p.packets_sent,
             "recoveries": self.counters["recoveries"],

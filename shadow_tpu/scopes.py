@@ -49,12 +49,19 @@ Every name is a single path component; nested scopes give paths:
                             its tie, its kind and its aux, ONE gather of H
                             indices reads the slot's eight payload words,
                             one select pass tombstones the two key arrays
-    exchange                flush_outbox: flatten, bucket, clear
+    exchange                flush_outbox: flatten, clear, has-traffic test
+    exchange/bucket         sharded all_to_all only: the entries' shard,
+                            the stable argsort by it, each entry's rank in
+                            its peer's bucket, and per array the
+                            [peers, capacity] buffer and the scatter into it
     exchange/collective     all_to_all / all_gather (sharded only)
-    exchange/land           equeue.land_sorted: destination sort, the
-                            runs' bounds, the payload packed as words,
-                            each row's free-slot ranks (no push_self
-                            under land)
+    exchange/land           equeue.land_sorted's own: each row's free-slot
+                            ranks (a cumsum over [H, Q]) and how many
+                            arrivals it lands (no push_self under land)
+    exchange/land/sort      the one stable sort of (destination, position)
+                            over the M flattened entries, and its key
+    exchange/land/pack      the payload packed where it lies as [14, M]
+                            32-bit words
     exchange/land/count     equeue.run_bounds: each destination's arrival
                             count and the start of its run in sorted order
     exchange/land/pull      the landing's while loop: a pass pulls
@@ -62,8 +69,10 @@ Every name is a single path component; nested scopes give paths:
                             through the sort's permutation and selects
                             them into the rows' free slots; as many
                             passes as the busiest destination needs
-    probe                   state_probe and the tracker plane's per-round
-                            high-water marks
+    probe                   state_probe, the exchange's three marks of a
+                            round (staged entries, fan-in, landing
+                            passes: always on) and the tracker plane's
+                            per-host high-water marks
 """
 
 from __future__ import annotations
@@ -88,6 +97,9 @@ COLLECTIVE = "collective"
 LAND = "land"
 COUNT = "count"
 PULL = "pull"
+SORT = "sort"
+PACK = "pack"
+BUCKET = "bucket"
 PROBE = "probe"
 
 # scope name -> layer of PERF.md / BENCHMARK.json that owns its time
@@ -107,6 +119,9 @@ SCOPES = {
     LAND: "kernels",
     COUNT: "kernels",
     PULL: "kernels",
+    SORT: "kernels",
+    PACK: "kernels",
+    BUCKET: "exchange",
     PROBE: "driver",
 }
 
@@ -149,10 +164,14 @@ last_chunk = None
 class EntryProbes:
     """What a driver entry saw at its two ends: the ChunkProbe of the
     state it started from and of its newest chunk (None until one is
-    fetched), and the rows of that state. A caller's warm state does not
-    start its counters at zero, so what the entry did is the difference."""
+    fetched), the rows of that state and its outbox slots (rows x outbox
+    capacity, over all shards: what the flushes of a round flatten, so
+    staged entries over it is a fill share). A caller's warm state does
+    not start its counters at zero, so what the entry did is the
+    difference."""
 
     hosts: int
+    outbox_slots: int
     entry: object
     chunk: object = None
 
@@ -186,8 +205,10 @@ def scope_path(op_name: str) -> str:
     """`drain/handle/push_self` from
     `jit(_run_chunk)/while/body/drain/while/body/handle/push_self/select_n`:
     the components of an `op_name` that are scope names, in order (of an
-    `a;b` that XLA wrote for two instructions it merged, the first's)."""
-    return "/".join(p for p in op_name.split(";")[0].split("/") if p in SCOPES)
+    `a;b` that XLA wrote for two instructions it merged, the first's). The
+    last component is the primitive's own name and no scope: a `sort`
+    under `exchange/bucket` is not under a scope `sort`."""
+    return "/".join(p for p in op_name.split(";")[0].split("/")[:-1] if p in SCOPES)
 
 
 def parse_hlo_text(text: str) -> dict:
@@ -198,15 +219,19 @@ def parse_hlo_text(text: str) -> dict:
     `s32[3932160,15]` of the instruction text (of a tuple, its first
     element's), as a device trace prints it. The innermost scope is the
     whole path (`exchange/land/push_self`), the outermost its first
-    component (`exchange`). A fusion carries the `op_name` of its root. An
+    component (`exchange`). A fusion carries the `op_name` of its root;
+    one whose root lost it (the chip's compiler merges the two 32-bit
+    halves of a 64-bit scatter into one scatter that carries none) takes
+    the deepest scope that the instructions inside it agree on. An
     instruction whose `op_name` names no scope, or that has none (the
     compiler's own: a copy, a rewritten reduction, a cumulative sum's
     helper), belongs where it runs: to the scope of the while or
     conditional whose body holds it. Where no scope encloses that body
     either, both scopes are "" for an instruction with an `op_name` and
     None for one without."""
-    comp, rows = None, []  # rows: (computation, name, shape, opcode, op_name)
+    comp, rows = None, []  # rows: (computation, name, shape, opcode, op_name, callees)
     called_by, fused = {}, set()  # control-flow bodies; fusions, reducers
+    named = {}  # computation -> the scope paths its instructions name
     for line in text.splitlines():
         if not line.startswith(" "):
             m = _COMPUTATION.match(line)
@@ -217,20 +242,34 @@ def parse_hlo_text(text: str) -> dict:
         if not opcode:
             continue
         op = _OP_NAME.search(line)
-        row = (comp, m.group(1), m.group(2) or "", opcode.group(1),
-               op.group(1) if op else None)
         callees = _CALLEE.findall(line)
         for group in _BRANCHES.findall(line):
             callees += [c.strip().lstrip("%") for c in group.split(",")]
+        row = (comp, m.group(1), m.group(2) or "", opcode.group(1),
+               op.group(1) if op else None, callees)
+        if op and scope_path(op.group(1)):
+            named.setdefault(comp, []).append(scope_path(op.group(1)).split("/"))
         if row[3] in _CONTROL:
             called_by.update((c, row) for c in callees)
         else:
             fused.update(callees)
         rows.append(row)
 
+    def agreed(row):
+        """The deepest scope path the instructions inside a fusion share."""
+        inside = [p for c in row[5] for p in named.get(c, ())]
+        shared = []
+        for parts in zip(*inside):
+            if len(set(parts)) > 1:
+                break
+            shared.append(parts[0])
+        return "/".join(shared)
+
     def scope_of(row):
         """The row's own scope path, or the one its body inherits."""
         own = scope_path(row[4]) if row[4] else ""
+        if not own and row[3] == "fusion":
+            own = agreed(row)
         caller = called_by.get(row[0])
         inherited = scope_of(caller) if caller and not own else None
         return own or inherited or (None if row[4] is None else "")

@@ -2,8 +2,9 @@
 
 The device half of the tracker plane lives in `engine/state.py`
 (TrackerState, accumulated by the round engines when
-EngineConfig.tracker is set) and rides the per-chunk probe as sync-free
-aggregate lanes (engine/round.py PROBE_*). This module is the host half
+EngineConfig.tracker is set; the exchange's three marks, exch_hwm /
+land_hwm / land_passes, in every program) and rides the per-chunk probe
+as sync-free aggregate lanes (engine/round.py PROBE_*). This module is the host half
 (the analogue of the reference's per-host Tracker, src/main/host/
 tracker.c:407-430, and the worker-local SimStats fold, sim_stats.rs):
 
@@ -375,6 +376,8 @@ class Tracker:
             out["high_water"] = {
                 "queue": int(max(hs["queue_hwm"])),
                 "outbox": int(max(hs["outbox_hwm"])),
+                # most entries one shard staged for one round's flush
+                "exchange": int(max(hs["exch_hwm"])),
                 # most arrivals one destination landed in one round
                 "landing": int(max(hs["land_hwm"])),
             }
@@ -425,7 +428,8 @@ class Tracker:
                 "retrans_segments": p.retrans_segs,
             }
             out["high_water"] = {
-                "queue": p.queue_hwm, "outbox": p.outbox_hwm, "landing": p.land_hwm,
+                "queue": p.queue_hwm, "outbox": p.outbox_hwm,
+                "exchange": p.exch_hwm, "landing": p.land_hwm,
             }
             out["rounds"] = {"live": p.rounds_live, "idle": p.rounds_idle}
         return out

@@ -50,7 +50,7 @@ def _assert_batch_exact(a, b, what=""):
     deviations (tests/test_mesh.py): per-shard iteration diagnostics
     and dead-slot queue garbage (live queue content is compared in
     canonical pop order via the host snapshot)."""
-    from test_mesh import _canon_queue
+    from test_mesh import _canon_queue, per_shard_leaf
 
     ha, hb = state_to_host(a), state_to_host(b)
     grid_leaves = (".queue.time", ".queue.tie", ".queue.kind",
@@ -60,9 +60,7 @@ def _assert_batch_exact(a, b, what=""):
     assert len(fa) == len(fb)
     for (path, la), (_, lb) in zip(fa, fb):
         ks = jax.tree_util.keystr(path)
-        if ("iters_done" in ks or "lanes_live" in ks or "exch_hwm" in ks
-                or "land_hwm" in ks or "land_passes" in ks
-                or ks in grid_leaves):
+        if per_shard_leaf(ks) or ks in grid_leaves:
             continue
         assert np.array_equal(np.asarray(la), np.asarray(lb)), (
             f"mismatch{what} at {ks}"

@@ -265,10 +265,20 @@ def test_the_cabled_cell_rehearses_end_to_end():
     assert all(v["value"] == 0 == v["limit"] for v in out["check"].values())
     assert "128 hosts, 1 chip(s)" in r.stdout
     counted = {k: m["value"] for k, m in out["metrics"].items() if m["value"] is not None}
-    assert set(counted) == {"drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct"}
+    assert set(counted) == {
+        "drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct",
+        "exchange.passes_per_unit", "exchange.fill_pct", "exchange.land_hwm", "exchange.staged_hwm",
+    }
     assert counted["drain.rounds_per_unit"] == 123
     assert counted["drain.iters_per_unit"] == 1486
     assert 1.0 < counted["drain.occupancy_pct"] < 1.25
+    # the exchange's counts, tracker off (PR 36): the passes are the paths'
+    # too (520 at full size), a mean round stages 0.04 % of what it flattens,
+    # and the busiest destination of the ramp landed one window of 40
+    assert counted["exchange.passes_per_unit"] == 520
+    assert 0.03 < counted["exchange.fill_pct"] < 0.045
+    assert counted["exchange.land_hwm"] == 40
+    assert 40 <= counted["exchange.staged_hwm"] <= 128 * 512
 
 
 def _reader(name):
@@ -297,7 +307,8 @@ def test_the_two_readers_read_the_kept_probes_and_none_without_them(monkeypatch)
         return dataclasses.replace(ChunkProbe.from_array([0] * PROBE_LANES),
                                    rounds_live=rounds_live, iters=iters, lanes_live=lanes_live)
 
-    kept = scopes.EntryProbes(hosts=100, entry=probe(7, 60, 900), chunk=probe(12, 100, 980))
+    kept = scopes.EntryProbes(hosts=100, outbox_slots=1600, entry=probe(7, 60, 900),
+                              chunk=probe(12, 100, 980))
     monkeypatch.setattr(scopes, "last_probes", kept)
     assert rounds(ctx) == 5 and occupancy(ctx) == pytest.approx(100.0 * 80 / (40 * 100))
     ctx.chips = 4  # a shard scans a quarter of the rows

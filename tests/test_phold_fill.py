@@ -106,10 +106,17 @@ def test_the_cell_rehearses_end_to_end():
     assert "64 hosts, 1 chip(s)" in r.stdout
     assert out["metrics"]["drain.iters_per_unit"]["value"] > 0
     # no time, rate or share from a CPU run: the two new readers among them;
-    # the counts keep their values (rounds and occupancy since PR 34)
+    # the counts keep their values (rounds and occupancy since PR 34, the
+    # exchange's four since PR 36)
     timed = {k for k, m in out["metrics"].items() if m["value"] is not None}
-    assert timed == {"drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct"}
+    assert timed == {
+        "drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct",
+        "exchange.passes_per_unit", "exchange.fill_pct", "exchange.land_hwm", "exchange.staged_hwm",
+    }
     assert out["metrics"]["drain.rounds_per_unit"]["value"] == 5  # 10 ms of a 2 ms lookahead
+    # one ball a host: no destination of 64 takes more than one pass a round
+    assert out["metrics"]["exchange.passes_per_unit"]["value"] == 5
+    assert 1 <= out["metrics"]["exchange.land_hwm"]["value"] <= 4
 
 
 def test_chip_smoke_knows_the_deployment():

@@ -41,7 +41,8 @@ CASES = ("tgen-plain", "tgen-pump", "phold-plain", "phold-pump", "tgen-sharded")
 EVERYWHERE = {
     "window", "drain", "drain/handle", "drain/handle/push_self", "drain/handle/pop",
     "drain/handle/stage", "drain/handle/route", "exchange", "exchange/land",
-    "exchange/land/count", "exchange/land/pull", "probe",
+    "exchange/land/count", "exchange/land/pull", "exchange/land/sort",
+    "exchange/land/pack", "probe",
 }
 # the tgen world shapes its hosts and speaks TCP; phold's does neither
 TGEN = EVERYWHERE | {"drain/handle/netstack", "drain/handle/tcp"}
@@ -52,7 +53,7 @@ EXPECTED = {
     # phold publishes no pump_spec: every engine value takes the handler
     "phold-plain": EVERYWHERE,
     "phold-pump": EVERYWHERE,
-    "tgen-sharded": TGEN | {"exchange/collective"},
+    "tgen-sharded": TGEN | {"exchange/collective", "exchange/bucket"},
 }
 
 
@@ -103,6 +104,14 @@ def test_every_scope_names_operations_of_the_compiled_chunk(chunks, case):
     assert "exchange/land/push_self" not in found
     pulled = [n for n, v in table.items() if v[1] == "exchange/land/pull"]
     assert any(n.startswith("while") for n in pulled) and len(pulled) > 1, pulled
+    # the bucketing stands in front of the collective, not around it or
+    # under it, and only the sharded all_to_all flush has one
+    assert not any("bucket" in p and "collective" in p for p in found), found
+    assert ("exchange/bucket" in found) == (case == "tgen-sharded")
+    # the one sort of the landing is the sort scope's, whatever else sorts
+    sorts = {v[1] for n, v in table.items() if n.startswith("sort")}
+    assert "exchange/land/sort" in sorts
+    assert sorts <= {"exchange/land/sort", "exchange/bucket"}, sorts
     # nothing outside the one list, and outermost is the path's head
     for shape, inner, outer in table.values():
         if inner:
@@ -219,6 +228,16 @@ def test_a_table_without_a_scope_is_refused_loudly(chunks, monkeypatch):
     # equeue.peek_min + clear_slot, as the handler calls them (PR 35)
     (scopes.POP, "kernels",
      "jit(_run_chunk)/while/body/drain/while/body/handle/pop/gather", "drain/handle/pop"),
+    # equeue.land_sorted's sort and packing, and the sharded flush's
+    # bucketing in front of the collective (PR 36)
+    (scopes.SORT, "kernels",
+     "jit(_run_chunk)/while/body/exchange/cond/branch_1_fun/land/sort/sort", "exchange/land/sort"),
+    (scopes.PACK, "kernels",
+     "jit(_run_chunk)/while/body/exchange/cond/branch_1_fun/land/pack/concatenate",
+     "exchange/land/pack"),
+    (scopes.BUCKET, "exchange",
+     "jit(_chunk)/while/body/exchange/cond/branch_1_fun/bucket/jit(argsort)/sort",
+     "exchange/bucket"),
 ])
 def test_a_later_scope_is_in_the_list_and_in_the_digest(name, layer, op, path):
     """The scope names its layer, and the chunk functions' names moved
@@ -241,5 +260,77 @@ def test_scope_path_keeps_only_the_lists_names():
     assert scopes.scope_path("jit(_run_chunk)/while/body/add") == ""
     # a primitive whose name only holds a scope's word is no scope
     assert scopes.scope_path("jit(_run_chunk)/while/body/drain/population_count") == "drain"
-    # no scope is named like something JAX writes into an op_name itself
+    # the last component is the primitive's name: a sort outside the
+    # landing's sort scope is not under it
+    assert scopes.scope_path("jit(_run_chunk)/while/body/exchange/land/jit(argsort)/sort") == (
+        "exchange/land")
+    assert scopes.scope_path("sort") == ""
+    # no scope is named like something JAX writes into the middle of an op_name
     assert not set(scopes.SCOPES) & {"cond", "body", "while", "closed_call", "jit"}
+
+
+_FUSED_SCATTER = """\
+HloModule jit_chunk
+
+%region_add (a: u32[], b: u32[]) -> u32[] {
+  %a = u32[] parameter(0)
+  ROOT %b = u32[] parameter(1)
+}
+
+%fused_scatter (p0: u32[64], p1: s32[16], p2: u32[16]) -> u32[64] {
+  %p0 = u32[64]{0} parameter(0)
+  %p1 = s32[16]{0} parameter(1)
+  %p2 = u32[16]{0} parameter(2)
+  %reshape.1 = u32[16]{0} reshape(%p2), metadata={op_name="jit(chunk)/exchange/cond/branch_1_fun/bucket/gather"}
+  ROOT %scatter.1 = u32[64]{0} scatter(%p0, %p1, %reshape.1), to_apply=%region_add
+}
+
+%fused_mixed (q0: s32[16]) -> s32[16] {
+  %q0 = s32[16]{0} parameter(0)
+  %add.1 = s32[16]{0} add(%q0, %q0), metadata={op_name="jit(chunk)/exchange/cond/branch_1_fun/land/sort/add"}
+  ROOT %mul.1 = s32[16]{0} multiply(%add.1, %q0), metadata={op_name="jit(chunk)/exchange/cond/branch_1_fun/land/pack/mul"}
+}
+
+%fused_bare (r0: s32[16]) -> s32[16] {
+  %r0 = s32[16]{0} parameter(0)
+  ROOT %neg.1 = s32[16]{0} negate(%r0)
+}
+
+%branch_flush (s: (u32[64], s32[16], u32[16])) -> u32[64] {
+  %s = (u32[64]{0}, s32[16]{0}, u32[16]{0}) parameter(0)
+  %g0 = u32[64]{0} get-tuple-element(%s), index=0
+  %g1 = s32[16]{0} get-tuple-element(%s), index=1
+  %g2 = u32[16]{0} get-tuple-element(%s), index=2
+  %fusion.5 = s32[16]{0} fusion(%g1), kind=kLoop, calls=%fused_mixed
+  %fusion.6 = s32[16]{0} fusion(%fusion.5), kind=kLoop, calls=%fused_bare
+  %fusion.7 = s32[16]{0} fusion(%fusion.6), kind=kLoop, calls=%fused_bare, metadata={op_name="jit(chunk)/exchange/cond/branch_1_fun/land/neg"}
+  ROOT %fusion.4 = u32[64]{0} fusion(%g0, %fusion.7, %g2), kind=kCustom, calls=%fused_scatter
+}
+
+%branch_skip (t: (u32[64], s32[16], u32[16])) -> u32[64] {
+  %t = (u32[64]{0}, s32[16]{0}, u32[16]{0}) parameter(0)
+  ROOT %h0 = u32[64]{0} get-tuple-element(%t), index=0
+}
+
+ENTRY %main (x: (u32[64], s32[16], u32[16]), c: pred[]) -> u32[64] {
+  %x = (u32[64]{0}, s32[16]{0}, u32[16]{0}) parameter(0)
+  %c = pred[] parameter(1)
+  ROOT %conditional.1 = u32[64]{0} conditional(%c, %x, %x), true_computation=%branch_flush, false_computation=%branch_skip, metadata={op_name="jit(chunk)/exchange/cond"}
+}
+"""
+
+
+def test_a_fusion_whose_root_lost_its_name_takes_what_its_instructions_agree_on():
+    """The chip's compiler merges the two 32-bit halves of a 64-bit scatter
+    into one scatter that carries no `op_name`, and the fusion around it
+    then has none: it belongs to the deepest scope the instructions inside
+    it share, not to the branch that holds it (in the four-chip chunk these
+    are the bucketing's three largest operations). Its own name still goes
+    first, and a fusion that names nothing inside inherits as before."""
+    table = scopes.parse_hlo_text(_FUSED_SCATTER)
+    assert table["fusion.4"] == ("u32[64]", "exchange/bucket", "exchange")
+    assert table["fusion.5"][1] == "exchange/land"  # sort and pack agree on the landing
+    assert table["fusion.6"][1] == "exchange"  # nothing named inside: the branch's scope
+    assert table["fusion.7"][1] == "exchange/land"  # its own name, whatever lies inside
+    assert table["conditional.1"][1] == "exchange"
+    assert "scatter.1" not in table  # inside a fusion: no operation of its own

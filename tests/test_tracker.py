@@ -198,7 +198,7 @@ def _staged(st, dst_by_row):
 def test_land_counters_book_the_landings_loop(tracker):
     """land_hwm is the most arrivals one destination landed in one round,
     land_passes the sum over the flushes of ceil(that round's mark / K);
-    both on row 0, and both untouched with the tracker off."""
+    both on row 0, and both counted whatever cfg.tracker says."""
     from shadow_tpu import equeue
     from shadow_tpu.engine.round import ChunkProbe, flush_outbox, state_probe
     from shadow_tpu.engine.state import EngineConfig, init_state
@@ -214,8 +214,8 @@ def test_land_counters_book_the_landings_loop(tracker):
     for n in fan_ins:  # n rows send to host 5, one more to host 0
         st = flush_outbox(_staged(st, [5] * n + [0]), None, cfg)
         st = st.replace(queue=equeue.create(hosts, 4 * k))
-    want_hwm = max(fan_ins) if tracker else 0
-    want_passes = sum(-(-n // k) for n in fan_ins) if tracker else 0
+    want_hwm = max(fan_ins)
+    want_passes = sum(-(-n // k) for n in fan_ins)
     assert st.tracker.land_hwm.tolist() == [want_hwm] + [0] * (hosts - 1)
     assert st.tracker.land_passes.tolist() == [want_passes] + [0] * (hosts - 1)
     probe = ChunkProbe.from_array(state_probe(st))
@@ -318,7 +318,7 @@ def test_heartbeat_lines_and_stats_fold_phold():
     assert set(stats["events_by_kind"]) == {"local", "tcp", "packet"}
     assert set(stats["drops"]) == {"loss", "codel", "unroutable"}
     assert set(stats["bytes"]) == {"ctrl", "data", "retrans_segments"}
-    assert set(stats["high_water"]) == {"queue", "outbox", "landing"}
+    assert set(stats["high_water"]) == {"queue", "outbox", "exchange", "landing"}
     assert set(stats["rounds"]) == {"live", "idle"}
     total = sum(stats["events_by_kind"].values())
     assert total == int(st.events_handled.sum())
@@ -435,6 +435,8 @@ def test_an_entry_keeps_its_probe_and_its_newest_chunks(driver):
     out = run(warm, 40 * NS_PER_MS, on_chunk=probes.append)
     kept = scopes.last_probes
     assert kept.hosts == 64 and kept.chunk == probes[-1]
+    # rows x outbox capacity over all shards: what a round's flushes flatten
+    assert kept.outbox_slots == 64 * cfg.outbox_capacity == out.outbox.valid.size
     assert kept.entry.rounds_live == int(warm.rounds_live) > 0
     assert kept.entry.now == int(warm.now)
     assert kept.entry.events_handled == int(warm.events_handled.sum())
