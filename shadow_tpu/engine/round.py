@@ -492,15 +492,37 @@ def model_pump_capable(model) -> bool:
     )
 
 
+def flush_block(o_cap: int) -> int:
+    """The slot columns a flush hands the landing at a time: an eighth of
+    the outbox's capacity (the largest divisor of it that is no more, none
+    below 1). Static, and a function of the capacity alone: how MANY
+    blocks a round's flush takes is read from the state (flush_outbox)."""
+    c = max(1, o_cap // 8)
+    while o_cap % c:
+        c -= 1
+    return c
+
+
+def _staged_width(st: SimState, axis_name: Optional[str]) -> jax.Array:
+    """The busiest outbox row's fill (scalar i32), mesh-uniform (pmax):
+    every shard must make the same number of passes over its outbox,
+    because the collectives inside are entered by all or none.
+    stage_packets and the pump write lane p into slot fill[h] and then
+    raise fill[h], the flush clears valid, time and fill together and
+    grow_state pads on the right, so valid[h, o] == (o < fill[h]) always:
+    the first max_h fill[h] columns hold everything a round staged."""
+    w = jnp.max(st.outbox.fill)
+    if axis_name is not None:
+        w = jax.lax.pmax(w, axis_name)
+    return w
+
+
 def _has_traffic(st: SimState, axis_name: Optional[str]) -> jax.Array:
     """Mesh-uniform "any packet staged in an outbox". Shared by
     flush_outbox's skip-cond and run_rounds_scan's quiescence gate — the
     two MUST agree, or the early-exit idle branch could skip a flush that
     would have delivered traffic."""
-    t = jnp.any(st.outbox.valid)
-    if axis_name is not None:
-        t = jax.lax.psum(t.astype(jnp.int32), axis_name) > 0
-    return t
+    return _staged_width(st, axis_name) > 0
 
 
 def flush_outbox(
@@ -517,86 +539,124 @@ def flush_outbox(
         O(devices x whole outbox). Bucket capacity is static (XLA
         shapes); overflow is counted and fails loudly via
         check_capacity, like every other fixed-slot resource.
-      * all_gather: every shard receives every shard's whole outbox and
-        filters its own rows (simple, never overflows, more traffic).
+      * all_gather: every shard receives every shard's staged columns
+        and filters its own rows (simple, never overflows, more traffic).
 
     In both modes the destination pops by the (time, tie) key, so
     delivery slot order — which differs between the modes — cannot
-    affect results. Every flush books how the landing's loop engaged
-    (TrackerState.land_hwm / land_passes), cfg.tracker or not.
+    affect results.
+
+    What a flush flattens, buckets, exchanges, sorts, counts and packs is
+    the staged COLUMNS, not the outbox's capacity: a row's staged entries
+    are its first fill[h] slots, so a lax.while_loop hands the landing's
+    sort (equeue.land_sort) one block of flush_block(O) slot columns at a
+    time, for as many blocks as hold the busiest row's fill, and ONE pull
+    (equeue.land_pull) lands what the blocks grouped: a destination's
+    arrivals are block 0's, then block 1's, so every leaf is what one
+    sort of the whole outbox gives, bit for bit. One loop body whatever
+    the width (a lax.switch over static widths held a copy of the
+    landing's code a width); one row staging O entries puts its round on
+    all the blocks. Under cfg.ensemble the block count is batched and a
+    batched loop would carry every replica's buffers through selects, so
+    that trace keeps ONE block, the whole outbox. Every flush books how
+    it engaged (TrackerState.land_hwm / land_passes / flush_cols),
+    cfg.tracker or not.
     """
-    # Empty rounds skip the exchange sorts entirely (lax.cond on a scalar
-    # any-reduce). Sharded: the predicate is made mesh-uniform with a
-    # psum, because the all_to_all/all_gather inside must be entered by
-    # every shard or none.
+    o_cap = st.outbox.valid.shape[1]
+    one_block = cfg is not None and cfg.ensemble
+    block = o_cap if one_block else flush_block(o_cap)
+    # Empty rounds skip the exchange entirely (lax.cond on the busiest
+    # row's fill). Sharded, the fill is made mesh-uniform with a pmax,
+    # because the all_to_all/all_gather inside must be entered by every
+    # shard or none, as often.
     with jax.named_scope(scopes.EXCHANGE):
-        has_traffic = _has_traffic(st, axis_name)
+        blocks = (_staged_width(st, axis_name) + (block - 1)) // block
 
         def _skip(st):
             return st, jnp.zeros((), jnp.int32)
 
         def _do_flush(st):
-            return _flush_outbox_traffic(st, axis_name, cfg)
+            return _flush_outbox_traffic(st, axis_name, cfg, block, blocks)
 
-        if not isinstance(has_traffic, jax.core.Tracer):
-            # eager path (round_body_debug/tests): concrete predicate — an
+        if not isinstance(blocks, jax.core.Tracer):
+            # eager path (round_body_debug/tests): concrete count — an
             # eager lax.cond over this state is pathological for the tracer
-            st, max_land = _do_flush(st) if bool(has_traffic) else _skip(st)
+            st, max_land = _do_flush(st) if int(blocks) else _skip(st)
         else:
-            st, max_land = jax.lax.cond(has_traffic, _do_flush, _skip, st)
-    # how the landing's loop engaged (row 0, like exch_hwm): the most
+            st, max_land = jax.lax.cond(blocks > 0, _do_flush, _skip, st)
+    # how the flush and the landing's loop engaged (row 0, like exch_hwm):
+    # the columns this flush flattened (0 for a skipped one), the most
     # arrivals one destination landed in one round — what LAND_LANES is
     # sized from — and the passes made over all landings. Counted in every
-    # program (cfg.tracker or not): two one-element updates a flush.
+    # program (cfg.tracker or not): three one-element updates a flush.
     with jax.named_scope(scopes.PROBE):
         tr = st.tracker
         return st.replace(
             tracker=tr.replace(
                 land_hwm=tr.land_hwm.at[0].max(max_land),
                 land_passes=tr.land_passes.at[0].add(equeue.land_passes(max_land)),
+                flush_cols=tr.flush_cols.at[0].add(blocks * block),
             )
         )
 
 
-def _payload_words(ob: Outbox) -> jax.Array:
-    """The outbox payload [H, 8, O] as [8, M] words, slot (h, o) in column
-    h * O + o: the flat order of the other outbox arrays."""
-    lanes = ob.data.shape[1]
-    return jnp.moveaxis(ob.data, 1, 0).reshape(lanes, -1)
-
-
 def _flush_outbox_traffic(
-    st: SimState, axis_name: Optional[str], cfg: "EngineConfig | None" = None
+    st: SimState,
+    axis_name: Optional[str],
+    cfg: "EngineConfig | None" = None,
+    block: "int | None" = None,
+    blocks=None,
 ) -> "tuple[SimState, jax.Array]":
-    """The flush of a round that staged something: (state, the most
-    arrivals one destination landed: land_sorted's max_land)."""
+    """The flush of a round that staged something: the first `blocks`
+    blocks of `block` slot columns each (all of the outbox when None),
+    which must hold every staged entry: (state, the most arrivals one
+    destination landed: land_pull's max_land).
+
+    Slot (h, o) of a block is its flat entry o * H + h, slots major and
+    hosts minor: the order the outbox arrays lie in on the chip ([H, O]
+    is held hosts on the lanes, slots on the sublanes), so the flatten is
+    no transposition and pads nothing. A destination's arrivals keep this
+    order (the sorts are stable, blocks land in order), so they take its
+    free slots slot-column by slot-column; pop order is key-driven."""
     ob = st.outbox
     h_local, o_cap = ob.valid.shape
-    m = h_local * o_cap
-
-    def flat(x):
-        return x.reshape(m)
-
-    valid, dst, time, tie = flat(ob.valid), flat(ob.dst), flat(ob.time), flat(ob.tie)
-    # the landing reads the payload word-major (land_sorted's data.T
-    # folds with this one); the sharded buckets read it as [M, 8] rows
-    data, aux = _payload_words(ob).T, flat(ob.aux)
-    overflow_extra = None
-
-    base = 0
+    if block is None:
+        block, blocks = o_cap, 1
+    nb = o_cap // block
+    m = h_local * block
+    lanes = ob.data.shape[1]
+    mode = None
     if axis_name is not None:
         mode = getattr(cfg, "exchange", "all_to_all") if cfg is not None else "all_gather"
+        d = jax.lax.axis_size(axis_name)
         base = jax.lax.axis_index(axis_name) * h_local
+    if mode == "all_to_all":
+        cap = getattr(cfg, "a2a_capacity", 0) or 0
+        # safe default: each peer bucket can hold everything a block
+        # flattens (PDES traffic is often pair-skewed — e.g. client i ->
+        # server i+H/2 lands a shard's entire outbox on one peer), which
+        # is also the most a block can send one peer. Tuning a2a_capacity
+        # below m is where the ICI traffic saving comes from.
+        cap = m if cap <= 0 else min(cap, m)
+        n = d * cap
+    else:
+        n = m if mode is None else d * m
+
+    def one_block(b, carry):
+        """Block b's columns, exchanged and grouped by destination into
+        segment b of the landing's buffers."""
+        orders, words, cnts, begins, n_pushed, overflow_extra = carry
+
+        def flat(x):  # [H, O] -> block b's [block * H], slots major
+            return jax.lax.dynamic_slice_in_dim(x, b * block, block, axis=1).T.reshape(m)
+
+        valid, dst, time, tie = flat(ob.valid), flat(ob.dst), flat(ob.time), flat(ob.tie)
+        # the landing reads the payload word-major (land_sort's data.T
+        # folds with this one); the sharded buckets read it as [M, 8] rows
+        data = jax.lax.dynamic_slice_in_dim(ob.data, b * block, block, axis=2)
+        data, aux = jnp.transpose(data, (2, 0, 1)).reshape(m, lanes), flat(ob.aux)
+
         if mode == "all_to_all":
-            d = jax.lax.axis_size(axis_name)
-            cap = getattr(cfg, "a2a_capacity", 0) or 0
-            if cap <= 0:
-                # safe default: each peer bucket can hold the whole local
-                # outbox (PDES traffic is often pair-skewed — e.g. client i
-                # -> server i+H/2 lands a shard's entire outbox on one
-                # peer). Tuning a2a_capacity below m is where the ICI
-                # traffic saving comes from.
-                cap = m
             # bucket by destination shard; stable sort keeps emission order
             # within each bucket (determinism is key-driven anyway)
             with jax.named_scope(scopes.BUCKET):
@@ -613,7 +673,7 @@ def _flush_outbox_traffic(
                 fits = valid_s & (rank < cap)
                 sdst = jnp.where(fits, sh_s, d)
                 sslot = jnp.where(fits, rank, cap)
-                overflow_extra = jnp.sum(valid_s & ~fits).astype(jnp.int32)
+                overflow_extra += jnp.sum(valid_s & ~fits).astype(jnp.int32)
 
             def to_peers(x, fill):
                 with jax.named_scope(scopes.BUCKET):
@@ -621,7 +681,7 @@ def _flush_outbox_traffic(
                     buf = buf.at[sdst, sslot].set(x[order], mode="drop")
                 with jax.named_scope(scopes.COLLECTIVE):
                     got = jax.lax.all_to_all(buf, axis_name, 0, 0, tiled=False)
-                return got.reshape((d * cap,) + x.shape[1:])
+                return got.reshape((n,) + x.shape[1:])
 
             valid = to_peers(valid, False)
             dst = to_peers(dst, 0)
@@ -629,7 +689,7 @@ def _flush_outbox_traffic(
             tie = to_peers(tie, 0)
             data = to_peers(data, 0)
             aux = to_peers(aux, 0)
-        else:
+        elif mode == "all_gather":
             with jax.named_scope(scopes.COLLECTIVE):
                 valid = jax.lax.all_gather(valid, axis_name, tiled=True)
                 dst = jax.lax.all_gather(dst, axis_name, tiled=True)
@@ -638,20 +698,52 @@ def _flush_outbox_traffic(
                 data = jax.lax.all_gather(data, axis_name, tiled=True)
                 aux = jax.lax.all_gather(aux, axis_name, tiled=True)
 
-    local_dst = dst - base
-    mine = valid & (local_dst >= 0) & (local_dst < h_local)
-    lanes = getattr(cfg, "deliver_lanes", 0) if cfg is not None else 0
+        local_dst = dst if mode is None else dst - base
+        mine = valid & (local_dst >= 0) & (local_dst < h_local)
+        at = b * n
+        with jax.named_scope(scopes.LAND):
+            pushed, cnt, begin, order, packed = equeue.land_sort(
+                h_local, local_dst, mine, time, tie,
+                jnp.full(valid.shape, KIND_PACKET, jnp.int32), data, aux,
+            )
+            # into segment b of the landing's buffers, each under the scope
+            # of the step that made it
+            with jax.named_scope(scopes.SORT):
+                orders = jax.lax.dynamic_update_slice_in_dim(orders, order + at, at, 0)
+            with jax.named_scope(scopes.PACK):
+                words = jax.lax.dynamic_update_slice_in_dim(words, packed, at, 1)
+        return (
+            orders,
+            words,
+            jax.lax.dynamic_update_index_in_dim(cnts, cnt, b, 0),
+            jax.lax.dynamic_update_index_in_dim(begins, begin, b, 0),
+            n_pushed + pushed,
+            overflow_extra,
+        )
+
+    zero = jnp.zeros((), jnp.int32)
+    carry = (
+        jnp.zeros((nb * n,), jnp.int32), jnp.zeros((14, nb * n), jnp.int32),
+        jnp.zeros((nb, h_local), jnp.int32), jnp.zeros((nb, h_local), jnp.int32), zero, zero,
+    )
+    if nb == 1:
+        carry = one_block(0, carry)
+    elif isinstance(blocks, jax.core.Tracer):
+        _, carry = jax.lax.while_loop(
+            lambda c: c[0] < blocks,
+            lambda c: (c[0] + 1, one_block(c[0], c[1])),
+            (zero, carry),
+        )
+    else:  # eager (round_body_debug/tests)
+        for b in range(int(blocks)):
+            carry = one_block(b, carry)
+    orders, words, cnts, begins, n_pushed, overflow_extra = carry
+
+    lanes_d = getattr(cfg, "deliver_lanes", 0) if cfg is not None else 0
     with jax.named_scope(scopes.LAND):
-        queue, max_land = equeue.land_sorted(
-            deliver_lanes=lanes if lanes > 0 else st.queue.capacity,
-            q=st.queue,
-            dst=local_dst,
-            valid=mine,
-            time=time,
-            tie=tie,
-            kind=jnp.full(valid.shape, KIND_PACKET, jnp.int32),
-            data=data,
-            aux=aux,
+        queue, max_land = equeue.land_pull(
+            st.queue, n_pushed, cnts, begins, orders, words,
+            lanes_d if lanes_d > 0 else st.queue.capacity,
         )
 
     fresh = ob.replace(
@@ -659,7 +751,7 @@ def _flush_outbox_traffic(
         time=jnp.full_like(ob.time, TIME_MAX),
         fill=jnp.zeros_like(ob.fill),
     )
-    if overflow_extra is not None:
+    if mode == "all_to_all":
         fresh = fresh.replace(overflow=fresh.overflow.at[0].add(overflow_extra))
     return st.replace(queue=queue, outbox=fresh), max_land
 
@@ -956,7 +1048,7 @@ def _peek_capacity(st: SimState) -> jax.Array:
 # (docs/observability.md): the queue/outbox overflow split (capacity
 # diagnostics — always live), drop reasons (always live), and the
 # TrackerState sums/maxima (zero unless cfg.tracker, but the exchange's
-# three marks, lanes 22-24, which every program counts). Heartbeats read
+# four counts, lanes 22-25, which every program keeps). Heartbeats read
 # these instead of ever fetching [H]-shaped state mid-run.
 
 PROBE_NEXT_TIME = 0
@@ -997,7 +1089,12 @@ PROBE_EXCH_HWM = 22
 # to its own busiest destination)
 PROBE_LAND_HWM = 23
 PROBE_LAND_PASSES = 24
-PROBE_LANES = 25
+# the outbox columns the flushes flattened (always live; flush_outbox):
+# whole blocks of flush_block columns, as many as hold the busiest row's
+# fill, 0 for a skipped flush, summed over the rounds and psum'd over the
+# shards (each takes the same, pmax'd count)
+PROBE_FLUSH_COLS = 25
+PROBE_LANES = 26
 
 
 def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
@@ -1027,6 +1124,7 @@ def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
         jnp.sum(st.iters_done).astype(jnp.int64),
         jnp.sum(st.lanes_live),
         jnp.sum(tr.land_passes).astype(jnp.int64),
+        jnp.sum(tr.flush_cols).astype(jnp.int64),
     ]
     maxes = [
         st.now,
@@ -1043,11 +1141,11 @@ def state_probe(st: SimState, axis_name: Optional[str] = None) -> jax.Array:
         maxes = [_pmax(x, axis_name) for x in maxes]
         rounds = [_pmax(x, axis_name) for x in rounds]
     now, qh, oh, xh, lh = maxes
-    (ov, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx, it, ll, lp) = sums
+    (ov, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx, it, ll, lp, fc) = sums
     rl, ri, wn = rounds
     return jnp.stack(
         [nt, ov, now, ev, pk, qov, oov, evl, evt, dl, dc, du, bc, bd, rx,
-         qh, oh, rl, ri, it, ll, wn, xh, lh, lp]
+         qh, oh, rl, ri, it, ll, wn, xh, lh, lp, fc]
     ).astype(jnp.int64)
 
 
@@ -1089,6 +1187,11 @@ class ChunkProbe:
     # depends on how the hosts are split over chips)
     land_hwm: int
     land_passes: int
+    # outbox columns the flushes flattened (cfg.tracker on or off): the
+    # width each live round's flush took, summed over rounds and shards;
+    # over shards x rounds_live x outbox_capacity it is the share of the
+    # outbox's capacity the exchange paid for
+    flush_cols: int
 
     @property
     def ev_packet(self) -> int:

@@ -263,6 +263,11 @@ class TrackerState:
     # (tests/test_mesh.py per_shard_leaf).
     land_hwm: jax.Array  # [H] i32
     land_passes: jax.Array  # [H] i32
+    # The outbox columns this shard's flushes flattened, on row 0 like the
+    # three above: whole blocks of engine/round.py flush_block columns, as
+    # many as hold the busiest row's fill over the whole mesh, 0 for a
+    # skipped flush. i32 is safe: at most outbox_capacity a live round.
+    flush_cols: jax.Array  # [H] i32
 
 
 def _empty_tracker(h: int) -> TrackerState:
@@ -278,6 +283,7 @@ def _empty_tracker(h: int) -> TrackerState:
         exch_hwm=jnp.zeros((h,), jnp.int32),
         land_hwm=jnp.zeros((h,), jnp.int32),
         land_passes=jnp.zeros((h,), jnp.int32),
+        flush_cols=jnp.zeros((h,), jnp.int32),
     )
 
 

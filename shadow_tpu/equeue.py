@@ -406,6 +406,11 @@ def land_sorted(
           push_self_lanes spells it). A lax.while_loop makes
           ceil(max_h land[h] / K) passes: none where nothing landed.
 
+    S and G are land_sort, P is land_pull: this is the two on ONE batch.
+    The flush (engine/round.py flush_outbox) hands land_sort the outbox a
+    block of slot columns at a time, the staged ones only, and land_pull
+    the blocks it filled.
+
     Cost follows the batch (S) and the busiest destination (P), not the
     queue's H x Q slots and not D = deliver_lanes: there is no [H, D]
     delivery grid, no scatter, no gather of H x Q or of M indices. D keeps
@@ -428,12 +433,34 @@ def land_sorted(
     free-slot marker) is rejected and counted into overflow — on row 0;
     always fatal via check_capacity.
     """
-    m = dst.shape[0]
-    if m == 0:
+    if dst.shape[0] == 0:
         return q, jnp.zeros((), jnp.int32)
+    n_pushed, cnt, begin, order, words = land_sort(
+        q.num_hosts, dst, valid, time, tie, kind, data, aux
+    )
+    return land_pull(q, n_pushed, cnt[None], begin[None], order, words, deliver_lanes)
+
+
+def land_sort(
+    h: int,
+    dst: jax.Array,  # [M] i32 destination host ids
+    valid: jax.Array,  # [M] bool
+    time: jax.Array,  # [M] i64
+    tie: jax.Array,  # [M] i64
+    kind: jax.Array,  # [M] i32
+    data: jax.Array,  # [M, PAYLOAD_LANES] i32
+    aux: "jax.Array | None" = None,  # [M] i32
+) -> tuple:
+    """Steps S and G of land_sorted on one batch of M entries bound for a
+    queue of `h` rows: (n_pushed, cnt, begin, order, words). n_pushed
+    (scalar i32) counts the valid entries as handed in; cnt[x] and
+    begin[x] ([h] i32) are destination x's run in the sorted order, order
+    ([M] i32) the sort's permutation, words ([14, M] i32) the payload
+    packed where it lies: time and tie as (low, high), kind, aux, the
+    data lanes."""
+    m = dst.shape[0]
     if aux is None:
         aux = jnp.zeros_like(kind)
-    h, cap = q.num_hosts, q.capacity
     n_pushed = jnp.sum(valid, dtype=jnp.int32)
     valid = valid & (time < TIME_MAX) & (dst >= 0) & (dst < h)
 
@@ -444,29 +471,58 @@ def land_sorted(
         _, order = jax.lax.sort((key1, pos), num_keys=1, is_stable=True)
     cnt, begin = run_bounds(key1, h)
 
-    # G: the payload as 32-bit words, word-major [W, M]: time and tie as
-    # (low, high), kind, aux, the data lanes. Word-major because the chip
-    # tiles the two minor dimensions: a [.., W] minor of 14 pads to 128
-    # lanes and every field sliced out of it is a strided pass over that.
-    def lo(x):  # i64 -> its low 32 bits as i32
-        return x.astype(jnp.int32)
-
-    def hi(x):
-        return (x >> 32).astype(jnp.int32)
-
-    def long(low, high):  # the i64 back, bit-exact
-        low = jax.lax.bitcast_convert_type(low, jnp.uint32)
-        return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
-
+    # G: word-major [W, M], because the chip tiles the two minor
+    # dimensions: a [.., W] minor of 14 pads to 128 lanes and every field
+    # sliced out of it is a strided pass over that.
     with jax.named_scope(scopes.PACK):
         words = jnp.concatenate(
-            [jnp.stack([lo(time), hi(time), lo(tie), hi(tie), kind, aux]), data.T]
+            [jnp.stack([_lo(time), _hi(time), _lo(tie), _hi(tie), kind, aux]), data.T]
         )
+    return n_pushed, cnt, begin, order, words
+
+
+def _lo(x):  # i64 -> its low 32 bits as i32
+    return x.astype(jnp.int32)
+
+
+def _hi(x):
+    return (x >> 32).astype(jnp.int32)
+
+
+def _long(low, high):  # the i64 back, bit-exact
+    low = jax.lax.bitcast_convert_type(low, jnp.uint32)
+    return (high.astype(jnp.int64) << 32) | low.astype(jnp.int64)
+
+
+def land_pull(
+    q: EventQueue,
+    n_pushed: jax.Array,  # scalar i32: valid entries handed to land_sort
+    cnt: jax.Array,  # [B, H] i32: each block's arrivals by destination
+    begin: jax.Array,  # [B, H] i32: where the run starts in its block
+    order: jax.Array,  # [B * N] i32: block b's permutation, each + b * N
+    words: jax.Array,  # [14, B * N] i32
+    deliver_lanes: int,
+) -> "tuple[EventQueue, jax.Array]":
+    """Step P of land_sorted over B blocks of N entries each that
+    land_sort grouped one by one: a destination's arrivals are block 0's
+    run, then block 1's, and so on, and its r-th arrival lands in its r-th
+    free slot, exactly as if the blocks had been sorted as one batch in
+    that order. A block nobody filled has cnt 0 and is never read. With
+    one block this is land_sorted's own loop."""
+    nb = cnt.shape[0]
+    n = order.shape[0] // nb
+    cap = q.capacity
+    total = jnp.sum(cnt, axis=0, dtype=jnp.int32)  # [H]
+    if nb > 1:
+        upto = jnp.cumsum(cnt, axis=0, dtype=jnp.int32)  # [B, H] inclusive
+        # rank r of row h is entry start[b, h] + r of the blocks' segments,
+        # b the first block whose inclusive count passes r
+        start = begin - (upto - cnt) + (jnp.arange(nb, dtype=jnp.int32) * n)[:, None]
 
     # P: each row's free slots by rank, and how many arrivals it lands
     free = q.time == TIME_MAX  # [H, Q]
     fr = (jnp.cumsum(free, axis=1) - free).astype(jnp.int32)  # rank among free slots
-    fit = jnp.minimum(cnt, deliver_lanes)
+    fit = jnp.minimum(total, deliver_lanes)
     land = jnp.minimum(fit, cap - q.count)  # [H]
     take = free & (fr < land[:, None])
     max_land = jnp.max(land)
@@ -477,9 +533,20 @@ def land_sorted(
         p, q = carry
         rank = p * LAND_LANES + lane  # [K] arrival ranks of this pass
         # lanes major, hosts minor: a minor axis of K would pad to 128 lanes
-        src = jnp.minimum(begin + rank[:, None], m - 1)  # [K, H]; used where rank < land
+        # a lane with no arrival left to pull (most lanes, in most rounds)
+        # reads on in block 0, past its row's run, where its neighbours'
+        # lanes read too. What idle lanes read is part of the gather's
+        # price on the chip: all ONE entry cost it a third more at 524,288
+        # rows, each an entry of its own a tenth more (PERF.md, PR 37)
+        off = begin[0]
+        if nb > 1:
+            more = rank[:, None] < total
+            for b in range(1, nb):
+                off = jnp.where(more & (rank[:, None] >= upto[b - 1]), start[b], off)
+        # [K, H]; used where rank < land
+        src = jnp.minimum(off + rank[:, None], order.shape[0] - 1)
         g = words[:, order[src]]  # [W, K, H]
-        g_time, g_tie = long(g[0], g[1]), long(g[2], g[3])
+        g_time, g_tie = _long(g[0], g[1]), _long(g[2], g[3])
         q_time, q_tie, q_kind, q_data, q_aux = q.time, q.tie, q.kind, q.data, q.aux
         for k in range(LAND_LANES):
             at = take & (fr == rank[k])  # the row's rank-th free slot

@@ -62,7 +62,8 @@ from shadow_tpu.utils.shadow_log import slog
 # 3: rounds_live is a leaf of SimState beside win_ns_sum, no longer of its
 # tracker: the leaf ORDER changed, and the two i64 scalars would pass the
 # shape check swapped
-CHECKPOINT_VERSION = 3
+# 4: TrackerState.flush_cols, one more [H] i32 leaf at the tracker's end
+CHECKPOINT_VERSION = 4
 
 
 class CheckpointError(ValueError):

@@ -71,6 +71,7 @@ SUMMARY_FIELDS = (
     "queue_hwm",
     "outbox_hwm",
     "land_passes",
+    "flush_cols",
     "exch_hwm",
     "land_hwm",
     "device_bytes_in_use",
@@ -254,9 +255,11 @@ class FlightRecorder:
             "queue_hwm": p.queue_hwm,
             "outbox_hwm": p.outbox_hwm,
             # the exchange's series, counted with or without --tracker:
-            # the chunk's landing passes, and the running marks of staged
-            # entries a shard a round and arrivals a destination a round
+            # the chunk's landing passes and the outbox columns its
+            # flushes flattened, and the running marks of staged entries
+            # a shard a round and arrivals a destination a round
             "land_passes": d("land_passes"),
+            "flush_cols": d("flush_cols"),
             "exch_hwm": p.exch_hwm,
             "land_hwm": p.land_hwm,
             "events_total": p.events_handled,

@@ -49,7 +49,9 @@ Every name is a single path component; nested scopes give paths:
                             its tie, its kind and its aux, ONE gather of H
                             indices reads the slot's eight payload words,
                             one select pass tombstones the two key arrays
-    exchange                flush_outbox: flatten, clear, has-traffic test
+    exchange                flush_outbox: the busiest row's fill, each
+                            block's flatten, the landing's buffers, the
+                            clear
     exchange/bucket         sharded all_to_all only: the entries' shard,
                             the stable argsort by it, each entry's rank in
                             its peer's bucket, and per array the
@@ -58,8 +60,10 @@ Every name is a single path component; nested scopes give paths:
     exchange/land           equeue.land_sorted's own: each row's free-slot
                             ranks (a cumsum over [H, Q]) and how many
                             arrivals it lands (no push_self under land)
-    exchange/land/sort      the one stable sort of (destination, position)
-                            over the M flattened entries, and its key
+    exchange/land/sort      the stable sort of (destination, position)
+                            over one block of slot columns, rows x O/8
+                            entries, once a block a flush takes, and its
+                            key
     exchange/land/pack      the payload packed where it lies as [14, M]
                             32-bit words
     exchange/land/count     equeue.run_bounds: each destination's arrival
@@ -69,10 +73,10 @@ Every name is a single path component; nested scopes give paths:
                             through the sort's permutation and selects
                             them into the rows' free slots; as many
                             passes as the busiest destination needs
-    probe                   state_probe, the exchange's three marks of a
+    probe                   state_probe, the exchange's four counts of a
                             round (staged entries, fan-in, landing
-                            passes: always on) and the tracker plane's
-                            per-host high-water marks
+                            passes, flattened columns: always on) and the
+                            tracker plane's per-host high-water marks
 """
 
 from __future__ import annotations

@@ -107,6 +107,7 @@ def _canon(st):
             exch_hwm=st.tracker.exch_hwm * 0,
             land_hwm=st.tracker.land_hwm * 0,
             land_passes=st.tracker.land_passes * 0,
+            flush_cols=st.tracker.flush_cols * 0,
         ),
     )
 

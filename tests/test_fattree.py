@@ -268,6 +268,7 @@ def test_the_cabled_cell_rehearses_end_to_end():
     assert set(counted) == {
         "drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct",
         "exchange.passes_per_unit", "exchange.fill_pct", "exchange.land_hwm", "exchange.staged_hwm",
+        "exchange.flat_pct",
     }
     assert counted["drain.rounds_per_unit"] == 123
     assert counted["drain.iters_per_unit"] == 1486
@@ -279,6 +280,10 @@ def test_the_cabled_cell_rehearses_end_to_end():
     assert 0.03 < counted["exchange.fill_pct"] < 0.045
     assert counted["exchange.land_hwm"] == 40
     assert 40 <= counted["exchange.staged_hwm"] <= 128 * 512
+    # the flush's width (PR 37): a sender stages at most one window of 40
+    # in a round of the ramp, so every flush takes one block, 64 of 512
+    # columns
+    assert counted["exchange.flat_pct"] == 12.5
 
 
 def _reader(name):

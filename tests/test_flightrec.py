@@ -114,6 +114,9 @@ def test_ring_bound_and_sample_deltas(tmp_path):
                 lanes_live=(i + 1) * 16,
                 rounds_live=(i + 1) * 2,
                 win_ns_sum=(i + 1) * 500,
+                land_passes=(i + 1) * 3,
+                flush_cols=(i + 1) * 24,
+                land_hwm=i,
             )
         )
     rec.close()
@@ -123,6 +126,10 @@ def test_ring_bound_and_sample_deltas(tmp_path):
     # per-chunk deltas of the cumulative lanes
     assert last["dt_ns"] == 1000 and last["events"] == 10
     assert last["win_ns_mean"] == 250.0  # 500 ns over 2 live rounds
+    # the exchange's series: the chunk's landing passes and flattened
+    # columns as differences, the marks as they stand
+    assert (last["land_passes"], last["flush_cols"], last["land_hwm"]) == (3, 24, 9)
+    assert {"land_passes", "flush_cols", "land_hwm"} <= set(flightrec.SUMMARY_FIELDS)
     # occupancy: 16 live lanes over 4 iterations of 8 lanes each
     assert last["occupancy"] == 0.5
     # cumulative totals ride every sample (the black-box matcher's key)
@@ -364,4 +371,6 @@ def test_metrics_stream_adds_zero_device_fetches(tmp_path, monkeypatch):
         run_until(st0, end, model, tables, cfg, rounds_per_chunk=4)
     rec.close()
     assert len(rec.samples) > 0  # the plane was actually on
+    # and the exchange's series came with the probe (tracker off)
+    assert sum(s["flush_cols"] for s in rec.samples) >= sum(s["land_passes"] for s in rec.samples) > 0
     assert calls["n"] == plain  # and cost zero extra fetches

@@ -8,7 +8,7 @@ Contracts pinned here, on the virtual 8-device CPU mesh:
     run seeded seed + r*stride — phold and tgen, plain and pump
     engines, tracker leaves included — modulo ONLY the established
     sharded-execution deviations: the per-shard iteration diagnostics
-    (iters_done / lanes_live / exch_hwm / land_hwm / land_passes,
+    (iters_done / lanes_live / exch_hwm / land_hwm / land_passes / flush_cols,
     excluded by every engine-equivalence test — engine/state.py; the
     last three accumulate on each shard's local row 0, so their
     placement depends on the grid layout, and a shard's landing loop
@@ -75,13 +75,15 @@ def _canon_queue(q, h):
     return items
 
 
-PER_SHARD_LEAVES = ("iters_done", "lanes_live", "exch_hwm", "land_hwm", "land_passes")
+PER_SHARD_LEAVES = (
+    "iters_done", "lanes_live", "exch_hwm", "land_hwm", "land_passes", "flush_cols",
+)
 
 
 def per_shard_leaf(keystr: str) -> bool:
     """The leaves a comparison ACROSS planes leaves out, and nothing else:
     each shard keeps them for itself (the iteration diagnostics per row of
-    its own loop, the exchange's three marks on its row 0), so a sharded
+    its own loop, the exchange's four counts on its row 0), so a sharded
     state holds other values there than the one-chip state, tracker on or
     off. Across chunkings on one plane they are equal and stay compared."""
     return any(name in keystr for name in PER_SHARD_LEAVES)

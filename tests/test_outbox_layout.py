@@ -8,7 +8,8 @@ Contracts pinned here:
     `overflow` included, on random emissions over rows that fill up and
     overflow;
   * a flush of the staged outbox leaves the queue that `push_many_sorted`
-    gives on the `[M, 8]` rows the old flatten handed it;
+    gives on the `[M, 8]` rows of a flatten spelled on the `[H, O, 8]`
+    payload, entry (h, o) at o * H + h;
   * `grow_state` widens the payload on its slot axis: staging into a grown
     outbox equals staging into one built at the larger capacity.
 """
@@ -104,17 +105,18 @@ def test_staging_and_flush_equal_the_slot_major_spelling(o_cap, ep):
             np.asarray(getattr(new, name)), np.asarray(getattr(old, name)), err_msg=name
         )
 
-    # the flush: the landing sees entry (h, o) of every array at h * O + o
+    # the flush: the landing sees entry (h, o) of every array at o * H + h
+    # (slots major, hosts minor: engine/round.py _flush_outbox_traffic)
     m = H * o_cap
     want = equeue.push_many_sorted(
         st.queue,
-        dst=old.dst.reshape(m),
-        valid=old.valid.reshape(m),
-        time=old.time.reshape(m),
-        tie=old.tie.reshape(m),
+        dst=old.dst.T.reshape(m),
+        valid=old.valid.T.reshape(m),
+        time=old.time.T.reshape(m),
+        tie=old.tie.T.reshape(m),
         kind=jnp.full((m,), KIND_PACKET, jnp.int32),
-        data=old_data.reshape(m, LANES),
-        aux=old.aux.reshape(m),
+        data=jnp.moveaxis(old_data, 1, 0).reshape(m, LANES),
+        aux=old.aux.T.reshape(m),
         deliver_lanes=st.queue.capacity,
     )
     got = flush_outbox(st.replace(outbox=new), None, cfg)

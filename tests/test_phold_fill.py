@@ -68,7 +68,8 @@ def test_benchmark_json_names_the_configuration_and_its_cell_last():
 
 def test_the_front_door_sizes_the_world_without_building_it(tmp_path):
     """`shadow-tpu mem` on the full document: state by shapes, 4.65 KiB a
-    host (4.64 until the landing's two tracker leaves, PR 33), and in its
+    host (4.64 until the landing's two tracker leaves, PR 33; the flush's
+    `flush_cols` leaf, PR 37, is the 2 MiB that rounds 2.32 GiB to 2.33), and in its
     projection the ratio the chip measured in a run."""
     r = subprocess.run(
         [sys.executable, "-m", "shadow_tpu.cli", "mem", str(CONFIGS / "phold-512k.json"),
@@ -78,7 +79,7 @@ def test_the_front_door_sizes_the_world_without_building_it(tmp_path):
     )
     assert r.returncode == 0, r.stderr[-2000:]
     assert "524288" in r.stdout or "524,288" in r.stdout
-    assert "2.32 GiB" in r.stdout and "4.65 KiB/host" in r.stdout
+    assert "2.33 GiB" in r.stdout and "4.65 KiB/host" in r.stdout
     # the projection is by state alone, and says what the chip measured on top
     from shadow_tpu.runtime.memtrack import DEVICE_OVER_STATE
 
@@ -107,12 +108,16 @@ def test_the_cell_rehearses_end_to_end():
     assert out["metrics"]["drain.iters_per_unit"]["value"] > 0
     # no time, rate or share from a CPU run: the two new readers among them;
     # the counts keep their values (rounds and occupancy since PR 34, the
-    # exchange's four since PR 36)
+    # exchange's four since PR 36, the flush's width since PR 37)
     timed = {k for k, m in out["metrics"].items() if m["value"] is not None}
     assert timed == {
         "drain.iters_per_unit", "drain.rounds_per_unit", "drain.occupancy_pct",
         "exchange.passes_per_unit", "exchange.fill_pct", "exchange.land_hwm", "exchange.staged_hwm",
+        "exchange.flat_pct",
     }
+    # five flushes of one or two blocks of 2 columns each: a busiest row of
+    # 64 hosts stages 1-4 of its 16 slots
+    assert out["metrics"]["exchange.flat_pct"]["value"] in {100.0 * c / (5 * 16) for c in range(10, 41, 2)}
     assert out["metrics"]["drain.rounds_per_unit"]["value"] == 5  # 10 ms of a 2 ms lookahead
     # one ball a host: no destination of 64 takes more than one pass a round
     assert out["metrics"]["exchange.passes_per_unit"]["value"] == 5

@@ -139,10 +139,15 @@ def test_the_staged_payload_keeps_its_slots_minor(chunks, case):
     ]
     assert f"s32[{h},{w},{o}]" in staged, staged
     assert f"s32[{h},{o},{w}]" not in staged
-    # nor anywhere else in the program, inside a fusion or out
-    assert f"s32[{h},{o},{w}]" not in chunks[case][2]
+    # nor anywhere else in the program, inside a fusion or out; at 8 hosts
+    # the flush's own [8, O, H] words (hosts minor: engine/round.py
+    # _payload_words) spell the same digits, and are told apart by scope
+    for line in chunks[case][2].splitlines():
+        if f"s32[{h},{o},{w}]" in line:
+            assert h == w and "/exchange/" in line and "/stage" not in line, line
     assert f"tensor<{h}x{w}x{o}xi32>" in chunks[case][0]
-    assert f"tensor<{h}x{o}x{w}xi32>" not in chunks[case][0]
+    if h != w:  # the lowered text names no scope to tell the flush's words by
+        assert f"tensor<{h}x{o}x{w}xi32>" not in chunks[case][0]
 
 
 def _scopes_of(text, opcode):

@@ -226,7 +226,7 @@ def main(argv=None) -> int:
             ).lower(st, t64, tables)
         if name == "flush":
             return lambda: jax.jit(
-                lambda s: rnd._flush_outbox_traffic(s, None, ecfg)
+                lambda s: rnd.flush_outbox(s, None, ecfg)
             ).lower(st)
         if name == "window":
             return lambda: jax.jit(
